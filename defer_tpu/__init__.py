@@ -15,7 +15,27 @@ Public API (mirrors the reference's user model, reference src/test.py:21,47):
     defer.run_defer(model, ["add_8"], input_q, output_q)
 """
 
-from defer_tpu.api import DEFER, run_local_inference
+import os
+
+import jax
+
+
+def _place_compile_cache() -> None:
+    """The one place this package chooses JAX's persistent compile
+    cache: where JAX_COMPILATION_CACHE_DIR is set JAX reads it and
+    nothing is touched; otherwise the cache is `.jax_cache/` at the
+    root of the checkout. The path is part of the cache key, so it is
+    derived from this file and is the same for every process."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        jax.config.update(
+            "jax_compilation_cache_dir", os.path.join(root, ".jax_cache")
+        )
+
+
+_place_compile_cache()
+
+from defer_tpu.api import DEFER, run_local_inference  # noqa: E402
 from defer_tpu.config import DeferConfig
 from defer_tpu.graph.ir import Graph, GraphBuilder, OpNode
 from defer_tpu.graph.partition import (

@@ -16,26 +16,15 @@ from __future__ import annotations
 import argparse
 import json
 
-from defer_tpu.utils.platform import honor_env_platform as _init_platform
-
 
 def cmd_info(args: argparse.Namespace) -> None:
-    _init_platform()
     from defer_tpu.models import model_names
     from defer_tpu.ops.registry import op_names
-    from defer_tpu.utils.platform import BackendInitHang, devices_with_deadline
+    from defer_tpu.parallel.mesh import describe_topology
 
-    try:
-        devices_with_deadline(60.0)
-        from defer_tpu.parallel.mesh import describe_topology
-
-        topology: dict = describe_topology()
-    except BackendInitHang as e:
-        # A wedged device transport must not hang the CLI forever.
-        topology = {"error": str(e)}
     print(json.dumps(
         {
-            "topology": topology,
+            "topology": describe_topology(),
             "models": model_names(),
             "num_ops": len(op_names()),
         },
@@ -44,7 +33,6 @@ def cmd_info(args: argparse.Namespace) -> None:
 
 
 def cmd_partition(args: argparse.Namespace) -> None:
-    _init_platform()
     import jax
 
     from defer_tpu.graph.partition import partition
@@ -80,7 +68,6 @@ def cmd_partition(args: argparse.Namespace) -> None:
 
 
 def cmd_roofline(args: argparse.Namespace) -> None:
-    _init_platform()
     import jax
     import jax.numpy as jnp
 

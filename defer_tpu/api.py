@@ -200,7 +200,7 @@ class DEFER:
         """Probe every candidate device with a tiny computation; a
         device that errors or misses the deadline is excluded from
         re-dispatch. Probes run concurrently under ONE shared deadline
-        (hard_sync_timeout fetches in helper threads and dedupes by
+        (hard_sync_timeout waits in helper threads and dedupes by
         array), so n hung devices cost max(timeout), not n*timeout."""
         devs = self.devices if self.devices is not None else jax.devices()
         probes: list[tuple[jax.Device, Any]] = []
@@ -212,7 +212,7 @@ class DEFER:
                 )
             except Exception as e:  # noqa: BLE001 — exclusion is the point
                 log.warning("device %s failed the health probe: %s", d, e)
-        for _, probe in probes:  # start every fetch thread
+        for _, probe in probes:  # start every wait thread
             try:
                 hard_sync_timeout(probe, 0.0)
             except Exception:  # noqa: BLE001 — surfaced in the wait below
@@ -283,8 +283,8 @@ class DEFER:
         monitor = ProgressMonitor(self.config.collective_timeout_s)
 
         def watchdog_sync(arr: Any) -> None:
-            # Fetch-based barrier with a deadline so a stuck stage trips
-            # the watchdog instead of hanging forever (utils/sync.py).
+            # Barrier with a deadline so a stuck stage trips the
+            # watchdog instead of hanging forever (utils/sync.py).
             # A barrier may cover many microbatches; on timeout we only
             # raise if the completed prefix stopped growing — genuinely
             # zero progress, matching collective_timeout_s semantics for

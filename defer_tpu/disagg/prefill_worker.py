@@ -226,10 +226,6 @@ def serve_prefill(
 def main(argv: list[str] | None = None) -> None:
     import argparse
 
-    from defer_tpu.utils.platform import honor_env_platform
-
-    honor_env_platform()
-
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--listen", type=int, default=5100)
     ap.add_argument("--listen-host", default="0.0.0.0")

@@ -262,13 +262,12 @@ def sample_token_batched_nosort(
 
 
 def _flash_decode_mode() -> str | None:
-    """Which attention path the T=1 decode step takes: None (the XLA
-    einsum — default off-TPU and on tunneled backends), "tpu" (the
-    pallas flash-decode kernel, when the backend can run Mosaic), or
-    "interpret" (DEFER_TPU_PALLAS_INTERPRET=1 — the kernel through the
-    pallas interpreter, for CI parity tests off-TPU). Checked at trace
-    time; set the env before building steps (compiled steps are
-    memoized)."""
+    """Which attention path the T=1 decode step takes: "tpu" (the
+    pallas flash-decode kernel, on a TPU), "interpret"
+    (DEFER_TPU_PALLAS_INTERPRET=1 — the kernel through the pallas
+    interpreter, for CI parity tests off-TPU), or None (the XLA
+    einsum, everywhere else). Checked at trace time; set the env
+    before building steps (compiled steps are memoized)."""
     import os
 
     if os.environ.get("DEFER_TPU_PALLAS_INTERPRET") == "1":
@@ -778,13 +777,13 @@ class GptDecoder:
                 if cfg.window is not None:
                     mask &= j[None, :] > tt - cfg.window
 
-        from defer_tpu.ops.pallas_attention import _pick_block
+        from defer_tpu.ops.pallas_attention import decode_k_block
 
         flash_mode = (
             _flash_decode_mode()
             if t == 1
             and not self.rolling_cache
-            and _pick_block(k_att.shape[2], 256) >= 8
+            and decode_k_block(k_att.shape[2], k_att.dtype) is not None
             else None
         )
         if flash_mode is not None:

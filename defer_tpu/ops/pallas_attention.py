@@ -9,8 +9,15 @@ softmax recurrence, so memory is O(S·D) instead of O(S²) and the two
 matmuls per block land on the MXU back-to-back.
 
 `multi_head_attention` (defer_tpu/ops/attention.py) dispatches here on
-TPU and falls back to the XLA einsum path elsewhere; tests run this
-kernel in interpreter mode on CPU against that reference.
+TPU for the shapes `flash_unsupported` accepts and takes the XLA einsum
+path for the rest; tests run this kernel in interpreter mode on CPU
+against that reference.
+
+Grid semantics every kernel here declares to Mosaic: the batch and head
+axes are "parallel" (cells share nothing), and the decode/prefill
+kernels' innermost K-block axis is "arbitrary" — it must run in order
+on one core, because the online-softmax carry lives in VMEM scratch
+across it.
 
 Differentiable: a custom VJP recomputes attention with the XLA
 reference implementation in the backward pass (flash-style
@@ -26,18 +33,51 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Finite stand-in for -inf: keeps fully-masked rows NaN-free in the
 # online-softmax recurrence (exp(MASK - MASK) would be NaN with -inf).
 _MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 
 
-def _pick_block(s: int, preferred: int) -> int:
-    """Largest divisor of `s` that is <= preferred (>= 8 when possible)."""
-    b = min(preferred, s)
-    while b > 1 and s % b:
-        b -= 1
-    return b
+# What Mosaic fits of blocked operands into its default 16 MiB of scoped
+# VMEM. Compiled ahead of time for a v5e: 14.75 MiB fit, 15.25 MiB fail
+# with "Scoped allocation with size 16.25M and limit 16.00M".
+_BLOCKED_VMEM_BYTES = 15 * 2**20
+
+
+def _sublane(dtype) -> int:
+    """Rows of one native (sublane, 128) tile: 8 for f32, 16 for bf16,
+    32 for int8."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _pick_block(s: int, preferred: int, sublane: int = 8) -> int | None:
+    """Block length along an axis of `s` rows: the whole axis when it
+    fits in `preferred`, else the largest divisor of `s` that is
+    <= preferred and a multiple of `sublane` (the dtype's tile rows).
+    Either way a multiple of 8 — Mosaic must prove that a
+    `pl.ds(i * block, block)` row slice starts on a tile boundary.
+    None when `s` has no such block."""
+    if s <= preferred:
+        return s if s % 8 == 0 else None
+    b = preferred - preferred % sublane
+    while b >= sublane:
+        if s % b == 0:
+            return b
+        b -= sublane
+    return None
+
+
+def decode_k_block(s: int, dtype, block_k: int = 256) -> int | None:
+    """The K-block length `flash_decode` tiles a cache of `s` rows
+    with, or None when it cannot take that cache — what a caller asks
+    before choosing the kernel."""
+    return _pick_block(s, block_k, _sublane(dtype))
+
+
+def _grid_params(*semantics: str):
+    return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 def _mha_kernel(
@@ -110,18 +150,17 @@ def _flash_fwd_impl(
     block_q: int = 256,
     block_k: int = 256,
 ) -> jax.Array:
+    reason = flash_unsupported(
+        q.shape, k.shape, q.dtype,
+        causal=causal, block_q=block_q, block_k=block_k,
+    )
+    if reason:
+        raise ValueError(reason)
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
-    if s_q < 8 or s_k < 8:
-        raise ValueError(f"sequence too short for the TPU kernel: {s_q}x{s_k}")
-    if causal and s_q != s_k:
-        raise ValueError("causal flash kernel requires s_q == s_k")
-    bq = _pick_block(s_q, block_q)
-    bk = _pick_block(s_k, block_k)
-    if bq < 8 or bk < 8:
-        raise ValueError(
-            f"no tile-friendly block split for seq lens {s_q}/{s_k}"
-        )
+    sub = _sublane(q.dtype)
+    bq = _pick_block(s_q, block_q, sub)
+    bk = _pick_block(s_k, block_k, sub)
     qf = q.reshape(b * h, s_q, d)
     kf = k.reshape(b * h, s_k, d)
     vf = v.reshape(b * h, s_k, d)
@@ -141,6 +180,7 @@ def _flash_fwd_impl(
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
+        compiler_params=_grid_params("parallel", "parallel"),
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, s_q, d)
@@ -296,9 +336,9 @@ def flash_decode(
     if hq % hkv:
         raise ValueError(f"Hq={hq} must be a multiple of Hkv={hkv}")
     g = hq // hkv
-    bk = _pick_block(s, block_k)
-    if bk < 8:
-        raise ValueError(f"no tile-friendly K block for cache len {s}")
+    bk = decode_k_block(s, k.dtype, block_k)
+    if bk is None:
+        raise ValueError(f"no tile-aligned K block divides cache len {s}")
     num_kb = s // bk
     g_pad = max(g, 8)
     qg = q.reshape(b, hkv, g, d)
@@ -316,8 +356,6 @@ def flash_decode(
     def kv_index(i, j, kb, pos_ref):
         lo, hi = _decode_lo_hi(pos_ref[i], bk, window)
         return (i, j, jnp.clip(kb, lo, hi), 0)
-
-    from jax.experimental.pallas import tpu as pltpu
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -342,9 +380,33 @@ def flash_decode(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g_pad, d), q.dtype),
+        compiler_params=_grid_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(pos1, qg, k, v)
     return out[:, :, :g, :].reshape(b, hq, d)
+
+
+def _slot_scales(scale: jax.Array, tables: jax.Array) -> jax.Array:
+    """[NB, Hkv] per-(block, head) pool scales -> [B*Hkv, 1, MB] f32
+    per-slot rows: entry tb of row i*Hkv + j is the scale of the pool
+    block that slot i's table names in column tb, for KV head j. The
+    kernels stage one such row per (slot, head) cell into SMEM — a
+    scalar belongs there, and a (1, 1) VMEM block of the [NB, Hkv]
+    tensor is below Mosaic's minimum tile — and read entry tb as a
+    scalar. The gather is B*MB*Hkv floats; the pool itself is still
+    read through the block table inside the kernel."""
+    b, mb = tables.shape
+    hkv = scale.shape[1]
+    rows = jnp.asarray(scale, jnp.float32)[tables]  # [B, MB, Hkv]
+    return rows.transpose(0, 2, 1).reshape(b * hkv, 1, mb)
+
+
+def _scale_spec(hkv: int, mb: int) -> pl.BlockSpec:
+    return pl.BlockSpec(
+        (1, 1, mb),
+        lambda i, j, tb, *_: (i * hkv + j, 0, 0),
+        memory_space=pltpu.SMEM,
+    )
 
 
 def _paged_decode_kernel(
@@ -373,10 +435,11 @@ def _paged_decode_kernel(
     the position mask keeps block-`hi` rows past `pos` unattended.
 
     With `quantized`, k_ref/v_ref are int8 pool tiles and two extra
-    (1, 1) scale refs follow (per-(block, head) symmetric scales,
-    staged through the SAME table indirection): the fold widens
-    int8 -> f32 and multiplies the scale in VMEM, so HBM sees one
-    byte per element — bandwidth, not just residency, halves."""
+    SMEM refs follow: this (slot, head) cell's [1, 1, MB] row of
+    per-(block, head) symmetric scales, gathered through the same
+    block table (`_slot_scales`). The fold widens int8 -> f32 and
+    multiplies entry tb in VMEM, so HBM sees one byte per element —
+    bandwidth, not just residency, halves."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -399,8 +462,8 @@ def _paged_decode_kernel(
         k = k_ref[0, 0].astype(jnp.float32)  # (block_size, d)
         v = v_ref[0, 0].astype(jnp.float32)
         if quantized:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
+            k = k * ks_ref[0, 0, tb]
+            v = v * vs_ref[0, 0, tb]
         s = lax.dot_general(
             q,
             k,
@@ -466,10 +529,10 @@ def paged_flash_decode(
 
     For the int8 pool (runtime/paged.py kv_dtype="int8") pass
     scale_k/scale_v [NB, Hkv] f32 — per-(block, head) symmetric
-    scales. They are regular inputs (NOT scalar prefetch: an
-    [NB, Hkv] f32 tensor does not fit SMEM) staged one (1, 1) cell at
-    a time through the same block-table index maps as the K/V tiles,
-    and the kernel dequantizes in VMEM — HBM reads stay int8."""
+    scales. The whole [NB, Hkv] tensor does not fit SMEM, so each
+    slot's entries are gathered through its table first
+    (`_slot_scales`) and staged one (slot, head) row at a time; the
+    kernel dequantizes in VMEM — HBM reads of K/V stay int8."""
     b, hq, d = q.shape
     nb, hkv, bs, _ = pool_k.shape
     if (scale_k is None) != (scale_v is None):
@@ -502,12 +565,6 @@ def paged_flash_decode(
         lo, hi = _decode_lo_hi(pos_ref[i], bs, window)
         return (tables_ref[i, jnp.clip(tb, lo, hi)], j, 0, 0)
 
-    def scale_index(i, j, tb, tables_ref, pos_ref):
-        lo, hi = _decode_lo_hi(pos_ref[i], bs, window)
-        return (tables_ref[i, jnp.clip(tb, lo, hi)], j)
-
-    from jax.experimental.pallas import tpu as pltpu
-
     in_specs = [
         pl.BlockSpec(
             (1, 1, g_pad, d),
@@ -518,13 +575,10 @@ def paged_flash_decode(
     ]
     operands = [qg, pool_k, pool_v]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, 1), scale_index),
-            pl.BlockSpec((1, 1), scale_index),
-        ]
+        in_specs += [_scale_spec(hkv, mb)] * 2
         operands += [
-            jnp.asarray(scale_k, jnp.float32),
-            jnp.asarray(scale_v, jnp.float32),
+            _slot_scales(scale_k, tables),
+            _slot_scales(scale_v, tables),
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -544,6 +598,7 @@ def paged_flash_decode(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g_pad, d), q.dtype),
+        compiler_params=_grid_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(tables, pos1, *operands)
     return out[:, :, :g, :].reshape(b, hq, d)
@@ -589,8 +644,8 @@ def _paged_prefill_kernel(
     prefill and the speculative verify forward read the pool directly,
     no contiguous gather. Rows padded past T*G attend a superset of
     live columns and are sliced off by the wrapper. With `quantized`,
-    two (1, 1) per-(block, head) scale refs follow k/v and the fold
-    dequantizes int8 tiles in VMEM (see `_paged_decode_kernel`)."""
+    two SMEM scale rows follow k/v and the fold dequantizes int8 tiles
+    in VMEM (see `_paged_decode_kernel`)."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -613,8 +668,8 @@ def _paged_prefill_kernel(
         k = k_ref[0, 0].astype(jnp.float32)  # (block_size, d)
         v = v_ref[0, 0].astype(jnp.float32)
         if quantized:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
+            k = k * ks_ref[0, 0, tb]
+            v = v * vs_ref[0, 0, tb]
         s = lax.dot_general(
             q,
             k,
@@ -728,12 +783,6 @@ def paged_flash_prefill(
         lo, hi = _prefill_lo_hi(start_ref[i], t_q, bs, window)
         return (tables_ref[i, jnp.clip(tb, lo, hi)], j, 0, 0)
 
-    def scale_index(i, j, tb, tables_ref, start_ref):
-        lo, hi = _prefill_lo_hi(start_ref[i], t_q, bs, window)
-        return (tables_ref[i, jnp.clip(tb, lo, hi)], j)
-
-    from jax.experimental.pallas import tpu as pltpu
-
     in_specs = [
         pl.BlockSpec(
             (1, 1, r_pad, d),
@@ -744,13 +793,10 @@ def paged_flash_prefill(
     ]
     operands = [qg, pool_k, pool_v]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, 1), scale_index),
-            pl.BlockSpec((1, 1), scale_index),
-        ]
+        in_specs += [_scale_spec(hkv, mb)] * 2
         operands += [
-            jnp.asarray(scale_k, jnp.float32),
-            jnp.asarray(scale_v, jnp.float32),
+            _slot_scales(scale_k, tables),
+            _slot_scales(scale_v, tables),
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -770,10 +816,53 @@ def paged_flash_prefill(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, r_pad, d), q.dtype),
+        compiler_params=_grid_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(tables, start1, *operands)
     out = out[:, :, :r, :].reshape(b, hkv, t_q, g, d)
     return out.transpose(0, 1, 3, 2, 4).reshape(b, hq, t_q, d)
+
+
+def flash_unsupported(
+    q_shape: tuple[int, ...],
+    k_shape: tuple[int, ...],
+    dtype,
+    *,
+    causal: bool = False,
+    block_q: int = 256,
+    block_k: int = 256,
+) -> str | None:
+    """Why `flash_attention` cannot take (B, H, S, Dh) operands of
+    these shapes, or None when it can. `multi_head_attention` asks
+    this BEFORE tracing the kernel, and the kernel raises the same
+    reason, so the two can never disagree.
+
+    The kernel keeps one head's whole K and V VMEM-resident (double
+    buffered, as Mosaic stages every blocked operand), which caps the
+    key length: at Dh=128, 15104 rows in bf16 and 7424 in f32."""
+    if len(q_shape) != 4:
+        return f"expected (B, H, S, Dh), got {tuple(q_shape)}"
+    s_q, d = q_shape[2], q_shape[3]
+    s_k = k_shape[2]
+    if s_q < 8 or s_k < 8:
+        return f"sequence too short for the TPU kernel: {s_q}x{s_k}"
+    if causal and s_q != s_k:
+        return "causal flash kernel requires s_q == s_k"
+    sub = _sublane(dtype)
+    bq = _pick_block(s_q, block_q, sub)
+    if bq is None or _pick_block(s_k, block_k, sub) is None:
+        return (
+            f"no block of <= {block_q}/{block_k} rows that is a multiple "
+            f"of {sub} divides seq lens {s_q}/{s_k}"
+        )
+    resident = 4 * (s_k + bq) * d * jnp.dtype(dtype).itemsize
+    if resident > _BLOCKED_VMEM_BYTES:
+        return (
+            f"K and V of one head ({s_k} x {d} {jnp.dtype(dtype).name}, "
+            f"double buffered) need {resident / 2**20:.2f} MiB of VMEM; "
+            f"{_BLOCKED_VMEM_BYTES / 2**20:.0f} MiB fit"
+        )
+    return None
 
 
 def flash_attention(
@@ -786,10 +875,7 @@ def flash_attention(
 ) -> jax.Array:
     """Flash attention on (B, H, S, Dh) tensors; returns (B, H, S, Dh).
 
-    Raises ValueError for shapes without a tile-friendly block split —
-    `multi_head_attention` catches that in "auto" mode and falls back to
-    the XLA path.
+    Raises ValueError with `flash_unsupported`'s reason for shapes the
+    kernel cannot take.
     """
-    if q.ndim != 4:
-        raise ValueError(f"expected (B, H, S, Dh), got {q.shape}")
     return _flash(causal, interpret, q, k, v)

@@ -281,8 +281,8 @@ class Pipeline(StreamMeasure):
         for x in inputs:
             # Backpressure: Retirer emits the known-ready prefix for
             # free and, at depth, takes one batched barrier on the
-            # middle of the window — never waits per item; completion
-            # notification can cost ~ms each (utils/sync.py).
+            # middle of the window — never waits per item
+            # (utils/sync.py).
             yield from retirer.add(self(x))
         yield from retirer.flush()
 
@@ -303,8 +303,7 @@ class Pipeline(StreamMeasure):
                 h = self._place(h, self.devices[i])
                 hard_sync(h)
             # Amortized half excludes the per-call host sync round
-            # trip, which on tunneled transports dwarfs the stage
-            # itself (probe_latency docstring has the methodology).
+            # trip (probe_latency docstring has the methodology).
             sample = probe_latency(fn, p, h, iters=iters)
             amortized = sample["amortized_s"]
             results.append(

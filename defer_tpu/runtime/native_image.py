@@ -63,12 +63,8 @@ def _load() -> ctypes.CDLL | None:
             os.path.exists(_SRC)
             and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
         )
-        if stale and not _build() and not os.path.exists(_SO):
-            # No compiler AND no prebuilt library — numpy fallback.
-            # (A rebuild failure with an existing .so still loads it:
-            # git does not preserve mtimes, so a fresh clone often
-            # looks 'stale' on hosts without g++.)
-            return None
+        if stale and not _build():
+            return None  # no compiler: the numpy path serves
         try:
             lib = ctypes.CDLL(_SO)
         except OSError as e:

@@ -335,10 +335,6 @@ def serve_pp_stage(
 def main(argv: list[str] | None = None) -> None:
     import argparse
 
-    from defer_tpu.utils.platform import honor_env_platform
-
-    honor_env_platform()
-
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--listen", type=int, default=5000)
     ap.add_argument(

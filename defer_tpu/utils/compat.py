@@ -1,22 +1,15 @@
-"""Version shims for the narrow band of jax APIs whose home moved.
+"""The one call site of `jax.shard_map`.
 
-`shard_map` graduated from `jax.experimental.shard_map` to `jax.shard_map`
-(and its `check_rep` knob was renamed `check_vma` along the way). The
-serving stack runs on whichever jax the image bakes in, so every caller
-goes through this one wrapper instead of guessing the import site.
+Every sharded step in the serving stack goes through this wrapper so
+the spec checker (analysis/shardcheck.py) has a single name to resolve
+and the callers keep one spelling of the replication-check switch.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Any
 
 import jax
-
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:  # pragma: no cover - depends on the installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
 
 
 def shard_map(
@@ -27,28 +20,14 @@ def shard_map(
     out_specs: Any,
     check_rep: bool = True,
 ) -> Any:
-    """`jax.shard_map` with the replication-check kwarg normalized:
-    pass `check_rep=` here regardless of what the installed jax calls
-    it. Bodies that end in an explicit collective whose output
-    replication the checker cannot infer (e.g. a tiled `all_gather` of
-    vocab-sharded logits) pass check_rep=False; everything else keeps
-    the checker on."""
-    try:
-        return _shard_map_impl(
-            f, mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_rep,
-        )
-    except TypeError:
-        return _shard_map_impl(
-            f, mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_rep,
-        )
-
-
-@functools.lru_cache(maxsize=None)
-def has_shard_map() -> bool:
-    """True when some shard_map implementation is importable (always,
-    on the jax versions this repo supports) — kept as a gate so callers
-    can degrade to single-device serving instead of crashing if a
-    stripped-down jax build drops the experimental module."""
-    return _shard_map_impl is not None
+    """`jax.shard_map` over `mesh`. Bodies that end in an explicit
+    collective whose output replication the checker cannot infer (e.g.
+    a tiled `all_gather` of vocab-sharded logits) pass check_rep=False;
+    everything else keeps the checker on."""
+    return jax.shard_map(
+        f,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        check_vma=check_rep,
+    )

@@ -20,10 +20,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
 
-from defer_tpu.utils.platform import honor_env_platform
-
-honor_env_platform()
-
 import argparse
 import time
 

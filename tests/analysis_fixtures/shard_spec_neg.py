@@ -7,7 +7,7 @@ mesh (``self.mesh``) is skipped rather than guessed at.
 """
 
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from defer_tpu.utils.compat import shard_map
 
 
 def build(devs):

@@ -12,7 +12,7 @@ Expected: 3 findings.
 """
 
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from defer_tpu.utils.compat import shard_map
 
 
 def build(devs):

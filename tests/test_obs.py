@@ -340,13 +340,16 @@ def test_dispatch_efficiency_metrics():
     )
     assert st4["tokens_per_dispatch"] > 1.0
 
-    # eos mid-window: pick a token actually generated mid-stream and
-    # re-serve with it — deterministic truncation on a cut window.
-    # Index 3, not earlier: greedy tiny_gpt repeats its first token
-    # for a few steps, and an eos equal to a request's FIRST token
+    # eos mid-window: a token request 0 first generates mid-window
+    # (admission emits generated token 0 and window n covers tokens
+    # 4n+1..4n+4, so a first occurrence at index j cuts a window iff
+    # j % 4 != 0). Not a fixed index: greedy tiny_gpt repeats its first
+    # token for a while, and an eos equal to a request's FIRST token
     # finishes it at admission, before any window runs.
-    t0 = reqs[0][0].shape[1]
-    eos = int(np.asarray(outs[0])[0, t0 + 3])
+    gen = np.asarray(outs[0])[0, reqs[0][0].shape[1]:].tolist()
+    eos = next(
+        tok for j, tok in enumerate(gen) if j % 4 and tok not in gen[:j]
+    )
     obs_reset()
     _, _ = serve_greedy(
         dec, params, reqs, max_batch=2, decode_window=4, eos_id=eos

@@ -179,3 +179,34 @@ def test_llama_stack_trains(devices):
     labels = jax.random.randint(jax.random.key(2), (3, 2), 0, 4)
     state, loss = train_step(state, ids, labels)
     assert jnp.isfinite(loss)
+
+
+def test_compat_shard_map_on_the_mesh(devices):
+    """utils/compat.shard_map — the wrapper every tensor-parallel step
+    goes through — against the installed jax.shard_map signature: a
+    psum body with the replication checker on, and a tiled all_gather
+    body that needs check_rep=False."""
+    from defer_tpu.utils.compat import shard_map
+
+    mesh = make_mesh({"model": 8}, devices)
+    x = jnp.arange(32.0).reshape(8, 4)
+    summed = shard_map(
+        lambda a: jax.lax.psum(a, "model"),
+        mesh,
+        in_specs=(P("model"),),
+        out_specs=P(),
+    )
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(summed)(x)), np.asarray(x.sum(0, keepdims=True))
+    )
+    gathered = shard_map(
+        lambda a: jax.lax.all_gather(a, "model", axis=0, tiled=True),
+        mesh,
+        in_specs=(P("model"),),
+        out_specs=P(),
+        # analysis: ignore[shard-spec] the checker cannot infer a tiled all_gather's replication — the case the flag exists for
+        check_rep=False,
+    )
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(gathered)(x)), np.asarray(x)
+    )

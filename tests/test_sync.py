@@ -67,19 +67,15 @@ def test_retirer_flush_returns_everything():
     assert r.flush() == []
 
 
-def test_hard_sync_timeout_dedups_inflight_fetches():
-    # A slow array: repeated timed-out calls must share one fetch
-    # thread, and the fetch must resolve once the array completes.
+def test_hard_sync_timeout_dedups_inflight_waits():
+    # A slow array: repeated timed-out calls must share one helper
+    # thread, and the wait must resolve once the array completes.
     release = threading.Event()
 
     class SlowArray:
-        ndim = 0
-
-        def __array__(self, dtype=None, copy=None):
+        def block_until_ready(self):
             release.wait(5)
-            import numpy as np
-
-            return np.zeros((), np.float32)
+            return self
 
     arr = SlowArray()
     n0 = threading.active_count()
@@ -92,15 +88,13 @@ def test_hard_sync_timeout_dedups_inflight_fetches():
     assert hard_sync_timeout(arr, 5.0) is True
 
 
-def test_hard_sync_timeout_propagates_fetch_errors():
+def test_hard_sync_timeout_propagates_wait_errors():
     class BrokenArray:
-        ndim = 0
-
-        def __array__(self, dtype=None, copy=None):
+        def block_until_ready(self):
             raise RuntimeError("xla runtime failure")
 
     with pytest.raises(RuntimeError, match="xla runtime failure"):
         hard_sync_timeout(BrokenArray(), 5.0)
-        # The fetch thread may need a beat to surface the error.
+        # The helper thread may need a beat to surface the error.
         time.sleep(0.1)
         hard_sync_timeout(BrokenArray(), 5.0)

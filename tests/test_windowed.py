@@ -158,16 +158,20 @@ def test_decode_window_validation(gpt):
 # -- eos mid-window ----------------------------------------------------
 
 
-def _harvest_eos(outs, reqs, gen_index=2):
-    """A token some request actually generates mid-stream, to use as
-    eos: re-serving with it forces a mid-window finish (deterministic
-    — same seeds, same tokens)."""
+def _harvest_eos(outs, reqs, window=4):
+    """A token some request first generates MID-WINDOW, to use as eos:
+    re-serving with it cuts that window short (deterministic — same
+    seeds, same tokens). Admission emits generated token 0 and window
+    n covers tokens n*K+1..(n+1)*K, so a first occurrence at index j
+    truncates iff j % K != 0; greedy tiny_gpt repeats its first token
+    for a while, so a fixed index would finish the request at
+    admission instead."""
     for (prompt, steps), o in zip(reqs, outs):
-        t0 = prompt.shape[1]
-        gen = np.asarray(o)[0, t0:]
-        if len(gen) > gen_index:
-            return int(gen[gen_index])
-    raise AssertionError("no request generated enough tokens")
+        gen = np.asarray(o)[0, prompt.shape[1]:].tolist()
+        for j, tok in enumerate(gen):
+            if j % window and tok not in gen[:j]:
+                return int(tok)
+    raise AssertionError("no request first emits a token mid-window")
 
 
 @pytest.mark.parametrize("server", ["flat", "paged"])
