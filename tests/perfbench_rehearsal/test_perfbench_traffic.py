@@ -1,0 +1,99 @@
+"""The one generator: the same work for every seed, in another order."""
+
+import json
+import os
+
+import numpy as np
+import pytest
+
+import rehearsal_util
+from perfbench import traffic
+
+CHAT = json.load(open(os.path.join(rehearsal_util.REPO, "perfbench", "traffic", "chat.json")))
+CELL = {"knee_per_s": 1.4, "lifetime_s": 20.0, "backlog_per_s": 2.2}
+
+
+def gen(seed, mix=CHAT, **kw):
+    return traffic.generate(mix, CELL, seconds=45.0, max_batch=32, seed=seed, **kw)
+
+
+def test_same_seed_same_requests_and_ids():
+    a, b = gen(2**31 + 7), gen(2**31 + 7)
+    assert a == b
+    ia = traffic.token_ids(a, 32000, 0, 2**31 + 7)
+    ib = traffic.token_ids(b, 32000, 0, 2**31 + 7)
+    assert all((x == y).all() for x, y in zip(ia, ib))
+    assert all(x.shape == (1, r.prompt_tokens) for x, r in zip(ia, a))
+
+
+def test_another_seed_is_the_same_work_in_another_order():
+    a, b = gen(1), gen(2)
+    assert a != b
+    sizes = lambda rs: sorted((r.prompt_tokens, r.output_tokens, r.standing) for r in rs)  # noqa: E731
+    assert sizes(a) == sizes(b)
+
+    def gaps(rs):
+        due = [r.due_s for r in rs if not r.standing]
+        assert due == sorted(due)
+        return sorted(np.round(np.diff([0.0] + due), 9))
+
+    assert gaps(a) == gaps(b)
+    in_window = [sum(0 <= r.due_s <= 45.0 for r in rs if not r.standing) for rs in (a, b)]
+    assert abs(in_window[0] - in_window[1]) <= 1
+    assert in_window[0] == pytest.approx(0.8 * 1.4 * 45.0, abs=1.5)
+
+
+def test_standing_population_is_staggered():
+    standing = [r for r in gen(3) if r.standing]
+    assert len(standing) == round(0.8 * 1.4 * 20.0) == 22
+    left = sorted(r.output_tokens for r in standing)
+    assert all(1 <= x <= 192 for x in left)
+    assert len(set(left)) >= 15  # completions spread over the window
+    assert all(r.due_s < 0 for r in standing)
+    backlog = dict(CHAT, arrival={"kind": "backlog"}, standing={"population": "max_batch"})
+    rs = gen(3, mix=backlog)
+    assert sum(r.standing for r in rs) == 32
+    assert all(r.due_s == 0.0 for r in rs if not r.standing)
+    assert sum(not r.standing for r in rs) == 99  # ceil(2.2 * 45)
+
+
+def test_chat_lengths_follow_the_distributions_and_hardly_repeat():
+    arrivals = [r for r in gen(4) if not r.standing]
+    prompts = [r.prompt_tokens for r in arrivals]
+    assert len(set(prompts)) == len(prompts)  # the program sees a new length each time
+    assert 128 <= min(prompts) < 140 and 960 < max(prompts) <= 1024
+    assert 330 < np.exp(np.log(prompts).mean()) < 400
+    outputs = [r.output_tokens for r in arrivals]
+    assert 64 <= min(outputs) and max(outputs) <= 192 and 124 < np.mean(outputs) < 132
+
+
+@pytest.mark.parametrize(
+    "spec, check",
+    [
+        ({"dist": "uniform", "low": 64, "high": 192}, lambda x: 120 < x.mean() < 136),
+        ({"dist": "loguniform", "low": 128, "high": 1024}, lambda x: 330 < np.exp(np.log(x).mean()) < 400),
+        ({"dist": "choice", "values": [16, 48]}, lambda x: set(x) == {16, 48} and abs((x == 16).sum() - 100) <= 1),
+    ],
+)
+def test_length_distributions(spec, check):
+    x = traffic.draw_lengths(spec, 200, np.random.default_rng(0))
+    assert len(x) == 200 and check(x)
+    if "low" in spec:
+        assert x.min() >= spec["low"] and x.max() <= spec["high"]
+
+
+def test_bursts_keep_the_mean_rate_and_bunch_arrivals():
+    arrival = {"kind": "bursts", "share_of_knee": 0.8, "on_s": 5.0, "off_s": 15.0, "factor": 3.0}
+    unit = np.cumsum(np.random.default_rng(0).permutation(traffic.exponential_gaps(4000)))
+    t = traffic.warp(unit, arrival, 2.0)
+    assert (np.diff(t) >= 0).all()
+    assert len(t) / t[-1] == pytest.approx(2.0, rel=0.02)
+    on = ((t % 20.0) < 5.0).mean()
+    assert on == pytest.approx(0.75, abs=0.02)  # 3x the rate for a quarter of the time
+
+
+def test_shared_prefix_is_common_to_every_prompt():
+    rs = gen(5)[:6]
+    ids = traffic.token_ids(rs, 32000, 64, 5)
+    assert all((a[0, :64] == ids[0][0, :64]).all() for a in ids)
+    assert not (ids[0][0, 64:128] == ids[1][0, 64:128]).all()
