@@ -193,6 +193,7 @@ def truncate_logits_batched(
 
 
 @jax.jit
+@jax.named_scope("sample")
 def sample_token_batched(
     logits_last: jax.Array,
     keys: jax.Array,
@@ -224,6 +225,7 @@ def sample_token_batched(
 
 
 @jax.jit
+@jax.named_scope("sample")
 def sample_token_batched_nosort(
     logits_last: jax.Array,
     keys: jax.Array,
@@ -583,6 +585,7 @@ class GptDecoder:
 
         return bias, proj
 
+    @jax.named_scope("attn_qkv")
     def _attn_qkv(self, p: dict, x, pos, adapter_ids=None):
         """ln1 + q/k/v projections (+rope at the step's absolute
         positions) + head split: everything a block does BEFORE the
@@ -618,24 +621,26 @@ class GptDecoder:
         paged block-native steps."""
         cfg = self.cfg
         bias, proj = self._proj_fns(p, x.dtype, adapter_ids)
-        attn = proj(attn, "wo")
-        if tp_axis is not None:
-            attn = lax.psum(attn, tp_axis)
-        attn = bias(attn, "bo")
-        x = x + attn
-        h2 = norm_apply(cfg, x, p, "ln2")
-        if cfg.ffn_style == "swiglu":
-            gate = jax.nn.silu(proj(h2, "w1"))
-            ff = proj(gate * proj(h2, "w3"), "w2")
+        with jax.named_scope("attn_out"):
+            attn = proj(attn, "wo")
+            if tp_axis is not None:
+                attn = lax.psum(attn, tp_axis)
+            attn = bias(attn, "bo")
+            x = x + attn
+        with jax.named_scope("mlp"):
+            h2 = norm_apply(cfg, x, p, "ln2")
+            if cfg.ffn_style == "swiglu":
+                gate = jax.nn.silu(proj(h2, "w1"))
+                ff = proj(gate * proj(h2, "w3"), "w2")
+                if tp_axis is not None:
+                    ff = lax.psum(ff, tp_axis)
+                return x + ff
+            ff = bias(proj(h2, "w1"), "b1")
+            ff = jax.nn.gelu(ff)
+            ff = proj(ff, "w2")
             if tp_axis is not None:
                 ff = lax.psum(ff, tp_axis)
-            return x + ff
-        ff = bias(proj(h2, "w1"), "b1")
-        ff = jax.nn.gelu(ff)
-        ff = proj(ff, "w2")
-        if tp_axis is not None:
-            ff = lax.psum(ff, tp_axis)
-        return bias(x + ff, "b2")
+            return bias(x + ff, "b2")
 
     def _block(
         self,
@@ -669,12 +674,21 @@ class GptDecoder:
         dequantizes at its gather and requantizes the returned new
         rows at its scatter, so this read path — and the new_k/new_v
         it hands back — is storage-dtype-agnostic by construction."""
-        cfg = self.cfg
-        dt = x.dtype
-        dh = cfg.dim // cfg.num_heads
-        per_slot = getattr(pos, "ndim", 0) == 1
         q, k, v = self._attn_qkv(p, x, pos, adapter_ids)
-        b, h_q, t, _ = q.shape
+        attn, k_cache, v_cache = self._attn_core(
+            q, k, v, k_cache, v_cache, pos, x.dtype
+        )
+        out = self._attn_out(p, x, attn, tp_axis, adapter_ids)
+        return out, k_cache, v_cache
+
+    @jax.named_scope("attn_core")
+    def _attn_core(self, q, k, v, k_cache, v_cache, pos, dt):
+        """The part of `_block` between the projections: write the T
+        new K/V rows into the caches and attend over them. Returns
+        (attn [B, T, Hq*Dh] in `dt`, new_k, new_v)."""
+        cfg = self.cfg
+        per_slot = getattr(pos, "ndim", 0) == 1
+        b, h_q, t, dh = q.shape
 
         if self.rolling_cache:
             win = cfg.window
@@ -818,8 +832,7 @@ class GptDecoder:
             attn = jnp.einsum("bkgts,bksd->bkgtd", weights, v_att)
             attn = attn.reshape(b, h_q, t, dh)
             attn = attn.transpose(0, 2, 1, 3).reshape(b, t, h_q * dh)
-        out = self._attn_out(p, x, attn, tp_axis, adapter_ids)
-        return out, k_cache, v_cache
+        return attn, k_cache, v_cache
 
     def _step_fn(self, tp_axis: str | None = None):
         """The ONE step body (embed -> scan over blocks -> final LN ->
@@ -856,6 +869,7 @@ class GptDecoder:
 
         return step
 
+    @jax.named_scope("embed")
     def _embed_tokens(self, params, ids, pos, tp_axis=None):
         """Token (+learned position) embedding for a step at write
         head `pos` (scalar, or (B,) per-slot depths — continuous
@@ -879,6 +893,7 @@ class GptDecoder:
         )
         return (emb + posv).astype(cd)
 
+    @jax.named_scope("logits")
     def _final_logits(self, params, x):
         """Final norm + output head, fp32: tied to the embedding
         unless the checkpoint shipped a distinct lm_head (untied llama

@@ -2,7 +2,8 @@
 
 Split from `utils/profiling.py` on purpose: profiling captures device
 *traces* (one-shot, heavyweight, opt-in), obs counts and times
-*always-on* host-side events (near-free per sample, pull-based export).
+*always-on* host-side events (near-free per sample, pull-based export):
+counters, gauges and histograms in `metrics.py`, spans in `spans.py`.
 See ARCHITECTURE.md "Observability".
 """
 
@@ -14,8 +15,9 @@ from defer_tpu.obs.metrics import (
     counter_deltas,
     get_registry,
     log_buckets,
-    reset,
 )
+from defer_tpu.obs import metrics as _metrics
+from defer_tpu.obs import spans
 from defer_tpu.obs.export import PeriodicDumper, prometheus_text
 from defer_tpu.obs.serving import (
     DisaggMetrics,
@@ -24,6 +26,14 @@ from defer_tpu.obs.serving import (
     ServerStats,
     ServingMetrics,
 )
+
+
+def reset() -> None:
+    """Zero the process registry in place and clear the span log
+    (test isolation)."""
+    _metrics.reset()
+    spans.reset()
+
 
 __all__ = [
     "Counter",
@@ -41,4 +51,5 @@ __all__ = [
     "log_buckets",
     "prometheus_text",
     "reset",
+    "spans",
 ]

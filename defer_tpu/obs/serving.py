@@ -66,8 +66,9 @@ class ServingMetrics:
         )
         self.ttft = reg.histogram(
             "defer_ttft_seconds",
-            "Admission to first-token dispatch (host-side; the token "
-            "array may still be in flight on device)",
+            "submit() to first-token dispatch: queue wait plus prefill "
+            "(host-side; the token array may still be in flight on "
+            "device)",
             _LATENCY_BUCKETS, labels,
         )
         self.itl = reg.histogram(
@@ -158,10 +159,6 @@ class ServingMetrics:
             "Rows the gathered full-pool-view path would have read "
             "for the same ticks (B * max_blocks * block_size each)",
             labels,
-        )
-        self.kv_rows_last = reg.gauge(
-            "defer_kv_rows_read_last_tick",
-            "KV rows read by the most recent decode tick", labels,
         )
         # Dispatch-efficiency instruments (fused decode windows,
         # runtime/*.py `decode_window`): one host dispatch drives up

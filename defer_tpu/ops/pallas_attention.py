@@ -182,6 +182,7 @@ def _flash_fwd_impl(
         out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
         compiler_params=_grid_params("parallel", "parallel"),
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     return out.reshape(b, h, s_q, d)
 
@@ -382,6 +383,7 @@ def flash_decode(
         out_shape=jax.ShapeDtypeStruct((b, hkv, g_pad, d), q.dtype),
         compiler_params=_grid_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
+        name="flash_decode",
     )(pos1, qg, k, v)
     return out[:, :, :g, :].reshape(b, hq, d)
 
@@ -600,6 +602,7 @@ def paged_flash_decode(
         out_shape=jax.ShapeDtypeStruct((b, hkv, g_pad, d), q.dtype),
         compiler_params=_grid_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
+        name="paged_flash_decode",
     )(tables, pos1, *operands)
     return out[:, :, :g, :].reshape(b, hq, d)
 
@@ -818,6 +821,7 @@ def paged_flash_prefill(
         out_shape=jax.ShapeDtypeStruct((b, hkv, r_pad, d), q.dtype),
         compiler_params=_grid_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
+        name="paged_flash_prefill",
     )(tables, start1, *operands)
     out = out[:, :, :r, :].reshape(b, hkv, t_q, g, d)
     return out.transpose(0, 1, 3, 2, 4).reshape(b, hq, t_q, d)

@@ -91,6 +91,7 @@ from defer_tpu.models.quant import (
     dequantize_symmetric,
     quantize_symmetric,
 )
+from defer_tpu.obs import spans
 from defer_tpu.obs.serving import ServerStats, ServingMetrics
 from defer_tpu.ops.pallas_attention import _MASK_VALUE
 from defer_tpu.runtime.batching import (
@@ -2387,7 +2388,6 @@ class PagedDecodeServer:
         tp = self.tp
         self.obs.kv_rows_read.inc(rows_read // tp)
         self.obs.kv_rows_gathered.inc(baseline // tp)
-        self.obs.kv_rows_last.set(rows_read // tp)
 
     def _account_psums(self, n_forwards: int) -> None:
         """Count the cross-shard collectives `n_forwards` sharded
@@ -2462,26 +2462,28 @@ class PagedDecodeServer:
                 # the flat block math expects: [B, Hkv, MB*bs, Dh].
                 # An int8 pool dequantizes AT the gather (scale folds
                 # into the block values), so _block sees fp blocks.
-                kc = _pool_gather(pk_l, tables, dec.compute_dtype)
-                vc = _pool_gather(pv_l, tables, dec.compute_dtype)
-                b_, mb, hkv, _, dh = kc.shape
-                kc = kc.transpose(0, 2, 1, 3, 4).reshape(
-                    b_, hkv, mb * bs, dh
-                )
-                vc = vc.transpose(0, 2, 1, 3, 4).reshape(
-                    b_, hkv, mb * bs, dh
-                )
+                with jax.named_scope("kv_gather"):
+                    kc = _pool_gather(pk_l, tables, dec.compute_dtype)
+                    vc = _pool_gather(pv_l, tables, dec.compute_dtype)
+                    b_, mb, hkv, _, dh = kc.shape
+                    kc = kc.transpose(0, 2, 1, 3, 4).reshape(
+                        b_, hkv, mb * bs, dh
+                    )
+                    vc = vc.transpose(0, 2, 1, 3, 4).reshape(
+                        b_, hkv, mb * bs, dh
+                    )
                 out, kc, vc = dec._block(
                     p, x, kc, vc, pos, tp_axis=tp,
                     adapter_ids=adapter_ids,
                 )
                 # Scatter ONLY the new row back to its page.
-                blk = tables[rows, pos // bs]  # [B]
-                row = pos % bs
-                new_k = kc[rows, :, pos, :]  # [B, Hkv, Dh]
-                new_v = vc[rows, :, pos, :]
-                pk_l = _pool_write_rows(pk_l, blk, row, new_k)
-                pv_l = _pool_write_rows(pv_l, blk, row, new_v)
+                with jax.named_scope("kv_scatter"):
+                    blk = tables[rows, pos // bs]  # [B]
+                    row = pos % bs
+                    new_k = kc[rows, :, pos, :]  # [B, Hkv, Dh]
+                    new_v = vc[rows, :, pos, :]
+                    pk_l = _pool_write_rows(pk_l, blk, row, new_k)
+                    pv_l = _pool_write_rows(pv_l, blk, row, new_v)
                 return out, (pk_l, pv_l)
 
             x, (pk, pv) = lax.scan(
@@ -2526,11 +2528,17 @@ class PagedDecodeServer:
                 q, k_new, v_new = dec._attn_qkv(
                     p, x, pos, adapter_ids=adapter_ids
                 )
-                pk_l = _pool_write_rows(pk_l, blk_w, row_w, k_new[:, :, 0, :])
-                pv_l = _pool_write_rows(pv_l, blk_w, row_w, v_new[:, :, 0, :])
-                attn = _blockwise_attend(
-                    q, pk_l, pv_l, tables, pos, bs, nb_live, window
-                )
+                with jax.named_scope("kv_scatter"):
+                    pk_l = _pool_write_rows(
+                        pk_l, blk_w, row_w, k_new[:, :, 0, :]
+                    )
+                    pv_l = _pool_write_rows(
+                        pv_l, blk_w, row_w, v_new[:, :, 0, :]
+                    )
+                with jax.named_scope("attn_core"):
+                    attn = _blockwise_attend(
+                        q, pk_l, pv_l, tables, pos, bs, nb_live, window
+                    )
                 out = dec._attn_out(
                     p, x, attn, tp, adapter_ids=adapter_ids
                 )
@@ -2576,22 +2584,28 @@ class PagedDecodeServer:
                 q, k_new, v_new = dec._attn_qkv(
                     p, x, pos, adapter_ids=adapter_ids
                 )
-                pk_l = _pool_write_rows(pk_l, blk_w, row_w, k_new[:, :, 0, :])
-                pv_l = _pool_write_rows(pv_l, blk_w, row_w, v_new[:, :, 0, :])
+                with jax.named_scope("kv_scatter"):
+                    pk_l = _pool_write_rows(
+                        pk_l, blk_w, row_w, k_new[:, :, 0, :]
+                    )
+                    pv_l = _pool_write_rows(
+                        pv_l, blk_w, row_w, v_new[:, :, 0, :]
+                    )
                 b_, hq, _, dh = q.shape
                 quantized = isinstance(pk_l, dict)
-                attn = paged_flash_decode(
-                    q[:, :, 0, :],
-                    _pool_arr(pk_l),
-                    _pool_arr(pv_l),
-                    tables,
-                    pos,
-                    window=window,
-                    interpret=interpret,
-                    scale_k=pk_l["s"] if quantized else None,
-                    scale_v=pv_l["s"] if quantized else None,
-                )  # [B, Hq, Dh]
-                attn = attn.astype(x.dtype).reshape(b_, 1, hq * dh)
+                with jax.named_scope("attn_core"):
+                    attn = paged_flash_decode(
+                        q[:, :, 0, :],
+                        _pool_arr(pk_l),
+                        _pool_arr(pv_l),
+                        tables,
+                        pos,
+                        window=window,
+                        interpret=interpret,
+                        scale_k=pk_l["s"] if quantized else None,
+                        scale_v=pv_l["s"] if quantized else None,
+                    )  # [B, Hq, Dh]
+                    attn = attn.astype(x.dtype).reshape(b_, 1, hq * dh)
                 out = dec._attn_out(
                     p, x, attn, tp, adapter_ids=adapter_ids
                 )
@@ -3474,6 +3488,7 @@ class PagedDecodeServer:
     def _build_insert(self, skip: int = 0):
         bs = self.bs
 
+        @jax.named_scope("kv_insert")
         def insert(pk, pv, small_k, small_v, table_row):
             """Scatter a contiguous single-request prefill cache
             ([L, 1, Hkv, S, Dh]) into this request's pool blocks.
@@ -4259,6 +4274,14 @@ class PagedDecodeServer:
         return None
 
     def _admit(self) -> None:
+        waiting = len(self.pending) + len(self.pending_prefilled)
+        with spans.span("paged.admit") as sp:
+            self._admit_variant()
+            seated = waiting - len(self.pending) - len(self.pending_prefilled)
+            sp.counts["seated"] = seated
+            sp.keep = seated > 0  # a poll of an empty queue leaves no record
+
+    def _admit_variant(self) -> None:
         if self.prefill_budget is not None:
             # Mixed-mode admission: new prompts take SEATS and prefill
             # inside the decode dispatches (runtime/schedule.py) — the
@@ -4287,21 +4310,37 @@ class PagedDecodeServer:
                     return  # pool exhausted even after eviction
                 self.pending.popleft()
                 continue
-            t0 = prompt.shape[1]
-            P = self.prefix_len
-            n_shared = len(self.shared_blocks)
-            need = self._own_need(t0, steps)
-            if need > len(self.free):
+            if self._own_need(prompt.shape[1], steps) > len(self.free):
                 return  # pool exhausted: wait for a finisher
             self.pending.popleft()
+            with spans.span(
+                "paged.admit.seat", rid=rid, prompt_tokens=prompt.shape[1]
+            ) as seat:
+                self._admit_plain(
+                    i, rid, prompt, steps, adapter_id, samp, stop_seqs,
+                    cid, seat,
+                )
+
+    def _admit_plain(
+        self, i, rid, prompt, steps, adapter_id, samp, stop_seqs, cid, seat
+    ) -> None:
+        """Seat the queue's head in slot i the default way: its blocks,
+        a contiguous prefill that stalls every live slot, the rows
+        paged in, the first token. `seat` is the request's
+        `paged.admit.seat` span; the four phases are its children."""
+        t0 = prompt.shape[1]
+        P = self.prefix_len
+        with spans.span("paged.admit.seat.plan"):
+            n_shared = len(self.shared_blocks)
+            need = self._own_need(t0, steps)
             blocks = [self.free.pop() for _ in range(need)]
             self.obs.requests_admitted.inc()
             self.obs.prefill_tokens.inc(t0)
             # Strict lookup (same rule as the radix/prefilled paths):
             # a missing rid is a bug, not a zero wait.
-            self.obs.queue_wait.observe(
-                time.perf_counter() - self._submit_t[rid]
-            )
+            t_submit = self._submit_t[rid]
+            queue_s = time.perf_counter() - t_submit
+            self.obs.queue_wait.observe(queue_s)
             self._build()
             self.blocks_peak = max(
                 self.blocks_peak, self.blocks_in_use + need
@@ -4311,12 +4350,13 @@ class PagedDecodeServer:
                 table_row[j] = blk
             for j, blk in enumerate(blocks):
                 table_row[n_shared + j] = blk
-            if self.prefill_chunk is not None or self.pp > 1:
-                # Pool-native chunked prefill: rows land in the
-                # allocated blocks as each chunk computes, and a
-                # global shared prefix (base=P) is read from ITS pool
-                # blocks by the block-table attention — no contiguous
-                # prefix lane, no insert pass.
+        if self.prefill_chunk is not None or self.pp > 1:
+            # Pool-native chunked prefill: rows land in the
+            # allocated blocks as each chunk computes, and a
+            # global shared prefix (base=P) is read from ITS pool
+            # blocks by the block-table attention — no contiguous
+            # prefix lane, no insert pass.
+            with spans.span("paged.admit.seat.prefill"):
                 logits_row = self._prefill_paged(
                     prompt,
                     table_row,
@@ -4324,16 +4364,18 @@ class PagedDecodeServer:
                     keep_from=0,
                     adapter_id=adapter_id,
                 )
-            else:
-                # Contiguous prefill through the flat decoder — pow2
-                # bucketed like the flat server, so the compiled
-                # prefill shape set stays tiny — then page the rows
-                # in. With a shared prefix the suffix prefills at
-                # offset P on a COPY of the contiguous prefix lane
-                # (the flat step donates its cache), and only rows
-                # past the shared blocks are paged.
+        else:
+            # Contiguous prefill through the flat decoder — pow2
+            # bucketed like the flat server, so the compiled
+            # prefill shape set stays tiny — then page the rows
+            # in. With a shared prefix the suffix prefills at
+            # offset P on a COPY of the contiguous prefix lane
+            # (the flat step donates its cache), and only rows
+            # past the shared blocks are paged.
+            with spans.span("paged.admit.seat.prefill"):
                 pad = 1 << (t0 - 1).bit_length()
                 pad = min(pad, self.dec.cfg.max_len - P)
+                seat.counts["pad"] = pad
                 padded = jnp.concatenate(
                     [prompt, jnp.zeros((1, pad - t0), prompt.dtype)],
                     axis=1,
@@ -4356,6 +4398,7 @@ class PagedDecodeServer:
                 )
                 self._account_psums(1)
                 self._note_prefill_stall(1)
+            with spans.span("paged.admit.seat.insert"):
                 self.pool_k, self.pool_v = self._insert(
                     self.pool_k,
                     self.pool_v,
@@ -4364,6 +4407,7 @@ class PagedDecodeServer:
                     jnp.asarray(table_row),
                 )
                 logits_row = logits[:, t0 - 1, :]
+        with spans.span("paged.admit.seat.first_token"):
             first = self._first_token(
                 i, samp, logits_row, prompt.dtype, cid
             )
@@ -4379,6 +4423,9 @@ class PagedDecodeServer:
                 "sampling": samp is not None,
                 "stop": matcher_or_none(stop_seqs),
                 "cid": cid,
+                # For the `paged.request` span _finish records.
+                "submit_t": t_submit,
+                "queue_s": queue_s,
             }
             self.slots[i] = slot
             if self._draft is not None and not slot["sampling"]:
@@ -4399,15 +4446,15 @@ class PagedDecodeServer:
             self._update_pool_gauges()
             # Host transfer only when eos/streaming/stop matching
             # consumes the value (same guard as _tick) — the plain
-            # path stays async.
+            # path stays async. It is where the host waits for the
+            # prefill to end, so it closes the phase.
             need_host = (
                 self.eos_id is not None
                 or self.on_token is not None
                 or slot["stop"] is not None
             )
-            self._emit_token(
-                i, slot, int(first[0, 0]) if need_host else None
-            )
+            tok = int(first[0, 0]) if need_host else None
+        self._emit_token(i, slot, tok)
 
     # -- mixed-mode admission + tick (prefill_budget=) ----------------
 
@@ -4894,165 +4941,193 @@ class PagedDecodeServer:
         self.window_tokens += accepted
 
     def _tick(self) -> None:
+        live = sum(s is not None for s in self.slots)
+        with spans.span("paged.tick", live=live) as sp:
+            sp.keep = live > 0  # a poll of an empty server leaves no record
+            sp.counts["kind"] = self._tick_variant()
+
+    def _tick_variant(self) -> str:
+        """Run the tick this server's mode calls for; which it was."""
         if self.pp > 1:
-            return self._tick_pp()
+            self._tick_pp()
+            return "pp"
         if self.spec_k:
             if self.decode_window > 1:
-                return self._tick_spec_window()
-            return self._tick_spec()
+                self._tick_spec_window()
+            else:
+                self._tick_spec()
+            return "spec"
         if self._seat_slots():
             # Mixed mode engages only while a seat is mid-prefill;
             # pure-decode stretches fall through to the EXACT plain /
             # window programs (the prefill_budget=None bit-identity
             # contract, and the window scan's dispatch amortization).
-            return self._tick_mixed()
+            self._tick_mixed()
+            return "mixed"
         if self.decode_window > 1:
-            return self._tick_window()
+            self._tick_window()
+            return "window"
+        self._tick_plain()
+        return "plain"
+
+    def _tick_plain(self) -> None:
+        """One token for every live slot: the default tick, and the
+        one whose phases the span log holds (plan, dispatch, sample,
+        sync, drain — `obs/spans.py`)."""
         live = [s is not None for s in self.slots]
         if not any(live):
             return
-        self._build()
-        # Persistent [B,1] device feed (constructor note): admissions
-        # set their row, draws below overwrite the whole vector — no
-        # per-tick concat of max_batch [1,1] arrays.
-        feed = self._feed
-        # Idle slots write into trash block 0 at position 0.
-        posm = np.where(live, self.pos, 0).astype(np.int32)
-        pos = jnp.asarray(posm)
-        # COPY the mutable host state before handing it to the device:
-        # jnp.asarray of a numpy array is zero-copy on CPU, and the
-        # host loop mutates tables/adapter in place (finish/admission)
-        # while the async-dispatched step may still be reading them —
-        # the aliasing race corrupts first-execution results.
-        logits, self.pool_k, self.pool_v = self._step(
-            self.params,
-            self.pool_k,
-            self.pool_v,
-            jnp.asarray(self.tables.copy()),
-            pos,
-            feed,
-            jnp.asarray(self.adapter.copy()),
-        )
-        self.ticks += 1
-        self.dispatches += 1
-        n_live = sum(live)
-        now = time.perf_counter()
-        if self._last_tick_t is not None:
-            self.obs.itl.observe(now - self._last_tick_t, n_live)
-        self._last_tick_t = now
-        self.obs.ticks.inc()
-        self.obs.host_dispatches.inc()
-        # Every decode tick moves the stall fraction's denominator —
-        # republished here so the gauge decays as decode resumes (the
-        # [contract.mixed] budget gate reads it).
-        self._update_stall_fraction()
-        self._account_psums(1)
-        self.obs.tokens_per_dispatch.set(float(n_live))
-        self.window_tokens += n_live
-        # K/V rows the attention path read this tick vs the gathered
-        # baseline (host-side, exact — the counters the bandwidth win
-        # is pinned by; units in obs/serving.py). "blockwise" reads
-        # every slot to the batch's deepest live block; "pallas"
-        # clamps per slot, so each reads only its own live span.
-        baseline = self.B * self.MB * self.bs
-        if self.attention == "gathered":
-            rows_read = baseline
-        elif self.attention == "blockwise":
-            rows_read = (
-                self.B * (int(posm.max()) // self.bs + 1) * self.bs
+        with spans.span("paged.tick.plan"):
+            self._build()
+            # Persistent [B,1] device feed (constructor note):
+            # admissions set their row, draws below overwrite the
+            # whole vector — no per-tick concat of max_batch [1,1]
+            # arrays.
+            feed = self._feed
+            # Idle slots write into trash block 0 at position 0.
+            posm = np.where(live, self.pos, 0).astype(np.int32)
+            pos = jnp.asarray(posm)
+            # COPY the mutable host state before handing it to the
+            # device: jnp.asarray of a numpy array is zero-copy on
+            # CPU, and the host loop mutates tables/adapter in place
+            # (finish/admission) while the async-dispatched step may
+            # still be reading them — the aliasing race corrupts
+            # first-execution results.
+            tables = jnp.asarray(self.tables.copy())
+            adapter = jnp.asarray(self.adapter.copy())
+        with spans.span("paged.tick.dispatch"):
+            logits, self.pool_k, self.pool_v = self._step(
+                self.params,
+                self.pool_k,
+                self.pool_v,
+                tables,
+                pos,
+                feed,
+                adapter,
             )
-        else:  # pallas
-            win = self.dec.cfg.window
-            lo = (
-                np.maximum(posm - win + 1, 0) // self.bs
-                if win is not None
-                else 0
-            )
-            rows_read = int(np.sum(posm // self.bs - lo + 1)) * self.bs
-        self._account_kv_rows(rows_read, baseline)
-        ll = logits[:, -1, :]
-        sm = self._sampler
-        # Constrained rows (defer_tpu/constrain/): fold the DFA mask
-        # into the batched logits BEFORE argmax/draw, advance states
-        # after. Guarded by the host mirror so unconstrained serving
-        # dispatches the exact pre-constraint op sequence.
-        constrained = any(sm.row_constrained)
-        if constrained:
-            crow, cacc = crt.constrain_rows(
-                self._ctrans, self._cacc, sm.cid, sm.cstate
-            )
-            cmask = crt.constrain_mask(crow, cacc, self.eos_id)
-            cvec = jnp.asarray(sm.row_constrained)
-            # Dead end (hand-built DFAs only — dfa.py prunes): no
-            # admissible token. Force eos so the row freezes; the
-            # drain drops the forced token and surfaces the error.
-            dead = cvec & jnp.asarray(live) & ~cmask.any(-1)
-            ll = crt.fold_mask(ll, cmask)
-        if any(s is not None and s["sampling"] for s in self.slots):
-            nxt = self._sampler.draw(ll)
-        else:
-            nxt = jnp.argmax(ll, axis=-1)
-        if constrained:
-            nxt = jnp.where(dead, self.eos_id, nxt)
-            sm.cstate = crt.advance_state(
-                crow, sm.cstate, nxt, cvec & ~dead
-            )
-            mfrac = crt.masked_frac(cmask, cvec & jnp.asarray(live))
-        self._feed = nxt[:, None].astype(jnp.int32)
-        # Host transfer only when eos/streaming/stop matching needs
-        # the values — the plain path stays async (same guard as the
-        # flat server).
-        need_host = (
-            self.eos_id is not None
-            or self.on_token is not None
-            or any(
-                s is not None and s["stop"] is not None
-                for s in self.slots
-            )
-        )
-        # analysis: ignore[host-sync-in-hot-loop] single batched
-        # transfer per WINDOW (a window of one token here), and only
-        # when an eos/stop/stream consumer needs host tokens — the
-        # sync this serving loop is designed around
-        host_nxt = np.asarray(nxt) if need_host else None
-        if constrained:
-            # analysis: ignore[host-sync-in-hot-loop] one batched
-            # per-tick transfer of the dead-end flags + mask
-            # fractions, and only while a constrained row is live
-            dead_host = np.asarray(dead)
-            # analysis: ignore[host-sync-in-hot-loop] ready with the
-            # vector above (same sync point)
-            mfrac_host = np.asarray(mfrac)
-        for i, slot in enumerate(self.slots):
-            if slot is None:
-                continue
-            if constrained and slot["cid"]:
-                if bool(dead_host[i]):
-                    # The forced eos never enters the output: the
-                    # request ends at its last admissible token with
-                    # a per-request error, not a hang.
-                    self.errors[slot["rid"]] = (
-                        "constraint dead end: DFA state admits no "
-                        "token and is not accepting"
-                    )
-                    self.constraint_dead_ends_n += 1
-                    self.obs.constrain_dead_ends.inc()
-                    slot["remaining"] = 0
-                    self._finish(i)
-                    continue
-                self.constrained_tokens_n += 1
-                self.obs.constrained_tokens.inc()
-                self.obs.constrain_masked_frac.observe(
-                    float(mfrac_host[i])
+        with spans.span("paged.tick.sample"):
+            self.ticks += 1
+            self.dispatches += 1
+            n_live = sum(live)
+            now = time.perf_counter()
+            if self._last_tick_t is not None:
+                self.obs.itl.observe(now - self._last_tick_t, n_live)
+            self._last_tick_t = now
+            self.obs.ticks.inc()
+            self.obs.host_dispatches.inc()
+            # Every decode tick moves the stall fraction's denominator —
+            # republished here so the gauge decays as decode resumes (the
+            # [contract.mixed] budget gate reads it).
+            self._update_stall_fraction()
+            self._account_psums(1)
+            self.obs.tokens_per_dispatch.set(float(n_live))
+            self.window_tokens += n_live
+            # K/V rows the attention path read this tick vs the gathered
+            # baseline (host-side, exact — the counters the bandwidth win
+            # is pinned by; units in obs/serving.py). "blockwise" reads
+            # every slot to the batch's deepest live block; "pallas"
+            # clamps per slot, so each reads only its own live span.
+            baseline = self.B * self.MB * self.bs
+            if self.attention == "gathered":
+                rows_read = baseline
+            elif self.attention == "blockwise":
+                rows_read = (
+                    self.B * (int(posm.max()) // self.bs + 1) * self.bs
                 )
-            tok = nxt[i][None, None].astype(slot["last"].dtype)
-            slot["last"] = tok
-            slot["toks"].append(tok)
-            slot["remaining"] -= 1
-            self.pos[i] += 1
-            self._emit_token(
-                i, slot, int(host_nxt[i]) if host_nxt is not None else None
+            else:  # pallas
+                win = self.dec.cfg.window
+                lo = (
+                    np.maximum(posm - win + 1, 0) // self.bs
+                    if win is not None
+                    else 0
+                )
+                rows_read = int(np.sum(posm // self.bs - lo + 1)) * self.bs
+            self._account_kv_rows(rows_read, baseline)
+            ll = logits[:, -1, :]
+            sm = self._sampler
+            # Constrained rows (defer_tpu/constrain/): fold the DFA mask
+            # into the batched logits BEFORE argmax/draw, advance states
+            # after. Guarded by the host mirror so unconstrained serving
+            # dispatches the exact pre-constraint op sequence.
+            constrained = any(sm.row_constrained)
+            if constrained:
+                crow, cacc = crt.constrain_rows(
+                    self._ctrans, self._cacc, sm.cid, sm.cstate
+                )
+                cmask = crt.constrain_mask(crow, cacc, self.eos_id)
+                cvec = jnp.asarray(sm.row_constrained)
+                # Dead end (hand-built DFAs only — dfa.py prunes): no
+                # admissible token. Force eos so the row freezes; the
+                # drain drops the forced token and surfaces the error.
+                dead = cvec & jnp.asarray(live) & ~cmask.any(-1)
+                ll = crt.fold_mask(ll, cmask)
+            if any(s is not None and s["sampling"] for s in self.slots):
+                nxt = self._sampler.draw(ll)
+            else:
+                nxt = jnp.argmax(ll, axis=-1)
+            if constrained:
+                nxt = jnp.where(dead, self.eos_id, nxt)
+                sm.cstate = crt.advance_state(
+                    crow, sm.cstate, nxt, cvec & ~dead
+                )
+                mfrac = crt.masked_frac(cmask, cvec & jnp.asarray(live))
+            self._feed = nxt[:, None].astype(jnp.int32)
+            # Host transfer only when eos/streaming/stop matching needs
+            # the values — the plain path stays async (same guard as the
+            # flat server).
+            need_host = (
+                self.eos_id is not None
+                or self.on_token is not None
+                or any(
+                    s is not None and s["stop"] is not None
+                    for s in self.slots
+                )
             )
+        with spans.span("paged.tick.sync"):
+            # analysis: ignore[host-sync-in-hot-loop] single batched
+            # transfer per WINDOW (a window of one token here), and only
+            # when an eos/stop/stream consumer needs host tokens — the
+            # sync this serving loop is designed around
+            host_nxt = np.asarray(nxt) if need_host else None
+            if constrained:
+                # analysis: ignore[host-sync-in-hot-loop] one batched
+                # per-tick transfer of the dead-end flags + mask
+                # fractions, and only while a constrained row is live
+                dead_host = np.asarray(dead)
+                # analysis: ignore[host-sync-in-hot-loop] ready with the
+                # vector above (same sync point)
+                mfrac_host = np.asarray(mfrac)
+        with spans.span("paged.tick.drain", tokens=n_live):
+            for i, slot in enumerate(self.slots):
+                if slot is None:
+                    continue
+                if constrained and slot["cid"]:
+                    if bool(dead_host[i]):
+                        # The forced eos never enters the output: the
+                        # request ends at its last admissible token with
+                        # a per-request error, not a hang.
+                        self.errors[slot["rid"]] = (
+                            "constraint dead end: DFA state admits no "
+                            "token and is not accepting"
+                        )
+                        self.constraint_dead_ends_n += 1
+                        self.obs.constrain_dead_ends.inc()
+                        slot["remaining"] = 0
+                        self._finish(i)
+                        continue
+                    self.constrained_tokens_n += 1
+                    self.obs.constrained_tokens.inc()
+                    self.obs.constrain_masked_frac.observe(
+                        float(mfrac_host[i])
+                    )
+                tok = nxt[i][None, None].astype(slot["last"].dtype)
+                slot["last"] = tok
+                slot["toks"].append(tok)
+                slot["remaining"] -= 1
+                self.pos[i] += 1
+                self._emit_token(
+                    i, slot, int(host_nxt[i]) if host_nxt is not None else None
+                )
 
     def _tick_spec(self) -> None:
         """One speculative round: TWO host dispatches advance every
@@ -6207,28 +6282,38 @@ class PagedDecodeServer:
 
     def _finish(self, i: int) -> None:
         slot = self.slots[i]
-        self.obs.requests_finished.inc()
-        self.done[slot["rid"]] = jnp.concatenate(slot["toks"], axis=1)
-        if self.radix is not None:
-            # Shared blocks deref (parking at refcount 0 for later
-            # revival); only privately owned blocks free immediately.
-            # Released DEEPEST-FIRST so LRU eviction reclaims the
-            # deep end of a chain before its shallow (more reusable,
-            # and prerequisite-for-lookup) blocks.
-            for blk in reversed(slot.get("shared", ())):
-                self.radix.release(blk)
-        self.free.extend(slot["blocks"])
-        self.tables[i] = 0
-        self.pos[i] = 0
-        self.adapter[i] = 0
-        self.slots[i] = None
-        if self._draft is not None:
-            self._draft.release(i)
-        # Release the slot's sampling policy row NOW, not at reuse —
-        # a lingering row_sort would drag every later tick through the
-        # sorting sampler (decode_server.SlotSampler.release).
-        self._sampler.release(i)
-        self._update_pool_gauges()
+        # Arrays joined below beside the prompt: one a token on the
+        # default path (the window and spec drains append several).
+        n_toks = len(slot["toks"]) - 1
+        with spans.span("paged.finish", rid=slot["rid"], tokens=n_toks):
+            self.obs.requests_finished.inc()
+            self.done[slot["rid"]] = jnp.concatenate(slot["toks"], axis=1)
+            if self.radix is not None:
+                # Shared blocks deref (parking at refcount 0 for later
+                # revival); only privately owned blocks free immediately.
+                # Released DEEPEST-FIRST so LRU eviction reclaims the
+                # deep end of a chain before its shallow (more reusable,
+                # and prerequisite-for-lookup) blocks.
+                for blk in reversed(slot.get("shared", ())):
+                    self.radix.release(blk)
+            self.free.extend(slot["blocks"])
+            self.tables[i] = 0
+            self.pos[i] = 0
+            self.adapter[i] = 0
+            self.slots[i] = None
+            if self._draft is not None:
+                self._draft.release(i)
+            # Release the slot's sampling policy row NOW, not at reuse —
+            # a lingering row_sort would drag every later tick through the
+            # sorting sampler (decode_server.SlotSampler.release).
+            self._sampler.release(i)
+            self._update_pool_gauges()
+        if "submit_t" in slot:  # seated by the default admission
+            spans.record(
+                "paged.request", slot["submit_t"], time.perf_counter(),
+                rid=slot["rid"], queue_s=slot["queue_s"],
+                prompt_tokens=slot["toks"][0].shape[1], tokens=n_toks,
+            )
 
 
 def serve_paged(
