@@ -156,7 +156,7 @@ class ServingMetrics:
         )
         self.kv_rows_gathered = reg.counter(
             "defer_kv_rows_gathered_baseline_total",
-            "Rows the gathered full-pool-view path would have read "
+            "Rows a gather of every slot's whole block table reads "
             "for the same ticks (B * max_blocks * block_size each)",
             labels,
         )

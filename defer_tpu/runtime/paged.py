@@ -19,10 +19,14 @@ Static-shape design (everything jits once):
         run the EXACT SAME block math as the flat decoder
         (GptDecoder._block) — numerical parity is inherited, not
         re-proven (bit-exact vs the flat server at tested scales) —
-        then scatter the single new K/V row back to its block. Per
-        tick it reads O(B * max_blocks * block_size) rows regardless
-        of request depth: the reference path, and the baseline the
-        others are measured against.
+        then scatter the single new K/V row back to its block. The
+        plain tick gathers the table up to the rung above the deepest
+        live slot (`span_rungs`: a quarter, three eighths or the
+        whole, one program per rung built before the first tick), so
+        it reads O(B * rung * block_size) rows; every other tick kind
+        reads the whole table, O(B * max_blocks * block_size) rows
+        regardless of request depth, which is the baseline all paths
+        are measured against.
       - "blockwise": attend THROUGH the block table — scatter the new
         K/V row into the pool first, then fold pool blocks into an
         online-softmax carry (running max / denominator,
@@ -70,6 +74,7 @@ allocated blocks in one jitted op.
 
 from __future__ import annotations
 
+import bisect
 import collections
 import hashlib
 import queue
@@ -110,6 +115,43 @@ def _pool_arr(pool):
     Dh]): the int8 payload of a quantized {"q","s"} pool, or the fp
     pool itself."""
     return pool["q"] if isinstance(pool, dict) else pool
+
+
+# Sixteenths of the table span at which the gathered decode step is
+# built: a quarter, three eighths, the whole. Every rung is one more
+# program traced, lowered and loaded at each server's start (0.7-0.9 s
+# each at Mistral-7B's widths on a v5e's host, PERF.md PR 26), so the
+# ladder is held to what chat-length traffic on a long table needs.
+_RUNG_SIXTEENTHS = (4, 6, 16)
+
+
+def span_rungs(mb: int) -> tuple[int, ...]:
+    """The ladder of table spans, in columns of a `mb`-column block
+    table, that the gathered decode step is built for: each rung a
+    share of the table rounded up to whole blocks, duplicates dropped
+    (a table too small to split holds fewer rungs), the last the whole
+    table."""
+    return tuple(sorted({-(-mb * s // 16) for s in _RUNG_SIXTEENTHS}))
+
+
+def pick_rung(rungs: tuple[int, ...], bs: int, depth: int) -> int:
+    """The lowest rung whose rows hold position `depth`."""
+    return rungs[bisect.bisect_right(rungs, depth // bs)]
+
+
+class _SpanSteps:
+    """The decode step as the plain tick calls it: one program per
+    table span, lowered and compiled from abstract shapes before the
+    first tick and picked by the width of the table it is handed, so
+    that a depth crossing a rung builds nothing. `lower` is the jitted
+    function's, for whoever reads the program."""
+
+    def __init__(self, jitted, programs: dict):
+        self.programs = programs
+        self.lower = jitted.lower
+
+    def __call__(self, params, pk, pv, tables, *rest):
+        return self.programs[tables.shape[1]](params, pk, pv, tables, *rest)
 
 
 def _pool_gather(pool_l, idx, dtype):
@@ -1477,6 +1519,13 @@ class PagedDecodeServer:
         cfg = dec.cfg
         # Max logical blocks any sequence can span.
         self.MB = -(-cfg.max_len // block_size)
+        # The gathered step's cost is linear in the span it gathers, so
+        # the plain tick hands it the table up to the rung above the
+        # deepest live slot; the other attention paths read through
+        # the whole table and bound their own reads.
+        self._rungs = (
+            span_rungs(self.MB) if attention == "gathered" else (self.MB,)
+        )
         dh = cfg.dim // cfg.num_heads
         self.kv_dtype = kv_dtype
         self.num_blocks = num_blocks
@@ -2335,13 +2384,13 @@ class PagedDecodeServer:
             "blockwise": self._build_step_blockwise,
             "pallas": self._build_step_pallas,
         }
-        self._step = cached_step(
-            self.dec,
-            (
-                "paged_step", self.bs, self.attention, self.kv_dtype,
-                self._mesh_key,
-            ),
-            builders[self.attention],
+        step_key = (
+            "paged_step", self.bs, self.attention, self.kv_dtype,
+            self._mesh_key,
+        )
+        jitted = cached_step(self.dec, step_key, builders[self.attention])
+        self._step = _SpanSteps(
+            jitted, self._build_span_programs(jitted, step_key)
         )
         skip = len(self.shared_blocks)
         self._insert = cached_step(
@@ -2363,6 +2412,51 @@ class PagedDecodeServer:
                 ),
                 self._build_insert_dynamic,
             )
+
+    def _build_span_programs(self, jitted, step_key) -> dict:
+        """The step compiled for every rung of the ladder, by table
+        columns; none for a server whose ticks never call the step
+        alone (windowed, speculative). Built here, before the first
+        tick: a span first met while serving would stall every live
+        slot for a compile. Memoised on the decoder like the jitted
+        function, and keyed on every shape a program is fixed to."""
+        if self.decode_window > 1 or self.spec_k:
+            return {}
+        from defer_tpu.utils.memo import cached_step
+
+        # An array that was placed (a mesh, `device=`) says where the
+        # program runs; with none placed it runs on the default device
+        # and its outputs stay uncommitted, as a jit call's.
+        fixed = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype,
+                sharding=a.sharding
+                if getattr(a, "committed", False) else None,
+            ),
+            (self.params, self.pool_k, self.pool_v),
+        )
+        leaves, treedef = jax.tree.flatten(fixed)
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        def build(nb):
+            with spans.span(
+                "jax.build", kind="paged_step", span_rows=nb * self.bs
+            ):
+                return jitted.lower(
+                    *fixed, i32(self.B, nb), i32(self.B),
+                    i32(self.B, 1), i32(self.B),
+                ).compile()
+
+        return {
+            nb: cached_step(
+                self.dec,
+                step_key + (self.B, nb, treedef, tuple(leaves)),
+                lambda: build(nb),
+            )
+            for nb in self._rungs
+        }
 
     def _tp_axis(self):
         """The tp_axis threaded into the tick bodies: the mesh's model
@@ -4944,39 +5038,41 @@ class PagedDecodeServer:
         live = sum(s is not None for s in self.slots)
         with spans.span("paged.tick", live=live) as sp:
             sp.keep = live > 0  # a poll of an empty server leaves no record
-            sp.counts["kind"] = self._tick_variant()
+            sp.counts.update(self._tick_variant())
 
-    def _tick_variant(self) -> str:
-        """Run the tick this server's mode calls for; which it was."""
+    def _tick_variant(self) -> dict:
+        """Run the tick this server's mode calls for; what to record
+        of it: which `kind` it was and, for the plain tick, the
+        `span_rows` of each slot's table that its step was handed."""
         if self.pp > 1:
             self._tick_pp()
-            return "pp"
+            return {"kind": "pp"}
         if self.spec_k:
             if self.decode_window > 1:
                 self._tick_spec_window()
             else:
                 self._tick_spec()
-            return "spec"
+            return {"kind": "spec"}
         if self._seat_slots():
             # Mixed mode engages only while a seat is mid-prefill;
             # pure-decode stretches fall through to the EXACT plain /
             # window programs (the prefill_budget=None bit-identity
             # contract, and the window scan's dispatch amortization).
             self._tick_mixed()
-            return "mixed"
+            return {"kind": "mixed"}
         if self.decode_window > 1:
             self._tick_window()
-            return "window"
-        self._tick_plain()
-        return "plain"
+            return {"kind": "window"}
+        return {"kind": "plain", "span_rows": self._tick_plain()}
 
-    def _tick_plain(self) -> None:
+    def _tick_plain(self) -> int:
         """One token for every live slot: the default tick, and the
         one whose phases the span log holds (plan, dispatch, sample,
-        sync, drain — `obs/spans.py`)."""
+        sync, drain — `obs/spans.py`). Returns the rows of each slot's
+        table that the step was handed."""
         live = [s is not None for s in self.slots]
         if not any(live):
-            return
+            return 0
         with spans.span("paged.tick.plan"):
             self._build()
             # Persistent [B,1] device feed (constructor note):
@@ -4993,7 +5089,11 @@ class PagedDecodeServer:
             # (finish/admission) while the async-dispatched step may
             # still be reading them — the aliasing race corrupts
             # first-execution results.
-            tables = jnp.asarray(self.tables.copy())
+            # The table up to the rung above the deepest live slot:
+            # rows past a slot's position are masked, so the columns
+            # dropped here change no logit.
+            nb = pick_rung(self._rungs, self.bs, int(posm.max()))
+            tables = jnp.asarray(self.tables[:, :nb].copy())
             adapter = jnp.asarray(self.adapter.copy())
         with spans.span("paged.tick.dispatch"):
             logits, self.pool_k, self.pool_v = self._step(
@@ -5024,12 +5124,13 @@ class PagedDecodeServer:
             self.window_tokens += n_live
             # K/V rows the attention path read this tick vs the gathered
             # baseline (host-side, exact — the counters the bandwidth win
-            # is pinned by; units in obs/serving.py). "blockwise" reads
-            # every slot to the batch's deepest live block; "pallas"
+            # is pinned by; units in obs/serving.py). "gathered" reads
+            # every slot to the rung above the batch's deepest live
+            # slot, "blockwise" to its deepest live block; "pallas"
             # clamps per slot, so each reads only its own live span.
             baseline = self.B * self.MB * self.bs
             if self.attention == "gathered":
-                rows_read = baseline
+                rows_read = self.B * nb * self.bs
             elif self.attention == "blockwise":
                 rows_read = (
                     self.B * (int(posm.max()) // self.bs + 1) * self.bs
@@ -5128,6 +5229,7 @@ class PagedDecodeServer:
                 self._emit_token(
                     i, slot, int(host_nxt[i]) if host_nxt is not None else None
                 )
+        return nb * self.bs
 
     def _tick_spec(self) -> None:
         """One speculative round: TWO host dispatches advance every

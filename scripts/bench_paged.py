@@ -2,10 +2,11 @@
 """Paged-decode attention microbench: tokens/sec and estimated K/V
 bytes read per tick for each attention mode, printed as ONE JSON line.
 
-The point being measured: the gathered path's per-tick HBM traffic is
-O(B * max_blocks * block_size) regardless of request depth, while the
-block-native paths ("blockwise", "pallas") read only live blocks —
-the new obs counters (defer_kv_rows_read_total vs the gathered
+The point being measured: a gather of every slot's whole table reads
+O(B * max_blocks * block_size) rows a tick regardless of request
+depth; the gathered path reads to the rung above the deepest live slot
+and the block-native paths ("blockwise", "pallas") only live blocks —
+the obs counters (defer_kv_rows_read_total vs the gathered
 baseline) make the ratio exact, and this bench prices it per mode on
 one identical request mix.
 

@@ -17,9 +17,15 @@ import numpy as np
 import pytest
 
 from defer_tpu import obs
+from defer_tpu.obs import spans
 from defer_tpu.models.gpt import SamplingParams, tiny_gpt
 from defer_tpu.models.llama import tiny_llama
-from defer_tpu.runtime.paged import PagedDecodeServer, serve_paged
+from defer_tpu.runtime.paged import (
+    PagedDecodeServer,
+    pick_rung,
+    serve_paged,
+    span_rungs,
+)
 
 
 def _mixed_requests(vocab, rng_seed=5):
@@ -106,12 +112,12 @@ def test_blockwise_matches_solo_generate_gpt():
 
 
 def test_kv_rows_scale_with_depth_not_pool():
-    """The acceptance criterion for the whole PR, on the obs
-    counters: the gathered path reads B * max_blocks * block_size
-    rows per tick regardless of occupancy; blockwise reads only live
-    depth — strictly fewer rows on the same workload, and the SAME
-    row count when the pool grows (reads scale with request depth,
-    not pool size)."""
+    """The acceptance criterion on the obs counters: against the
+    baseline of B * max_blocks * block_size rows a tick, the gathered
+    path reads to the rung above the deepest live slot and blockwise
+    to the deepest live block — blockwise <= gathered < baseline on
+    the same workload — and both read the SAME rows when the pool
+    grows (reads scale with request depth, not pool size)."""
     dec = tiny_gpt(64)
     params = dec.init(jax.random.key(0))
     reqs = _mixed_requests(dec.cfg.vocab_size)
@@ -129,17 +135,135 @@ def test_kv_rows_scale_with_depth_not_pool():
         return read, base, stats["ticks"]
 
     g_read, g_base, g_ticks = rows("gathered", 18)
-    assert g_read == g_base > 0  # gathered reads the full view
     b_read, b_base, b_ticks = rows("blockwise", 18)
     assert b_ticks == g_ticks  # same schedule, comparable baselines
     assert b_base == g_base
-    assert 0 < b_read < b_base  # depth-scaled reads beat the baseline
-    # Growing the pool must not change what blockwise reads: both
-    # pools admit the whole mix immediately, so the schedule — and
-    # therefore live depth per tick — is identical.
-    b_read2, _, b_ticks2 = rows("blockwise", 44)
-    assert b_ticks2 == b_ticks
-    assert b_read2 == b_read
+    assert 0 < b_read <= g_read < g_base  # depth-scaled reads beat it
+    # Growing the pool must not change what either reads: both pools
+    # admit the whole mix immediately, so the schedule — and therefore
+    # live depth per tick — is identical.
+    for attention, read, ticks in [
+        ("gathered", g_read, g_ticks), ("blockwise", b_read, b_ticks),
+    ]:
+        read2, _, ticks2 = rows(attention, 44)
+        assert ticks2 == ticks
+        assert read2 == read
+
+
+# -- the gathered step's span ladder --------------------------------------
+
+
+@pytest.mark.parametrize(
+    "mb,bs", [(256, 16), (16, 4), (8, 8), (100, 16), (3, 8), (1, 16), (37, 2)]
+)
+def test_span_ladder_holds_every_depth(mb, bs):
+    """Every position of the table maps to the lowest rung that holds
+    it; rungs are whole blocks, at most 16, the last the whole table."""
+    rungs = span_rungs(mb)
+    assert rungs == tuple(sorted(set(rungs))) and len(rungs) <= 16
+    assert rungs[0] >= 1 and rungs[-1] == mb
+    assert all(isinstance(r, int) for r in rungs)
+    for depth in range(mb * bs):
+        nb = pick_rung(rungs, bs, depth)
+        assert nb in rungs and nb * bs > depth
+        assert all(r * bs <= depth for r in rungs if r < nb)
+    if (mb, bs) == (256, 16):
+        assert [r * bs for r in rungs] == [1024, 1536, 4096]
+
+
+def _ladder_requests(vocab):
+    """Through two slots of a 64-row table in blocks of 4 (rungs at 16,
+    24 and 64 rows): a short request, one that climbs from 9 rows over
+    all three rungs, and one that starts on the last rung and ends on
+    the table's last row."""
+    rng = np.random.default_rng(17)
+    return [
+        (jnp.asarray(rng.integers(1, vocab, size=(1, n)), jnp.int32), steps)
+        for n, steps in [(3, 6), (9, 30), (50, 14), (2, 20)]
+    ]
+
+
+@pytest.fixture(scope="module")
+def ladder():
+    dec = tiny_gpt(64)
+    params = dec.init(jax.random.key(0))
+    reqs = _ladder_requests(dec.cfg.vocab_size)
+    return dec, params, reqs, [dec.generate(params, p, s) for p, s in reqs]
+
+
+def _tick_spans():
+    return [
+        r.counts["span_rows"] for r in spans.snapshot().records
+        if r.name == "paged.tick" and r.counts["kind"] == "plain"
+    ]
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_ladder_serves_what_generate_gives(ladder, kv_dtype):
+    """Depths that cross rungs and reach the last one: the fp pool
+    serves token-for-token what `dec.generate` gives (dropped columns
+    were masked rows), and so does the int8 pool against itself on
+    the whole table (its parity against fp is test_kv_quant.py's)."""
+    dec, params, reqs, want = ladder
+    kw = dict(num_blocks=40, block_size=4, max_batch=2, kv_dtype=kv_dtype)
+    obs.reset()
+    with obs.counter_deltas() as d:
+        outs, _ = serve_paged(dec, params, reqs, **kw)
+    rows = _tick_spans()
+    assert set(rows) == {16, 24, 64}
+    # The counters are the ticks' spans summed: the engagement share.
+    assert d['defer_kv_rows_read_total{server="paged"}'] == 2 * sum(rows)
+    assert d['defer_kv_rows_gathered_baseline_total{server="paged"}'] == (
+        2 * 64 * len(rows)
+    )
+    if kv_dtype == "fp":
+        for got, ref in zip(outs, want):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+        return
+    # The whole table every tick: a ladder of one rung.
+    srv = PagedDecodeServer(dec, params, **kw)
+    srv._build()
+    srv._rungs = srv._rungs[-1:]
+    rids = [srv.submit(p, s) for p, s in reqs]
+    whole = srv.run()
+    assert set(_tick_spans()[len(rows):]) == {64}
+    for got, rid in zip(outs, rids):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(whole[rid]))
+
+
+def test_no_program_is_built_while_depths_cross_rungs(ladder):
+    """Every rung's program exists before the first tick: each is a
+    `jax.build` record of kind `paged_step` under the first admission,
+    and no tick lowers, compiles or loads a program in its plan or its
+    dispatch though the ticks run on all three rungs."""
+    dec, params, reqs, _ = ladder
+    # A server of its own shape, so that no test before it built these.
+    kw = dict(num_blocks=39, block_size=4, max_batch=2)
+    obs.reset()
+    serve_paged(dec, params, reqs, **kw)
+    records = spans.snapshot().records
+    by_id = {r.id: r for r in records}
+    ticks = [r for r in records if r.name == "paged.tick"]
+    assert {r.counts["span_rows"] for r in ticks} == {16, 24, 64}
+    builds = [r for r in records if r.name == "jax.build"]
+    rungs = [r for r in builds if r.counts["kind"] == "paged_step"]
+    assert [r.counts["span_rows"] for r in rungs] == [16, 24, 64]
+    assert all(r.t1 <= ticks[0].t0 for r in rungs)
+    # What the listener saw of them lies inside them, and nowhere else
+    # near the step: a tick's other builds are the drain's and the
+    # finish's small eager programs (PERF.md 7a).
+    inside = [r for r in builds if r.parent in {g.id for g in rungs}]
+    assert {r.counts["kind"] for r in inside} >= {"lowered"}
+    under = {by_id[r.parent].name for r in builds if r.parent in by_id}
+    assert not under & {"paged.tick.plan", "paged.tick.dispatch"}, under
+    # A second server of the same shapes builds nothing: the programs
+    # are memoised on the decoder.
+    obs.reset()
+    serve_paged(dec, params, reqs[:1], **kw)
+    assert not [
+        r for r in spans.snapshot().records
+        if r.name == "jax.build" and r.counts["kind"] == "paged_step"
+    ]
 
 
 def test_unknown_attention_mode_raises():

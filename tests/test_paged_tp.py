@@ -111,6 +111,41 @@ def test_tp_token_identical(
     assert stats["tp_psums"] > 0
 
 
+def test_tp_span_ladder_token_identical(model):
+    """The gathered step's span ladder through `_jit_tick`'s
+    shard_map on two devices: depths that start on the first rung and
+    end on the last serve the plain decoder's own tokens, every rung
+    is built before the first tick, and no tick's dispatch builds a
+    program."""
+    from defer_tpu.obs import spans
+
+    dec, params = model
+    rng = np.random.default_rng(23)
+    reqs = [
+        (jnp.asarray(rng.integers(1, dec.cfg.vocab_size, size=(1, n)), jnp.int32), s)
+        for n, s in [(9, 30), (3, 5), (50, 14)]
+    ]
+    obs.reset()
+    outs, _ = serve_paged(
+        dec, params, reqs, num_blocks=37, block_size=4, max_batch=2,
+        mesh=_mesh(2),
+    )
+    for (p, s), got in zip(reqs, outs):
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(dec.generate(params, p, s))
+        )
+    records = spans.snapshot().records
+    by_id = {r.id: r for r in records}
+    ticks = [r for r in records if r.name == "paged.tick"]
+    assert {r.counts["span_rows"] for r in ticks} >= {16, 64}
+    builds = [r for r in records if r.name == "jax.build"]
+    rungs = [r for r in builds if r.counts["kind"] == "paged_step"]
+    assert [r.counts["span_rows"] for r in rungs] == [16, 24, 64]
+    assert all(r.t1 <= ticks[0].t0 for r in rungs)
+    under = {by_id[r.parent].name for r in builds if r.parent in by_id}
+    assert not under & {"paged.tick.plan", "paged.tick.dispatch"}, under
+
+
 def test_size1_mesh_matches_mesh_none(model, solo):
     """A 1-device mesh runs the shard_map path end to end; tokens must
     match mesh=None exactly (the degenerate-mesh contract)."""
