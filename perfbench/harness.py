@@ -5,7 +5,8 @@ configuration, traffic mix and metrics; each is a file of its own
 under the benchmark's directories, found by that name:
 
   <path>/configs/<config>.json        sizes, `family`, server arguments
-  <path>/families/<family>.py         decoder, weights, plain reference
+  <path>/families/<family>.py         decoder, weights, plain reference,
+                                      its own count of a decode step
   <path>/traffic/<traffic>.json       parameters of the one generator
   <path>/cells/<workload>.json        what belongs to config x traffic
   <path>/end_to_end/<metric>.py       read(run) -> number | None
@@ -15,7 +16,8 @@ From the program it takes the system under test and these names only:
 `PagedDecodeServer(dec, params, num_blocks=, block_size=, max_batch=,
 mesh=, on_token=)`, `submit`, `_admit`, `_tick`, `slots`, `pending`,
 `blocks_peak`, the `obs` counters `host_dispatches` and
-`tokens_generated`, `make_mesh`, and what perfbench/families/ names.
+`tokens_generated`, the metrics registry `obs.metrics.get_registry()`
+by exported name, `make_mesh`, and what perfbench/families/ names.
 """
 
 from __future__ import annotations
@@ -30,6 +32,7 @@ import json
 import math
 import os
 import shutil
+import sys
 import tempfile
 import time
 
@@ -101,6 +104,7 @@ class Run:
 
     workload: dict
     model: dict
+    family: object  # the module families/<family>.py
     server_args: dict
     traffic: dict
     cell: dict
@@ -119,10 +123,15 @@ class Run:
     late: list = dataclasses.field(default_factory=list)
     # (t0, t1, tokens, live_slots, live_kv_rows) of each _tick call
     ticks: list = dataclasses.field(default_factory=list)
+    # beside each entry of `ticks`: its live slots' cached rows
+    tick_depths: list = dataclasses.field(default_factory=list)
     # (t0, t1, requests seated) of each _admit call that seated any
     admits: list = dataclasses.field(default_factory=list)
     counters_open: dict = dataclasses.field(default_factory=dict)
     counters_close: dict = dataclasses.field(default_factory=dict)
+    # the program's whole metrics registry, exported name -> value
+    registry_open: dict = dataclasses.field(default_factory=dict)
+    registry_close: dict = dataclasses.field(default_factory=dict)
     builds_open: dict = dataclasses.field(default_factory=dict)
     builds_close: dict = dataclasses.field(default_factory=dict)
     blocks_peak: int = 0
@@ -153,6 +162,11 @@ class Run:
 
     def window_ticks(self) -> list:
         return [t for t in self.ticks if self.in_window(t[1])]
+
+    def window_tick_depths(self) -> list:
+        return [
+            d for t, d in zip(self.ticks, self.tick_depths) if self.in_window(t[1])
+        ]
 
     def window_admits(self) -> list:
         return [a for a in self.admits if self.in_window(a[1])]
@@ -244,11 +258,12 @@ def drive(srv, run: Run, schedule, until: float, span) -> int:
             run.admits.append((t0, t1, rec.n - n0))
         if any(s is not None for s in srv.slots):
             n1 = rec.n
-            live, rows = len(rec.depth), sum(rec.depth.values())
+            depths = tuple(rec.depth.values())
             t2 = clock()
             with span("tick"):
                 srv._tick()
-            run.ticks.append((t2, clock(), rec.n - n1, live, rows))
+            run.ticks.append((t2, clock(), rec.n - n1, len(depths), sum(depths)))
+            run.tick_depths.append(depths)
         elif until is None and not schedule and not srv.pending:
             break
         else:
@@ -300,7 +315,10 @@ def check_correct(srv, run, family, params, seed, span):
     import jax.numpy as jnp
 
     model = run.model
-    t0 = min(CHECK_PROMPT, model["max_position_embeddings"] // 2)
+    t0 = min(
+        model.get("check_prompt_tokens", CHECK_PROMPT),
+        model["max_position_embeddings"] // 2,
+    )
     rng = np.random.default_rng([seed, 2])
     prompt = rng.integers(1, model["vocab_size"], (1, t0)).astype(np.int32)
     (rid,) = serve_now(srv, run, [(jnp.asarray(prompt), CHECK_STEPS)], span)
@@ -317,7 +335,7 @@ def check_correct(srv, run, family, params, seed, span):
     ok = bool(np.isfinite(ref).all() and max(behind) <= MODEL_TOL)
     return ok, {
         "behind_best_max": max(behind), "tolerance": MODEL_TOL,
-        "tokens_passing_mean": passing,
+        "tokens_passing_mean": passing, "prompt_tokens": t0,
     }
 
 
@@ -384,6 +402,16 @@ def counters(srv) -> dict:
     }
 
 
+def registry() -> dict:
+    """Every instrument of the program's metrics registry by its
+    exported name (labels inline): a counter's or gauge's value, a
+    histogram's count, sum and buckets."""
+    from defer_tpu.obs import metrics as program_metrics
+
+    kinds = program_metrics.get_registry().to_dict()
+    return {**kinds["counters"], **kinds["gauges"], **kinds["histograms"]}
+
+
 # -- one run -----------------------------------------------------------------
 
 
@@ -430,7 +458,8 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
         dec, params, **server_args, mesh=mesh, on_token=rec.on_token
     )
     run = Run(
-        workload=workload, model=config, server_args=config["server"],
+        workload=workload, model=config, family=family,
+        server_args=config["server"],
         traffic=mix, cell=cell, chips=workload["chips"], peaks=peak_table,
         weight_bytes=weight_bytes, pool_bytes=srv.pool_bytes,
         seconds=float(args.seconds), t_start=t_start, rec=rec,
@@ -477,7 +506,9 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
         if len(srv.pending) == before:
             break  # the pool is full: the rest waits in the queue
     run.ticks.clear()
+    run.tick_depths.clear()
     run.admits.clear()
+    run.registry_open = registry()
     gc.collect()
     gc.freeze()
 
@@ -510,6 +541,7 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
             jax.profiler.stop_trace()
     run.t_close = min(run.t_close, clock())
     run.counters_close = counters(srv)
+    run.registry_close = registry()
     run.builds_close = builds.snapshot()
     run.blocks_peak = srv.blocks_peak
     # A request due after the window closed was never attempted.
@@ -544,6 +576,13 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
             "device_ops": xplane.top_device_ops(run.trace),
             "idle_gaps": xplane.idle_by_span(run.trace),
         }
+    # Each number `correct` compared, beside its limit: last in the
+    # result line, and the last lines of standard error.
+    result["compared"] = compared = {
+        "behind_best_max": {
+            "value": check_detail.get("behind_best_max"), "limit": MODEL_TOL,
+        },
+    }
     stamps = run.window_stamps()
     gaps = metrics.inter_token_gaps(stamps)
     ticks = run.window_ticks()
@@ -605,4 +644,6 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
     }
     out("details: " + json.dumps(details))
     out(json.dumps(result))
+    for name, c in compared.items():
+        print(f"compared: {name} {c['value']} limit {c['limit']}", file=sys.stderr)
     return 0

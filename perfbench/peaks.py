@@ -29,12 +29,17 @@ def peaks_for(device_kind: str) -> dict:
         ) from None
 
 
+def head_dim(model: dict) -> int:
+    """A head's size: the file's `head_dim` where it states one, the
+    quotient of hidden size and heads where the source has no such key."""
+    return model.get("head_dim") or model["hidden_size"] // model["num_attention_heads"]
+
+
 def kv_bytes_per_row(model: dict, itemsize: int = 2) -> int:
     """Bytes of K and V one cached token holds over all layers."""
-    head_dim = model["hidden_size"] // model["num_attention_heads"]
     return (
         2 * model["num_hidden_layers"] * model["num_key_value_heads"]
-        * head_dim * itemsize
+        * head_dim(model) * itemsize
     )
 
 
@@ -53,27 +58,45 @@ def decode_step_flops(live_slots: float, live_kv_rows: float, model: dict) -> fl
     d = model["hidden_size"]
     f = model["intermediate_size"]
     hq = model["num_attention_heads"]
-    dh = d // hq
+    dh = head_dim(model)
     dkv = model["num_key_value_heads"] * dh
-    per_layer = 2 * d * d + 2 * d * dkv + 3 * d * f
+    per_layer = 2 * d * hq * dh + 2 * d * dkv + 3 * d * f
     matrices = model["num_hidden_layers"] * per_layer + model["vocab_size"] * d
     attention = 4 * model["num_hidden_layers"] * hq * dh * live_kv_rows
     return 2 * matrices * live_slots + attention
+
+
+def decode_step_counts(model: dict, weight_bytes: int, depths) -> tuple[float, float]:
+    """(bytes, operations): the least a decode step must read and
+    compute with one live slot at each of `depths` cached rows. This
+    is the dense GQA count, linear in the rows; a family whose step is
+    not that (experts, a window under a longer table) brings its own
+    `decode_step_counts` of the same signature in `families/<family>.py`."""
+    rows = sum(depths)
+    return (
+        decode_step_bytes(weight_bytes, rows, model),
+        decode_step_flops(len(depths), rows, model),
+    )
+
+
+def least_s(nbytes: float, flops: float, peaks: dict, chips: int) -> tuple[float, str]:
+    """The least time one chip of `chips` could take for these bytes
+    and operations, split evenly over the chips, and which peak bounds
+    it."""
+    by_bytes = nbytes / (chips * peaks["bytes_per_s"])
+    by_flops = flops / (chips * peaks["flops_per_s"])
+    if by_bytes >= by_flops:
+        return by_bytes, "bytes"
+    return by_flops, "flops"
 
 
 def decode_step_least_s(
     weight_bytes: int, live_slots: float, live_kv_rows: float,
     model: dict, peaks: dict, chips: int,
 ) -> tuple[float, str]:
-    """The least time one chip of `chips` could take for a decode
-    step, and which peak bounds it. Weights, cache and operations are
-    split evenly over the chips."""
-    by_bytes = decode_step_bytes(weight_bytes, live_kv_rows, model) / (
-        chips * peaks["bytes_per_s"]
+    """`least_s` of the dense GQA count at these live slots and rows."""
+    return least_s(
+        decode_step_bytes(weight_bytes, live_kv_rows, model),
+        decode_step_flops(live_slots, live_kv_rows, model),
+        peaks, chips,
     )
-    by_flops = decode_step_flops(live_slots, live_kv_rows, model) / (
-        chips * peaks["flops_per_s"]
-    )
-    if by_bytes >= by_flops:
-        return by_bytes, "bytes"
-    return by_flops, "flops"

@@ -31,17 +31,28 @@ def real_benchmark() -> dict:
         return json.load(f)
 
 
-def tiny_root(tmp: str, mesh=None, chips: int = 1) -> str:
-    """BENCHMARK.json's metrics over one toy cell, in `tmp`. The code
-    directories are links to the real ones."""
+def tiny_root(
+    tmp: str, mesh=None, chips: int = 1, model: dict = TINY_MODEL, families=None
+) -> str:
+    """BENCHMARK.json's metrics over one toy cell of `model`, in `tmp`.
+    The code directories are links to the real ones; the family files
+    of the directory `families`, where one is given, are linked beside
+    the real families."""
     bench = real_benchmark()
     real = os.path.join(REPO, "perfbench")
     os.makedirs(os.path.join(tmp, "perfbench"))
-    for folder in ("end_to_end", "layer_metrics", "families"):
+    for folder in ("end_to_end", "layer_metrics"):
         os.symlink(os.path.join(real, folder), os.path.join(tmp, "perfbench", folder))
-    for folder in ("configs", "traffic", "cells"):
+    for folder in ("configs", "traffic", "cells", "families"):
         os.makedirs(os.path.join(tmp, "perfbench", folder))
-    model = dict(TINY_MODEL)
+    for source in filter(None, (os.path.join(real, "families"), families)):
+        for name in os.listdir(source):
+            if name.endswith(".py"):
+                os.symlink(
+                    os.path.join(source, name),
+                    os.path.join(tmp, "perfbench", "families", name),
+                )
+    model = dict(model)
     model["server"] = {"num_blocks": 40, "block_size": 16, "max_batch": 4, "mesh": mesh}
 
     def dump(obj, *parts):

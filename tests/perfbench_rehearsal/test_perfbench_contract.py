@@ -6,7 +6,7 @@ import re
 import pytest
 
 import rehearsal_util
-from perfbench import harness
+from perfbench import contract, harness
 
 BENCH = rehearsal_util.real_benchmark()
 ROOT = rehearsal_util.REPO
@@ -89,14 +89,79 @@ def test_every_workload_resolves_to_files_of_its_own(cell):
 def test_every_config_is_used_and_no_width_is_reduced():
     used = {w["config"] for w in BENCH["workloads"]}
     assert used == {c["name"] for c in BENCH["configs"]}
-    widths = re.compile(r"(hidden_size|intermediate|latent|state_size|head_dim|_dim$|_rank$|experts_per_tok)")
     for c in BENCH["configs"]:
-        assert not [k for k in c["reduced"] if widths.search(k)]
         cfg = harness.load_json(os.path.join(ROOT, c["file"]))
-        published = {
-            "hidden_size": 4096, "intermediate_size": 14336,
-            "num_attention_heads": 32, "num_key_value_heads": 8,
-            "vocab_size": 32000, "sliding_window": 4096,
-            "rope_theta": 10000.0, "rms_norm_eps": 1e-05,
-        }
-        assert {k: cfg[k] for k in published} == published
+        assert contract.check_config(c, cfg) == [], c["name"]
+
+
+# A configuration of another architecture, as a later PR's data files
+# would state it: expert layers, a head size that is not the quotient
+# of hidden size and heads, window and full layers in a period of four.
+# One chip's share of eight: 16 of 128 experts, an eighth of the
+# vocabulary, one period of the depth.
+PERIOD = ["sliding_attention"] * 3 + ["full_attention"]
+OTHER_ENTRY = {
+    "name": "other", "source": "none", "file": "none", "why": "another architecture",
+    "reduced": ["num_hidden_layers", "layer_types", "num_experts", "vocab_size"],
+}
+OTHER = {
+    "family": "other",
+    "hidden_size": 4096, "intermediate_size": 4096, "num_attention_heads": 128,
+    "num_key_value_heads": 8, "head_dim": 128, "num_experts": 16,
+    "num_experts_per_tok": 8, "num_shared_experts": 4, "sliding_window": 4096,
+    "vocab_size": 32768, "num_hidden_layers": 4, "layer_types": PERIOD,
+    "rope_parameters": {"rope_theta": 50000, "rope_type": "default"},
+    "published": {
+        "hidden_size": 4096, "intermediate_size": 4096, "num_attention_heads": 128,
+        "num_key_value_heads": 8, "head_dim": 128, "num_experts": 128,
+        "num_experts_per_tok": 8, "num_shared_experts": 4, "sliding_window": 4096,
+        "vocab_size": 262144, "num_hidden_layers": 32, "layer_types": PERIOD * 8,
+        "rope_parameters": {"rope_theta": 50000, "rope_type": "default"},
+    },
+    "server": {"num_blocks": 2560, "block_size": 16, "max_batch": 32, "mesh": None},
+}
+
+
+def changed(file=None, published=None, drop=(), reduced=None):
+    """OTHER with keys of the file and of `published` replaced, keys of
+    the file dropped, and the entry's `reduced` replaced."""
+    cfg = {**OTHER, **(file or {})}
+    cfg["published"] = {**OTHER["published"], **(published or {})}
+    cfg = {k: v for k, v in cfg.items() if k not in drop}
+    entry = dict(OTHER_ENTRY, reduced=OTHER_ENTRY["reduced"] if reduced is None else reduced)
+    return entry, cfg
+
+
+def test_check_config_passes_a_second_architecture_without_an_edit():
+    assert contract.check_config(*changed()) == []
+    assert contract.layer_period(OTHER["published"]) == 4
+    assert contract.layer_period({"full_attention_interval": 4, "expert_layer_period": 2}) == 4
+    assert contract.layer_period({"num_hidden_layers": 32}) == 1
+
+
+@pytest.mark.parametrize("case, kwargs, says", [
+    ("a cut width", {"file": {"intermediate_size": 2048}}, "intermediate_size is 2048"),
+    ("a cut width listed in reduced",
+     {"file": {"head_dim": 64}, "reduced": OTHER_ENTRY["reduced"] + ["head_dim"]},
+     "head_dim is a width"),
+    ("a width missing from published", {"file": {"expert_width": 4096}}, "expert_width is a width"),
+    ("a window missing from published", {"file": {"window_size": 512}}, "window_size is a width"),
+    ("a stale reduced", {"file": {"vocab_size": 262144}}, "vocab_size is in `reduced` and equals"),
+    ("a reduced key the source does not have",
+     {"reduced": OTHER_ENTRY["reduced"] + ["tie_word_embeddings"]}, "no published value"),
+    ("a changed key that is not in reduced", {"file": {"num_key_value_heads": 4}}, "num_key_value_heads is 4"),
+    ("a published key the file leaves out", {"drop": ("num_shared_experts",)}, "num_shared_experts is published"),
+    ("4 experts", {"file": {"num_experts": 4}}, "fewer than 8"),
+    ("a sixteenth of the vocabulary", {"file": {"vocab_size": 16384}}, "under an eighth"),
+    ("6 layers of a period of 4",
+     {"file": {"num_hidden_layers": 6, "layer_types": PERIOD + PERIOD[:2]}}, "no whole number of periods"),
+    ("a width changed inside a reduced group",
+     {"file": {"rope_parameters": {"rope_theta": 50000, "rope_type": "default", "rotary_dim": 64}},
+      "published": {"rope_parameters": {"rope_theta": 50000, "rope_type": "default", "rotary_dim": 128}},
+      "reduced": OTHER_ENTRY["reduced"] + ["rope_parameters"]},
+     "rope_parameters.rotary_dim"),
+    ("no published values", {"drop": ("published",)}, "no `published`"),
+])
+def test_check_config_fails(case, kwargs, says):
+    faults = contract.check_config(*changed(**kwargs))
+    assert len(faults) == 1 and says in faults[0], (case, faults)

@@ -97,3 +97,39 @@ def test_decode_step_bytes_and_least_time_from_shapes():
         weights, 32, rows, model, peaks.PEAKS["TPU v5 lite"], 4
     )
     assert four == pytest.approx(least / 4)
+
+
+@pytest.mark.parametrize("stated, head", [(None, 32), (128, 128)])
+def test_kv_bytes_per_row_reads_head_dim_where_the_file_has_it(stated, head):
+    # 128 Q heads on a hidden size of 4096: the quotient is 32, and a
+    # file that states heads of 128 is counted at 128.
+    model = {
+        "hidden_size": 4096, "num_attention_heads": 128,
+        "num_key_value_heads": 8, "num_hidden_layers": 4,
+    }
+    if stated is not None:
+        model["head_dim"] = stated
+    assert peaks.head_dim(model) == head
+    assert peaks.kv_bytes_per_row(model) == 2 * 4 * 8 * head * 2
+    # Q and the output projection are hidden x (heads x head size).
+    model.update(intermediate_size=4096, vocab_size=32768)
+    per_layer = 2 * 4096 * 128 * head + 2 * 4096 * 8 * head + 3 * 4096 * 4096
+    assert peaks.decode_step_flops(1, 0, model) == 2 * (4 * per_layer + 32768 * 4096)
+
+
+def test_the_default_count_is_the_dense_one_and_least_s_divides_either():
+    model = {
+        "hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "num_hidden_layers": 2, "vocab_size": 256,
+    }
+    depths = (5, 9, 30)
+    nbytes, flops = peaks.decode_step_counts(model, 1000, depths)
+    assert nbytes == peaks.decode_step_bytes(1000, 44, model)
+    assert flops == peaks.decode_step_flops(3, 44, model)
+    table = {"bytes_per_s": 100.0, "flops_per_s": 1000.0}
+    assert peaks.least_s(nbytes, flops, table, 1) == peaks.decode_step_least_s(
+        1000, 3, 44, model, table, 1
+    )
+    assert peaks.least_s(500.0, 100.0, table, 1) == (5.0, "bytes")
+    assert peaks.least_s(500.0, 100.0, table, 2) == (2.5, "bytes")
+    assert peaks.least_s(50.0, 1000.0, table, 1) == (1.0, "flops")

@@ -32,7 +32,10 @@ def run_tiny(tmp_path, trace, mesh=None, chips=1):
 
 def test_last_line_has_exactly_the_contracts_keys(tmp_path, cpu_peaks):
     result, details = run_tiny(tmp_path, trace=0)
-    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics", "device", "compared"]
+    # A configuration that states no `check_prompt_tokens`: the default
+    # 256, capped at half of the toy table.
+    assert details["correct"]["prompt_tokens"] == 64
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] == details["requests_due"] > 5
     assert set(result["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}

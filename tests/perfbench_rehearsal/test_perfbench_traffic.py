@@ -11,6 +11,7 @@ from perfbench import traffic
 
 CHAT = json.load(open(os.path.join(rehearsal_util.REPO, "perfbench", "traffic", "chat.json")))
 CELL = {"knee_per_s": 1.4, "lifetime_s": 20.0, "backlog_per_s": 2.2}
+VOCAB = 50000  # any vocabulary: the generator knows no model
 
 
 def gen(seed, mix=CHAT, **kw):
@@ -20,8 +21,8 @@ def gen(seed, mix=CHAT, **kw):
 def test_same_seed_same_requests_and_ids():
     a, b = gen(2**31 + 7), gen(2**31 + 7)
     assert a == b
-    ia = traffic.token_ids(a, 32000, 0, 2**31 + 7)
-    ib = traffic.token_ids(b, 32000, 0, 2**31 + 7)
+    ia = traffic.token_ids(a, VOCAB, 0, 2**31 + 7)
+    ib = traffic.token_ids(b, VOCAB, 0, 2**31 + 7)
     assert all((x == y).all() for x, y in zip(ia, ib))
     assert all(x.shape == (1, r.prompt_tokens) for x, r in zip(ia, a))
 
@@ -94,6 +95,6 @@ def test_bursts_keep_the_mean_rate_and_bunch_arrivals():
 
 def test_shared_prefix_is_common_to_every_prompt():
     rs = gen(5)[:6]
-    ids = traffic.token_ids(rs, 32000, 64, 5)
+    ids = traffic.token_ids(rs, VOCAB, 64, 5)
     assert all((a[0, :64] == ids[0][0, :64]).all() for a in ids)
     assert not (ids[0][0, 64:128] == ids[1][0, 64:128]).all()
