@@ -138,7 +138,7 @@ class KVBlockIngest:
             -(-t0 // srv.bs),
             cfg.kv_heads,
             srv.bs,
-            cfg.dim // cfg.num_heads,
+            cfg.dh,
         )
         if tuple(payload.k.shape) != expect:
             raise IngestError(

@@ -69,6 +69,9 @@ def run_prefill(
     position, which the decode side samples the first token from."""
     import jax.numpy as jnp
 
+    from defer_tpu.parallel.transformer_stack import refuse_mechanisms
+
+    refuse_mechanisms(dec.cfg, "disagg prefill (run_prefill)")
     t0 = int(prompt.shape[1])
     max_len = dec.cfg.max_len
     if t0 >= max_len:
@@ -96,7 +99,7 @@ def run_prefill(
         pos += chunk
     L = dec.cfg.num_layers
     hkv = dec.cfg.kv_heads
-    dh = dec.cfg.dim // dec.cfg.num_heads
+    dh = dec.cfg.dh
     n_blocks = -(-t0 // block_size)
     # Host transfer of the finished cache — the whole point of the
     # worker: these rows ship to the decode host instead of living

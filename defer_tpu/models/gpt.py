@@ -37,10 +37,36 @@ from defer_tpu.parallel.transformer_stack import (
     _layer_norm,
     _rms_norm,
     apply_rope,
+    EXPERT_LEAVES,
     embed_lookup,
+    held_experts_ffn,
     init_stack,
     norm_apply,
 )
+
+# The float32 scores of a multi-token attention step, [B, Hq, T, S],
+# may take this many bytes; over it the step attends one KV group and
+# a chunk of its queries at a time, the chunk sized from the shapes
+# alone. 32 heads x 1024 queries x 4096 keys is exactly this, and stays
+# whole.
+_SCORE_BYTES = 1 << 29
+# Rows of scores longer than this take their maximum behind an
+# optimization barrier. XLA's TPU compiler fuses `x - max(x)` into one
+# pass by turning the row maximum into a reduce-window as wide as the
+# row; over 8192 columns that one fusion took 47 ms per 2^27 scores
+# (PERF.md, PR 28), fifty times the two products beside it. Rows of
+# 4096 and under keep `jax.nn.softmax`, and their programs with it.
+_SOFTMAX_ROW = 4096
+
+
+def _query_chunk(b: int, g: int, t: int, s: int) -> int:
+    """The most queries (a divisor of `t`) of ONE KV group of `g`
+    heads whose float32 scores over `s` keys fit `_SCORE_BYTES`."""
+    per_query = b * g * s * 4
+    return next(
+        t // n for n in range(1, t + 1)
+        if t % n == 0 and (t // n) * per_query <= _SCORE_BYTES or n == t
+    )
 
 
 def seen_tokens_mask(ids: jax.Array, vocab: int) -> jax.Array:
@@ -472,8 +498,20 @@ class GptDecoder:
             raise ValueError(
                 "GptDecoder uses pre-LN blocks: cfg.norm_style must be 'pre'"
             )
-        if self.cfg.num_experts:
-            raise ValueError("MoE decoder blocks are not supported here")
+        if self.cfg.num_experts and self.cfg.ffn_style != "swiglu":
+            raise ValueError(
+                "the decoder's expert layer is SwiGLU "
+                "(held_experts_ffn): GELU experts are the training "
+                "stack's (parallel/transformer_stack.py::moe_ffn)"
+            )
+        if self.rolling_cache and (
+            self.cfg.layer_kinds is not None or self.cfg.num_experts
+        ):
+            raise ValueError(
+                "rolling_cache holds one window for the whole stack and "
+                "no counters: it does not serve layer kinds "
+                "(cfg.layer_kinds) or experts (cfg.num_experts)"
+            )
         if self.cfg.lora_rank:
             raise ValueError(
                 "GptDecoder serves merged weights only: fold adapters "
@@ -510,7 +548,7 @@ class GptDecoder:
                 )
                 * 0.02
             )
-        if cfg.norm_type == "layer":
+        if cfg.norm_type == "layer" and cfg.norm_bias:
             p["final_ln_bias"] = jnp.zeros((cfg.dim,))
         return p
 
@@ -529,18 +567,84 @@ class GptDecoder:
 
     def init_cache(self, batch: int) -> dict:
         cfg = self.cfg
-        dh = cfg.dim // cfg.num_heads
+        dh = cfg.dh
         # GQA caches store KV heads only — the architecture's memory
         # win: cache bytes scale with kv_heads, not num_heads. Rolling
         # caches bound the slot count by the attention window instead
         # of max_len.
         slots = cfg.window if self.rolling_cache else cfg.max_len
         shape = (cfg.num_layers, batch, cfg.kv_heads, slots, dh)
-        return {
+        cache = {
             "k": jnp.zeros(shape, self.compute_dtype),
             "v": jnp.zeros(shape, self.compute_dtype),
             "pos": jnp.zeros((), jnp.int32),
         }
+        if cfg.num_experts:
+            # The expert layer's counters ride in the cache: a step
+            # counts its first `moe_live` rows (the rest are padding)
+            # and leaves per layer [assignments on held experts,
+            # distinct held experts touched] in `moe`.
+            cache["moe_live"] = jnp.full((), cfg.max_len, jnp.int32)
+            cache["moe"] = jnp.zeros((cfg.num_layers, 2), jnp.int32)
+        return cache
+
+    def split_experts(self, stack: dict) -> tuple[dict, dict]:
+        """(the leaves a layer scan slices, the expert leaves it must
+        leave whole): `held_experts_ffn` indexes the second by layer
+        where it reads them. A dense stack has none."""
+        if not self.cfg.num_experts:
+            return stack, {}
+        whole = {k: v for k, v in stack.items() if k in EXPERT_LEAVES}
+        return {k: v for k, v in stack.items() if k not in whole}, whole
+
+    def scan_layers(self, body, carry, stack, caches=()):
+        """`lax.scan` of `body(carry, p, caches_l, kind, layer) ->
+        (out, ys)` over the layers of `stack` and the layer-stacked
+        `caches`; returns (carry, ys, stats). `carry` is the
+        activations, or a tuple that holds more (the paged step's
+        pool). A homogeneous dense stack scans layer by layer with
+        kind and layer None, as it always has. With `cfg.layer_kinds`
+        the scan runs over PERIODS and the period's layers are
+        unrolled in its body, so that each layer's window and rotary
+        flag stay static. An expert decoder's `p` holds its expert
+        leaves whole beside the layer's own (`split_experts`) and its
+        `out` is `(carry, stats)`, stacked into stats [L, 2]; stats is
+        None for a dense one."""
+        cfg = self.cfg
+        if cfg.layer_kinds is None and not cfg.num_experts:
+            carry, ys = lax.scan(
+                lambda c, xs: body(c, *xs, None, None), carry, (stack, caches)
+            )
+            return carry, ys, None
+        kinds = cfg.layer_kinds or (None,)
+        per = len(kinds)
+        n_per = cfg.num_layers // per
+        sliced, whole = self.split_experts(stack)
+
+        def period(carry, xs):
+            layers, caches_p, first = xs
+            outs = []
+            for j, kind in enumerate(kinds):
+                p, caches_l = jax.tree.map(lambda a: a[j], (layers, caches_p))
+                out, ys = body(carry, {**p, **whole}, caches_l, kind, first + j)
+                carry, st = out if cfg.num_experts else (out, None)
+                outs.append((ys, st))
+            return carry, jax.tree.map(lambda *a: jnp.stack(a), *outs)
+
+        carry, outs = lax.scan(
+            period, carry,
+            (
+                *jax.tree.map(
+                    lambda a: a.reshape(n_per, per, *a.shape[1:]),
+                    (sliced, caches),
+                ),
+                jnp.arange(n_per) * per,
+            ),
+        )
+        ys, stats = jax.tree.map(
+            lambda a: a.reshape(-1, *a.shape[2:]), outs
+        )
+        return carry, ys, stats
 
     # -- one step (prefill or decode) -------------------------------------
 
@@ -548,7 +652,7 @@ class GptDecoder:
         # Head count inferred from the actual width: under tensor
         # parallelism each shard sees D/tp == local_heads * Dh.
         b, t, d = x.shape
-        dh = self.cfg.dim // self.cfg.num_heads
+        dh = self.cfg.dh
         return x.reshape(b, t, d // dh, dh).transpose(0, 2, 1, 3)
 
     def _proj_fns(self, p: dict, dt, adapter_ids=None):
@@ -586,39 +690,53 @@ class GptDecoder:
         return bias, proj
 
     @jax.named_scope("attn_qkv")
-    def _attn_qkv(self, p: dict, x, pos, adapter_ids=None):
+    def _attn_qkv(self, p: dict, x, pos, adapter_ids=None, kind=None):
         """ln1 + q/k/v projections (+rope at the step's absolute
         positions) + head split: everything a block does BEFORE the
         cache layout matters. Returns (q [B,Hq,T,Dh], k, v
         [B,Hkv,T,Dh]). Shared verbatim by `_block` and the paged
-        block-native steps so their new K/V rows are bit-identical."""
+        block-native steps so their new K/V rows are bit-identical.
+        `kind` is the layer's entry of `cfg.layer_kinds` (a layer that
+        is not rotary gets no positions at all)."""
         cfg = self.cfg
         dt = x.dtype
-        dh = cfg.dim // cfg.num_heads
+        dh = cfg.dh
         per_slot = getattr(pos, "ndim", 0) == 1
         bias, proj = self._proj_fns(p, dt, adapter_ids)
         h = norm_apply(cfg, x, p, "ln1")
         qf = bias(proj(h, "wq"), "bq")
         kf = bias(proj(h, "wk"), "bk")
         vf = bias(proj(h, "wv"), "bv")
-        if cfg.pos_style == "rope":
+        if cfg.kind_of(kind)[1]:
             steps_r = jnp.arange(qf.shape[1])
             positions = (
                 pos[:, None] + steps_r[None] if per_slot else pos + steps_r
             )
-            qf = apply_rope(qf, dh, positions, cfg.rope_theta)
-            kf = apply_rope(kf, dh, positions, cfg.rope_theta)
+            qf = apply_rope(
+                qf, dh, positions, cfg.rope_theta, cfg.rope_pairing
+            )
+            kf = apply_rope(
+                kf, dh, positions, cfg.rope_theta, cfg.rope_pairing
+            )
         return (
             self._split_heads(qf),
             self._split_heads(kf),
             self._split_heads(vf),
         )
 
-    def _attn_out(self, p: dict, x, attn, tp_axis=None, adapter_ids=None):
+    def _attn_out(
+        self, p: dict, x, attn, tp_axis=None, adapter_ids=None, live=None,
+        layer=None,
+    ):
         """Everything a block does AFTER attention: wo projection
         (+psum under tp), residual, ln2, FFN. `attn` is the merged
         [B, T, Hq*Dh] attention output. Shared by `_block` and the
-        paged block-native steps."""
+        paged block-native steps. A parallel block (`cfg.parallel_block`)
+        feeds the FFN the block's one norm of its INPUT and adds both
+        branches to it. An expert decoder returns `(out, stats)`, the
+        expert layer's counters over the `live` rows
+        (`held_experts_ffn`, which is handed `layer` where the expert
+        leaves of `p` are still layer-stacked: `split_experts`)."""
         cfg = self.cfg
         bias, proj = self._proj_fns(p, x.dtype, adapter_ids)
         with jax.named_scope("attn_out"):
@@ -626,9 +744,15 @@ class GptDecoder:
             if tp_axis is not None:
                 attn = lax.psum(attn, tp_axis)
             attn = bias(attn, "bo")
-            x = x + attn
+            x_in, x = x, x + attn
         with jax.named_scope("mlp"):
-            h2 = norm_apply(cfg, x, p, "ln2")
+            if cfg.parallel_block:
+                h2 = norm_apply(cfg, x_in, p, "ln1")
+            else:
+                h2 = norm_apply(cfg, x, p, "ln2")
+            if cfg.num_experts:
+                ff, stats = held_experts_ffn(p, h2, cfg, live, layer)
+                return x + ff, stats
             if cfg.ffn_style == "swiglu":
                 gate = jax.nn.silu(proj(h2, "w1"))
                 ff = proj(gate * proj(h2, "w3"), "w2")
@@ -651,10 +775,15 @@ class GptDecoder:
         pos,
         tp_axis=None,
         adapter_ids=None,
+        kind=None,
+        live=None,
+        layer=None,
     ):
         """One decoder block on [B, T, D] with cache update; returns
-        (out, new_k, new_v). Under shard_map with tp_axis set, the
-        projections arrive column-sharded (this shard's head group),
+        (out, new_k, new_v); `kind` is the layer's entry of
+        `cfg.layer_kinds`, and an expert decoder's `out` is `(x,
+        stats)` over the `live` rows (`_attn_out`). Under shard_map
+        with tp_axis set, the projections arrive column-sharded (this shard's head group),
         the caches hold only local heads, and wo/w2 are row-sharded
         with psum — the Megatron pattern on the decode path.
 
@@ -674,21 +803,27 @@ class GptDecoder:
         dequantizes at its gather and requantizes the returned new
         rows at its scatter, so this read path — and the new_k/new_v
         it hands back — is storage-dtype-agnostic by construction."""
-        q, k, v = self._attn_qkv(p, x, pos, adapter_ids)
+        q, k, v = self._attn_qkv(p, x, pos, adapter_ids, kind)
         attn, k_cache, v_cache = self._attn_core(
-            q, k, v, k_cache, v_cache, pos, x.dtype
+            q, k, v, k_cache, v_cache, pos, x.dtype, kind
         )
-        out = self._attn_out(p, x, attn, tp_axis, adapter_ids)
+        out = self._attn_out(p, x, attn, tp_axis, adapter_ids, live, layer)
         return out, k_cache, v_cache
 
     @jax.named_scope("attn_core")
-    def _attn_core(self, q, k, v, k_cache, v_cache, pos, dt):
+    def _attn_core(self, q, k, v, k_cache, v_cache, pos, dt, kind=None):
         """The part of `_block` between the projections: write the T
         new K/V rows into the caches and attend over them. Returns
-        (attn [B, T, Hq*Dh] in `dt`, new_k, new_v)."""
+        (attn [B, T, Hq*Dh] in `dt`, new_k, new_v). The layer's window
+        is its `kind`'s (`cfg.layer_kinds`), else the stack's. A
+        multi-token step whose float32 scores would pass
+        `_SCORE_BYTES` attends one KV group and a chunk of its queries
+        at a time."""
         cfg = self.cfg
+        window = cfg.kind_of(kind)[0]
         per_slot = getattr(pos, "ndim", 0) == 1
         b, h_q, t, dh = q.shape
+        mask_of = None
 
         if self.rolling_cache:
             win = cfg.window
@@ -779,17 +914,27 @@ class GptDecoder:
             # excluded by the same test. A sliding window additionally
             # drops slots more than `window`-1 behind (Mistral-style).
             j = jnp.arange(k_att.shape[2])
-            if per_slot:
-                tt = pos[:, None] + jnp.arange(t)  # (B, T)
-                mask = j[None, None, :] <= tt[:, :, None]  # (B, T, S)
-                if cfg.window is not None:
-                    mask &= j[None, None, :] > tt[:, :, None] - cfg.window
-                mask = mask[:, None, None, :, :]
-            else:
-                tt = pos + jnp.arange(t)[:, None]  # (T, 1)
-                mask = j[None, :] <= tt  # (T, S)
-                if cfg.window is not None:
-                    mask &= j[None, :] > tt - cfg.window
+
+            def mask_of(n, start=None):
+                """The mask of the `n` queries from offset `start`."""
+
+                def steps():
+                    r = jnp.arange(n)
+                    return r if start is None else start + r
+
+                if per_slot:
+                    tt = pos[:, None] + steps()  # (B, Tc)
+                    m = j[None, None, :] <= tt[:, :, None]  # (B, Tc, S)
+                    if window is not None:
+                        m &= j[None, None, :] > tt[:, :, None] - window
+                    return m[:, None, None, :, :]
+                tt = pos + steps()[:, None]  # (Tc, 1)
+                m = j[None, :] <= tt  # (Tc, S)
+                if window is not None:
+                    m &= j[None, :] > tt - window
+                return m
+
+            mask = mask_of(t)
 
         from defer_tpu.ops.pallas_attention import decode_k_block
 
@@ -814,22 +959,68 @@ class GptDecoder:
                 k_att,
                 v_att,
                 posv,
-                window=cfg.window,
+                window=window,
                 interpret=flash_mode == "interpret",
             )  # [B, Hq, Dh]
             attn = attn.astype(dt).reshape(b, t, h_q * dh)
         else:
             hkv = k_att.shape[1]
             qg = q.reshape(b, hkv, h_q // hkv, t, dh)
-            logits = jnp.einsum(
-                "bkgtd,bksd->bkgts",
-                qg,
-                k_att,
-                preferred_element_type=jnp.float32,
-            ) * (dh**-0.5)
-            logits = jnp.where(mask, logits, -jnp.inf)
-            weights = jax.nn.softmax(logits, axis=-1).astype(dt)
-            attn = jnp.einsum("bkgts,bksd->bkgtd", weights, v_att)
+
+            def attend(qg, mask, k_att=k_att, v_att=v_att):
+                logits = jnp.einsum(
+                    "bkgtd,bksd->bkgts",
+                    qg,
+                    k_att,
+                    preferred_element_type=jnp.float32,
+                ) * (dh**-0.5)
+                logits = jnp.where(mask, logits, -jnp.inf)
+                if logits.shape[-1] > _SOFTMAX_ROW:
+                    top = lax.optimization_barrier(
+                        jnp.max(logits, axis=-1, keepdims=True)
+                    )
+                    e = jnp.exp(logits - top)
+                    weights = (e / jnp.sum(e, axis=-1, keepdims=True)).astype(dt)
+                else:
+                    weights = jax.nn.softmax(logits, axis=-1).astype(dt)
+                return jnp.einsum("bkgts,bksd->bkgtd", weights, v_att)
+
+            s_len = k_att.shape[2]
+            if mask_of is None or b * h_q * t * s_len * 4 <= _SCORE_BYTES:
+                attn = attend(qg, mask)
+            else:
+                # One KV group and a chunk of its queries after the
+                # other: a piece's scores are the step's largest
+                # temporary.
+                g = h_q // hkv
+                tc = _query_chunk(b, g, t, s_len)
+                n = t // tc
+                pieces = (
+                    qg.reshape(b, hkv, g, n, tc, dh)
+                    .transpose(1, 3, 0, 2, 4, 5)
+                    .reshape(hkv * n, b, 1, g, tc, dh)
+                )
+
+                def piece(c):
+                    qc, kv, start = c
+                    return attend(
+                        qc, mask_of(tc, start),
+                        lax.dynamic_slice_in_dim(k_att, kv, 1, axis=1),
+                        lax.dynamic_slice_in_dim(v_att, kv, 1, axis=1),
+                    )
+
+                attn = lax.map(
+                    piece,
+                    (
+                        pieces,
+                        jnp.repeat(jnp.arange(hkv), n),
+                        jnp.tile(jnp.arange(n) * tc, hkv),
+                    ),
+                )  # [hkv * n, b, 1, g, tc, dh]
+                attn = (
+                    attn.reshape(hkv, n, b, g, tc, dh)
+                    .transpose(2, 0, 3, 1, 4, 5)
+                )
             attn = attn.reshape(b, h_q, t, dh)
             attn = attn.transpose(0, 2, 1, 3).reshape(b, t, h_q * dh)
         return attn, k_cache, v_cache
@@ -849,22 +1040,30 @@ class GptDecoder:
             adapter_ids = cache.get("adapter")
             x = self._embed_tokens(params, ids, pos, tp_axis)
 
-            def body(carry, layer):
-                x = carry
-                p, kc, vc = layer
+            live = None
+            if "moe_live" in cache:
+                live = jnp.broadcast_to(
+                    jnp.arange(t) < cache["moe_live"], ids.shape
+                )
+
+            def body(x, p, kv, kind, layer):
                 out, kc, vc = self._block(
-                    p, x, kc, vc, pos,
+                    p, x, *kv, pos,
                     tp_axis=tp_axis, adapter_ids=adapter_ids,
+                    kind=kind, live=live, layer=layer,
                 )
                 return out, (kc, vc)
 
-            x, (new_k, new_v) = lax.scan(
-                body, x, (params["stack"], cache["k"], cache["v"])
+            x, (new_k, new_v), stats = self.scan_layers(
+                body, x, params["stack"], (cache["k"], cache["v"])
             )
             logits = self._final_logits(params, x)
             new_cache = {"k": new_k, "v": new_v, "pos": pos + t}
             if adapter_ids is not None:
                 new_cache["adapter"] = adapter_ids
+            if stats is not None:
+                new_cache["moe_live"] = cache["moe_live"]
+                new_cache["moe"] = stats
             return logits, new_cache
 
         return step
@@ -911,7 +1110,7 @@ class GptDecoder:
             xn = _layer_norm(
                 xf,
                 params["final_ln_scale"],
-                params["final_ln_bias"],
+                params.get("final_ln_bias"),
                 cfg.layer_norm_eps,
             )
         head = params.get("lm_head", params["token_embedding"])
@@ -1354,7 +1553,7 @@ class SpmdGptDecoder(GptDecoder):
         from jax.sharding import NamedSharding
 
         cfg = self.cfg
-        dh = cfg.dim // cfg.num_heads
+        dh = cfg.dh
         shape = (cfg.num_layers, batch, cfg.kv_heads, cfg.max_len, dh)
         spec = self._cache_spec()
         # Allocate DIRECTLY sharded: materializing the full replicated

@@ -119,8 +119,11 @@ def from_hf_state_dict(
     separate lm_head in the checkpoint is ignored with a warning-free
     contract (tied models simply don't ship one).
     """
+    from defer_tpu.parallel.transformer_stack import refuse_mechanisms
+
+    refuse_mechanisms(cfg, "from_hf_state_dict (llama checkpoints)")
     L = cfg.num_layers
-    dh = cfg.dim // cfg.num_heads
+    dh = cfg.dh
 
     from defer_tpu.models.transplant import tensor_to_numpy
 

@@ -468,7 +468,7 @@ def draft_width_geometry(cfg, width: float) -> tuple[int, int, int]:
             f"width={width}: draft width fraction must be in (0, 1]"
         )
     kv = cfg.kv_heads
-    dh = cfg.dim // cfg.num_heads
+    dh = cfg.dh
     heads = kv * max(1, round(cfg.num_heads * width / kv))
     heads = min(heads, cfg.num_heads)
     ffn = max(1, round(cfg.ffn_dim * width))
@@ -510,6 +510,9 @@ def make_draft(
             "make_draft needs a GptDecoder-style (decoder, params) pair "
             "(a .cfg config and a params['stack'] block tree)"
         )
+    from defer_tpu.parallel.transformer_stack import refuse_mechanisms
+
+    refuse_mechanisms(cfg, "make_draft")
     if any(
         isinstance(v, dict) and "q" in v
         for v in list(params["stack"].values())
@@ -532,7 +535,7 @@ def make_draft(
         heads, dim, ffn = cfg.num_heads, cfg.dim, cfg.ffn_dim
     else:
         heads, dim, ffn = draft_width_geometry(cfg, width)
-    dims = {"d": dim, "f": ffn, "kv": cfg.kv_heads * (cfg.dim // cfg.num_heads)}
+    dims = {"d": dim, "f": ffn, "kv": cfg.kv_heads * cfg.dh}
 
     def cut(leaf, axes):
         idx = (slice(0, keep_l),) + tuple(

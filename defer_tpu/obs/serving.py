@@ -160,6 +160,45 @@ class ServingMetrics:
             "for the same ticks (B * max_blocks * block_size each)",
             labels,
         )
+        # The expert layer's counters (parallel/transformer_stack.py::
+        # held_experts_ffn), added by the host from what each step
+        # hands back with its tokens. A layer-step is one layer of one
+        # forward; the decode step's (`phase="decode"`, live slots'
+        # rows only) and admission's prefill (`phase="prefill"`, the
+        # prompt's rows) are kept apart, because a prefill's hundreds
+        # of rows would drown a decode step's few in one mean.
+        # assignments / layer_steps / experts held = tokens an expert
+        # sees a step; touched / layer_steps / experts held = the share
+        # of the held weights a step has to read.
+        def moe(phase):
+            pl = {**labels, "phase": phase}
+            return (
+                reg.counter(
+                    "defer_moe_assignments_held_total",
+                    "(token, expert) assignments of the router's top-k "
+                    "that fell on an expert held here, summed over "
+                    "layers and forwards", pl,
+                ),
+                reg.counter(
+                    "defer_moe_experts_touched_total",
+                    "Distinct held experts that received a token, "
+                    "summed over layers and forwards", pl,
+                ),
+                reg.counter(
+                    "defer_moe_layer_steps_total",
+                    "Expert layers computed: layers x forwards", pl,
+                ),
+            )
+
+        self.moe_decode = moe("decode")
+        self.moe_prefill = moe("prefill")
+        self.kv_rows_window_masked = reg.counter(
+            "defer_kv_rows_window_masked_total",
+            "KV cache rows (same unit as defer_kv_rows_read_total, "
+            "summed over the stack's sliding layers) that lay behind a "
+            "layer's window in a decode tick: gathered or not, the "
+            "layer attends none of them", labels,
+        )
         # Dispatch-efficiency instruments (fused decode windows,
         # runtime/*.py `decode_window`): one host dispatch drives up
         # to K decode sub-steps, so dispatches-per-token falls toward

@@ -89,10 +89,54 @@ class TransformerConfig:
     lora_rank: int = 0
     lora_targets: tuple = ("wq", "wv")
     lora_alpha: float | None = None  # scale = alpha / rank; None -> 1.0
+    # -- decoder layer shapes beyond llama's (defaults keep every
+    #    model above as it is) ---------------------------------------
+    # A head's size where it is not dim // num_heads: wq is then
+    # [dim, num_heads * head_dim] and wo its transpose's shape.
+    head_dim: int | None = None
+    # One norm a layer, attention and FFN both reading it:
+    # y = x + attn(n) + ffn(n), n = norm(x); no ln2 leaves.
+    parallel_block: bool = False
+    # norm_type="layer" without the bias leaves (mean subtracted,
+    # scale only).
+    norm_bias: bool = True
+    # Which lanes a rotary pair takes: "half" = (i, i + Dh/2), llama's
+    # rotate-half; "interleaved" = (2i, 2i + 1), GPT-J's.
+    rope_pairing: str = "half"
+    # A kind per layer over ONE period of the stack, each entry
+    # (window | None, rotary: bool); layer l is of kind l % period.
+    # None = every layer takes `window` and `pos_style`, as before.
+    layer_kinds: tuple | None = None
+    # The expert layer of the serving decoder (`held_experts_ffn`):
+    # `num_experts` is the PUBLISHED count, the router's width;
+    # `experts_held` = (lo, hi) says which of them live on this chip
+    # (None = all). "sigmoid" gates score each expert on its own.
+    experts_held: tuple | None = None
+    moe_gate: str = "softmax"
+    expert_dim: int | None = None  # one expert's width; None = ffn_dim
+    num_shared_experts: int = 0
+    shared_combine: str = "mean"  # "mean" | "sum" of the shared experts
 
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    @property
+    def dh(self) -> int:
+        """A head's size: `head_dim`, else the quotient."""
+        return self.head_dim or self.dim // self.num_heads
+
+    @property
+    def held(self) -> tuple[int, int]:
+        """The [lo, hi) range of published experts held here."""
+        return self.experts_held or (0, self.num_experts)
+
+    def kind_of(self, layer_kind) -> tuple:
+        """(window, rotary) of a layer: its entry of `layer_kinds`, or
+        the stack's one `window` and `pos_style` where it has none."""
+        if layer_kind is None:
+            return self.window, self.pos_style == "rope"
+        return layer_kind
 
     @property
     def lora_scale(self) -> float:
@@ -108,8 +152,6 @@ class TransformerConfig:
                 f"num_kv_heads={self.kv_heads} must divide "
                 f"num_heads={self.num_heads}"
             )
-        if self.ffn_style == "swiglu" and self.num_experts:
-            raise ValueError("swiglu MoE blocks are not supported")
         if self.window is not None and (
             self.window < 1 or not self.causal
         ):
@@ -129,6 +171,30 @@ class TransformerConfig:
                 f"moe_top_k={self.moe_top_k} must be in "
                 f"[1, num_experts={self.num_experts}]"
             )
+        if self.experts_held is not None:
+            lo, hi = self.experts_held
+            if not 0 <= lo < hi <= self.num_experts:
+                raise ValueError(
+                    f"experts_held={self.experts_held} must be a "
+                    f"non-empty range within num_experts={self.num_experts}"
+                )
+        if self.layer_kinds is not None:
+            if not self.layer_kinds or self.num_layers % len(self.layer_kinds):
+                raise ValueError(
+                    f"layer_kinds has {len(self.layer_kinds)} entries: "
+                    f"num_layers={self.num_layers} must be a whole "
+                    "number of periods"
+                )
+            for w, rotary in self.layer_kinds:
+                if w is not None and (w < 1 or not self.causal):
+                    raise ValueError(
+                        f"layer_kinds window {w} needs causal=True and "
+                        "window >= 1"
+                    )
+                if rotary and self.pos_style != "rope":
+                    raise ValueError(
+                        "a rotary layer kind needs pos_style='rope'"
+                    )
         if self.lora_rank:
             if self.lora_rank < 1:
                 raise ValueError(f"lora_rank={self.lora_rank} must be >= 1")
@@ -156,12 +222,40 @@ class TransformerConfig:
             ("ffn_style", ("gelu", "swiglu")),
             ("pos_style", ("learned", "rope")),
             ("moe_dispatch", ("dense", "a2a")),
+            ("rope_pairing", ("half", "interleaved")),
+            ("moe_gate", ("softmax", "sigmoid")),
+            ("shared_combine", ("mean", "sum")),
         ):
             v = getattr(self, field)
             if v not in allowed:
                 raise ValueError(
                     f"{field}={v!r}: must be one of {allowed}"
                 )
+
+
+def refuse_mechanisms(cfg: TransformerConfig, option: str) -> None:
+    """Raise, naming the mechanism and the option, where a path that
+    computes one homogeneous dense stack is asked to serve a model with
+    layer kinds, experts or a parallel block. The default paged path
+    (`PagedDecodeServer` at its defaults) and the flat step serve them;
+    nothing else has been held to the reference, and none may run such
+    a model as a silently homogeneous stack."""
+    named = [
+        name
+        for name, on in (
+            ("layer kinds (cfg.layer_kinds)", cfg.layer_kinds is not None),
+            ("experts (cfg.num_experts)", bool(cfg.num_experts)),
+            ("a parallel block (cfg.parallel_block)", cfg.parallel_block),
+        )
+        if on
+    ]
+    if named:
+        raise ValueError(
+            f"{option} does not serve a model with {' and '.join(named)}: "
+            "it computes one kind of layer with a dense FFN and two "
+            "norms. Serve this model on PagedDecodeServer's default "
+            "path (attention='gathered', no other option)."
+        )
 
 
 #: Projections whose INPUT axis is tp-sharded (Megatron row-parallel,
@@ -173,12 +267,13 @@ _ROW_PARALLEL = frozenset({"wo", "w2"})
 def lora_target_dims(cfg: TransformerConfig) -> dict:
     """(in_dim, out_dim) for every projection an adapter can target."""
     D, F = cfg.dim, cfg.ffn_dim
-    dkv = cfg.kv_heads * (D // cfg.num_heads)
+    dq = cfg.num_heads * cfg.dh
+    dkv = cfg.kv_heads * cfg.dh
     dims = {
-        "wq": (D, D),
+        "wq": (D, dq),
         "wk": (D, dkv),
         "wv": (D, dkv),
-        "wo": (D, D),
+        "wo": (dq, D),
         "w1": (D, F),
         "w2": (F, D),
     }
@@ -194,38 +289,52 @@ def init_stack(
 
     The key set follows the config: GQA narrows wk/wv to the KV head
     width, use_bias=False drops every b*, norm_type="rms" drops the
-    norm biases, and ffn_style="swiglu" adds the w3 up-projection."""
+    norm biases, ffn_style="swiglu" adds the w3 up-projection, a
+    parallel block has no second norm, and SwiGLU experts (the serving
+    decoder's, `held_experts_ffn`) are the HELD experts' matrices under
+    a router of the published width, plus the shared experts'."""
     L, D, F = cfg.num_layers, cfg.dim, cfg.ffn_dim
-    dkv = cfg.kv_heads * (D // cfg.num_heads)
+    dq = cfg.num_heads * cfg.dh
+    dkv = cfg.kv_heads * cfg.dh
     ks = jax.random.split(rng, 8)
     s = D**-0.5
+    norms = ("ln1",) if cfg.parallel_block else ("ln1", "ln2")
     p = {
-        "wq": jax.random.normal(ks[0], (L, D, D), dtype) * s,
+        "wq": jax.random.normal(ks[0], (L, D, dq), dtype) * s,
         "wk": jax.random.normal(ks[1], (L, D, dkv), dtype) * s,
         "wv": jax.random.normal(ks[2], (L, D, dkv), dtype) * s,
-        "wo": jax.random.normal(ks[3], (L, D, D), dtype) * s,
-        "ln1_scale": jnp.ones((L, D), dtype),
-        "ln2_scale": jnp.ones((L, D), dtype),
+        "wo": jax.random.normal(ks[3], (L, dq, D), dtype) * dq**-0.5,
     }
+    p.update({f"{n}_scale": jnp.ones((L, D), dtype) for n in norms})
     if cfg.use_bias:
         p.update(
             {
-                "bq": jnp.zeros((L, D), dtype),
+                "bq": jnp.zeros((L, dq), dtype),
                 "bk": jnp.zeros((L, dkv), dtype),
                 "bv": jnp.zeros((L, dkv), dtype),
                 "bo": jnp.zeros((L, D), dtype),
             }
         )
-    if cfg.norm_type == "layer":
-        p.update(
-            {
-                "ln1_bias": jnp.zeros((L, D), dtype),
-                "ln2_bias": jnp.zeros((L, D), dtype),
-            }
-        )
-    if cfg.ffn_style == "swiglu":
-        p["w3"] = jax.random.normal(ks[7], (L, D, F), dtype) * s
-    if cfg.num_experts:
+    if cfg.norm_type == "layer" and cfg.norm_bias:
+        p.update({f"{n}_bias": jnp.zeros((L, D), dtype) for n in norms})
+    if cfg.num_experts and cfg.ffn_style == "swiglu":
+        lo, hi = cfg.held
+        Fe = cfg.expert_dim or F
+        kr, ksh = jax.random.split(ks[6])
+
+        def experts(key, n):
+            k1, k2, k3 = jax.random.split(key, 3)
+            return (
+                jax.random.normal(k1, (L, n, D, Fe), dtype) * s,
+                jax.random.normal(k3, (L, n, D, Fe), dtype) * s,
+                jax.random.normal(k2, (L, n, Fe, D), dtype) * Fe**-0.5,
+            )
+
+        p["router"] = jax.random.normal(kr, (L, D, cfg.num_experts), dtype) * s
+        p["w1"], p["w3"], p["w2"] = experts(ks[4], hi - lo)
+        if cfg.num_shared_experts:
+            p["sw1"], p["sw3"], p["sw2"] = experts(ksh, cfg.num_shared_experts)
+    elif cfg.num_experts:
         E = cfg.num_experts
         p.update(
             {
@@ -245,6 +354,8 @@ def init_stack(
                 * (F**-0.5),
             }
         )
+        if cfg.ffn_style == "swiglu":
+            p["w3"] = jax.random.normal(ks[7], (L, D, F), dtype) * s
         if cfg.use_bias:
             p["b1"] = jnp.zeros((L, F), dtype)
             p["b2"] = jnp.zeros((L, D), dtype)
@@ -591,6 +702,126 @@ def moe_ffn_a2a(
     return out.astype(dt).reshape(b, s, d)
 
 
+# Rows of one tile of an expert's tokens in `held_experts_ffn`: an
+# expert's weights (100 MB at 4096 x 4096 SwiGLU in bf16) are read once
+# a tile, so a tile must hold enough rows to pay for the read, and a
+# step of fewer tokens than this is one tile an expert.
+_EXPERT_TILE = 256
+
+
+#: The leaves of `held_experts_ffn` that a layer scan must NOT slice:
+#: they stay layer-stacked and are indexed [layer, expert] where a
+#: product reads them (see its docstring).
+EXPERT_LEAVES = ("w1", "w3", "w2", "sw1", "sw3", "sw2")
+
+
+def held_experts_ffn(
+    p: dict, x: jax.Array, cfg: TransformerConfig, live=None, layer=None
+):
+    """The serving decoder's expert layer on (B, T, D), told which
+    experts it holds (`cfg.experts_held` of the `cfg.num_experts` the
+    router scores): it routes over ALL published experts, normalises
+    the chosen `moe_top_k` weights over the chosen whether held or not,
+    and returns the HELD experts' part of the routed sum plus the
+    shared experts' mean (or sum). What the absent experts would add
+    is left out: on one chip the layer runs without its exchange.
+
+    No capacity and no dropped token. Each held expert's tokens are
+    brought to the front of an order (a stable argsort of its column of
+    the assignment matrix) and computed in tiles of `_EXPERT_TILE`
+    rows, as many tiles as its count needs (a traced trip count: an
+    expert nobody chose computes nothing), so the work follows the
+    assignments that fell here and every shape is static. One form for
+    decode (a tile is then the whole batch) and prefill.
+
+    With `layer` given, the `EXPERT_LEAVES` of `p` are still
+    layer-stacked ([L, E, ...]) and `layer` (an int or a traced scalar)
+    picks the layer: each product then slices its one matrix out of
+    the whole stack inside the loop that uses it. A layer's experts
+    sliced outside (a scan's xs) would be a loop operand, and XLA
+    copies a loop's operands: 512 MB a leaf a layer at 16 x 4096 x
+    4096, more than the step should read in all.
+
+    `live` (B, T) bool marks the rows the counters count (None = all).
+    Returns (y, stats): stats int32 [2] = the assignments that fell on
+    held experts and the distinct held experts touched, live rows only.
+    """
+    dt = x.dtype
+    b, t, d = x.shape
+    n = b * t
+    xf = x.reshape(n, d)
+    lo, hi = cfg.held
+    eh = hi - lo
+    with jax.named_scope("moe_router"):
+        logits = jnp.dot(
+            xf.astype(jnp.float32),
+            p["router"].astype(jnp.float32),
+            precision=lax.Precision.HIGHEST,
+        )
+        scores = (
+            jax.nn.sigmoid(logits)
+            if cfg.moe_gate == "sigmoid"
+            else jax.nn.softmax(logits, axis=-1)
+        )
+        w, idx = lax.top_k(scores, cfg.moe_top_k)  # (N, k)
+        if cfg.moe_top_k > 1:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        # gate[n, e]: token n's weight on held expert lo + e, 0 where
+        # it did not choose it (a chosen weight is never 0: sigmoid
+        # and softmax are positive).
+        sel = jax.nn.one_hot(idx - lo, eh, dtype=jnp.float32)  # (N, k, eh)
+        gate = (sel * w[..., None]).sum(axis=1)  # (N, eh)
+        chosen = gate > 0
+        counted = chosen if live is None else chosen & live.reshape(n, 1)
+        stats = jnp.stack(
+            [counted.sum(), counted.any(axis=0).sum()]
+        ).astype(jnp.int32)
+
+    def weight(name, e=None):
+        idx = tuple(i for i in (layer, e) if i is not None)
+        return p[name][idx].astype(dt)
+
+    def swiglu(rows, e):
+        h = jax.nn.silu(rows @ weight("w1", e)) * (rows @ weight("w3", e))
+        return h @ weight("w2", e)
+
+    tile = min(n, _EXPERT_TILE)
+    with jax.named_scope("moe_experts"):
+        out = jnp.zeros((n, d), jnp.float32)
+        for e in range(eh):
+            mine = chosen[:, e]
+            count = mine.sum()
+            # This expert's tokens first, in their order; past `count`
+            # the order holds tokens that did not choose it, and the
+            # tile's last rows are masked by `count`.
+            order = jnp.argsort(~mine, stable=True)
+            if n % tile:
+                order = jnp.pad(order, (0, tile - n % tile))
+
+            def one_tile(i, out, e=e, order=order, count=count):
+                rows = lax.dynamic_slice_in_dim(order, i * tile, tile)
+                y = swiglu(xf[rows], e)
+                wt = jnp.where(
+                    i * tile + jnp.arange(tile) < count, gate[rows, e], 0.0
+                )
+                return out.at[rows].add(y.astype(jnp.float32) * wt[:, None])
+
+            out = lax.fori_loop(0, -(-count // tile), one_tile, out)
+    if "sw1" in p:
+        with jax.named_scope("moe_shared"):
+            ys = jnp.einsum(
+                "snf,sfd->nd",
+                jax.nn.silu(jnp.einsum("nd,sdf->snf", xf, weight("sw1")))
+                * jnp.einsum("nd,sdf->snf", xf, weight("sw3")),
+                weight("sw2"),
+                preferred_element_type=jnp.float32,
+            )
+            if cfg.shared_combine == "mean":
+                ys = ys / cfg.num_shared_experts
+            out = out + ys
+    return out.astype(dt).reshape(b, t, d), stats
+
+
 def embed_lookup(
     table: Any, ids: jax.Array, tp_axis: str | None = None
 ) -> jax.Array:
@@ -626,9 +857,10 @@ def _layer_norm(x, scale, bias, eps):
     mean = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
     out = (xf - mean) * lax.rsqrt(var + eps)
-    return (out * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(
-        x.dtype
-    )
+    out = out * scale.astype(jnp.float32)
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
+    return out.astype(x.dtype)
 
 
 def _rms_norm(x, scale, eps):
@@ -643,7 +875,7 @@ def norm_apply(cfg: TransformerConfig, x, p: dict, which: str):
     if cfg.norm_type == "rms":
         return _rms_norm(x, p[f"{which}_scale"], cfg.layer_norm_eps)
     return _layer_norm(
-        x, p[f"{which}_scale"], p[f"{which}_bias"], cfg.layer_norm_eps
+        x, p[f"{which}_scale"], p.get(f"{which}_bias"), cfg.layer_norm_eps
     )
 
 
@@ -652,6 +884,7 @@ def apply_rope(
     head_dim: int,
     positions: jax.Array,
     theta: float,
+    pairing: str = "half",
 ) -> jax.Array:
     """Rotary position embedding on a flat (B, T, H*Dh) projection.
 
@@ -664,18 +897,35 @@ def apply_rope(
     shape (T,) shared across the batch (decode passes cache_pos +
     arange(T); sequence-parallel shards pass their global offsets) or
     (B, T) per batch element (continuous batching, where every slot
-    sits at its own depth)."""
+    sits at its own depth). `pairing="interleaved"` rotates lanes
+    (2i, 2i + 1) together instead (GPT-J's convention), at the same
+    frequencies."""
     b, t, d = x_flat.shape
     x = x_flat.reshape(b, t, d // head_dim, head_dim)
     half = head_dim // 2
-    freqs = theta ** (
-        -jnp.arange(0, half, dtype=jnp.float32) * 2.0 / head_dim
-    )
-    ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., T, half)
+    if pairing == "interleaved":
+        # Lane j turns with its neighbour j ^ 1 at the pair's frequency.
+        lane = jnp.arange(head_dim)
+        freqs = theta ** (-(lane // 2).astype(jnp.float32) * 2.0 / head_dim)
+    else:
+        freqs = theta ** (
+            -jnp.arange(0, half, dtype=jnp.float32) * 2.0 / head_dim
+        )
+    ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., T, lanes)
     if ang.ndim == 2:  # shared positions -> add the batch axis
         ang = ang[None]
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if pairing == "interleaved":
+        # The partner comes by a roll along the lanes: splitting them
+        # into (Dh/2, 2) would leave a minor axis of 2, which the
+        # TPU's (8, 128) tiles pad 64-fold.
+        xf = x.astype(jnp.float32)
+        partner = jnp.where(
+            lane % 2 == 0, -jnp.roll(xf, -1, axis=-1), jnp.roll(xf, 1, axis=-1)
+        )
+        out = (xf * cos + partner * sin).astype(x_flat.dtype)
+        return out.reshape(b, t, d)
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(
         jnp.float32
     )
@@ -718,10 +968,18 @@ def block_apply(
     ring / Ulysses over that mesh axis (defer_tpu/parallel/sequence.py);
     everything else in the block is per-token and needs no collective.
     """
+    if cfg.layer_kinds is not None or cfg.parallel_block or (
+        cfg.num_experts and cfg.ffn_style == "swiglu"
+    ):
+        raise ValueError(
+            "the training block computes one kind of layer, two norms "
+            "and GELU experts: layer_kinds, parallel_block and SwiGLU "
+            "experts are the serving decoder's (models/gpt.py)"
+        )
     dt = x.dtype
     tp_size = 1 if tp_axis is None else lax.axis_size(tp_axis)
     local_heads = cfg.num_heads // tp_size
-    dh = cfg.dim // cfg.num_heads
+    dh = cfg.dh
     groups = cfg.num_heads // cfg.kv_heads
     pre = cfg.norm_style == "pre"
 
@@ -750,8 +1008,8 @@ def block_apply(
             0 if sp_axis is None else lax.axis_index(sp_axis) * s_local
         )
         positions = offset + jnp.arange(s_local)
-        q = apply_rope(q, dh, positions, cfg.rope_theta)
-        k = apply_rope(k, dh, positions, cfg.rope_theta)
+        q = apply_rope(q, dh, positions, cfg.rope_theta, cfg.rope_pairing)
+        k = apply_rope(k, dh, positions, cfg.rope_theta, cfg.rope_pairing)
     # GQA: expand KV head groups AFTER rope so each query head in a
     # group attends its shared (rotated) KV head.
     k = repeat_kv(k, dh, groups)

@@ -830,7 +830,7 @@ class _PPLocalStage:
         self.submesh = submesh
         self.model_axis = model_axis if submesh is not None else None
         cfg = dec.cfg
-        dh = cfg.dim // cfg.num_heads
+        dh = cfg.dh
         pool_shape = (
             last - first, num_blocks, cfg.kv_heads, block_size, dh,
         )
@@ -1199,6 +1199,26 @@ class PagedDecodeServer:
         suffix."""
         if getattr(dec, "rolling_cache", False):
             raise ValueError("paged serving does not support rolling caches")
+        # Layer kinds, experts and a parallel block are served by the
+        # default path alone (the gathered step, the flat prefill at
+        # admission, `_tick_plain`): every other option names what it
+        # cannot serve instead of running a homogeneous dense stack.
+        from defer_tpu.parallel.transformer_stack import refuse_mechanisms
+
+        for option, asked in (
+            (f"attention={attention!r}", attention != "gathered"),
+            (f"kv_dtype={kv_dtype!r}", kv_dtype != "fp"),
+            ("decode_window > 1", decode_window > 1),
+            ("spec_k > 0", bool(spec_k)),
+            ("prefill_chunk", prefill_chunk is not None),
+            ("prefill_budget", prefill_budget is not None),
+            ("pp_stages > 1", pp_stages > 1),
+            ("mesh=", mesh is not None),
+            ("prefix_cache=True", prefix_cache),
+            ("prefix_ids=", prefix_ids is not None),
+        ):
+            if asked:
+                refuse_mechanisms(dec.cfg, f"PagedDecodeServer({option})")
         # Multi-LoRA: adapter banks (parallel/lora.py::stack_adapters)
         # make the slot -> adapter assignment per-slot state, same as
         # the flat server; id 0 = base model.
@@ -1208,6 +1228,7 @@ class PagedDecodeServer:
         self.multi_lora = n_adapters is not None
         if self.multi_lora:
             self.num_adapters = n_adapters
+            refuse_mechanisms(dec.cfg, "PagedDecodeServer(multi-LoRA banks)")
         if block_size < 1 or num_blocks < 2:
             raise ValueError(
                 f"need block_size >= 1 and num_blocks >= 2 (one trash "
@@ -1526,7 +1547,7 @@ class PagedDecodeServer:
         self._rungs = (
             span_rungs(self.MB) if attention == "gathered" else (self.MB,)
         )
-        dh = cfg.dim // cfg.num_heads
+        dh = cfg.dh
         self.kv_dtype = kv_dtype
         self.num_blocks = num_blocks
         pool_shape = (
@@ -1665,7 +1686,7 @@ class PagedDecodeServer:
                 if pp_devices is not None
                 else jax.devices()
             )
-            dh_ = cfg.dim // cfg.num_heads
+            dh_ = cfg.dh
             itemsize = jnp.dtype(dec.compute_dtype).itemsize
             for s in range(self.pp):
                 first_l, last_l = bounds[s], bounds[s + 1]
@@ -2021,6 +2042,11 @@ class PagedDecodeServer:
         from the prompt ids — draft prefill is the cheap side of the
         asymmetry, so decode-worker speculation keeps the disagg
         split's point.)"""
+        from defer_tpu.parallel.transformer_stack import refuse_mechanisms
+
+        refuse_mechanisms(
+            self.dec.cfg, "disagg ingest (submit_prefilled/deliver_kv)"
+        )
         if self.pp > 1:
             raise ValueError(
                 "disagg ingest (submit_prefilled/deliver_kv) does not "
@@ -2110,7 +2136,7 @@ class PagedDecodeServer:
             n_need,
             cfg.kv_heads,
             self.bs,
-            cfg.dim // cfg.num_heads,
+            cfg.dh,
         )
         if tuple(k_blocks.shape) != expect or tuple(v_blocks.shape) != expect:
             raise ValueError(
@@ -2299,7 +2325,7 @@ class PagedDecodeServer:
         cfg = self.dec.cfg
         expect = (
             cfg.num_layers, n, cfg.kv_heads, self.bs,
-            cfg.dim // cfg.num_heads,
+            cfg.dh,
         )
         if tuple(k_blocks.shape) != expect or tuple(v_blocks.shape) != expect:
             raise ValueError(
@@ -2483,6 +2509,15 @@ class PagedDecodeServer:
         self.obs.kv_rows_read.inc(rows_read // tp)
         self.obs.kv_rows_gathered.inc(baseline // tp)
 
+    def _account_moe(self, counters, stats: np.ndarray) -> None:
+        """Add one forward's expert-layer counters (`stats` [L, 2]:
+        per layer the assignments on held experts and the held experts
+        touched) to the phase's three instruments."""
+        assignments, touched, layer_steps = counters
+        assignments.inc(int(stats[:, 0].sum()))
+        touched.inc(int(stats[:, 1].sum()))
+        layer_steps.inc(stats.shape[0])
+
     def _account_psums(self, n_forwards: int) -> None:
         """Count the cross-shard collectives `n_forwards` sharded
         transformer forwards issue (per forward: attn + ffn psum per
@@ -2543,6 +2578,8 @@ class PagedDecodeServer:
         so both paths run identical math by construction."""
         dec, bs = self.dec, self.bs
         tp = self._tp_axis()
+        if dec.cfg.layer_kinds is not None or dec.cfg.num_experts:
+            return self._step_body_kinds()
 
         def step(params, pk, pv, tables, pos, ids, adapter_ids):
             b = ids.shape[0]
@@ -2585,6 +2622,60 @@ class PagedDecodeServer:
             )
             logits = self._replicate_logits(dec._final_logits(params, x))
             return logits, pk, pv
+
+        return step
+
+    def _step_body_kinds(self):
+        """The gathered step of a stack whose layers differ in kind
+        (`cfg.layer_kinds`: a window or none, rotary or not) or hold
+        experts: the same gather, `_block` and single-row scatter as
+        `_step_body`, under `GptDecoder.scan_layers` (periods, each
+        layer's window the flash-decode kernel's static argument). The
+        pool rides in the scan's carry and is read and written at
+        [layer, block] in place; its layout is `_step_body`'s. Returns
+        `(logits, stats)` first where the decoder has experts: stats
+        int32 [L, 2], per layer the assignments that fell on held
+        experts and the distinct held experts touched, over live rows
+        (an idle slot sits at position 0, which no live one does)."""
+        dec, bs = self.dec, self.bs
+        experts = bool(dec.cfg.num_experts)
+
+        def step(params, pk, pv, tables, pos, ids, adapter_ids):
+            b = ids.shape[0]
+            x = dec._embed_tokens(params, ids, pos, None)
+            rows = jnp.arange(b)
+            live = (pos > 0)[:, None]
+            blk = tables[rows, pos // bs]  # [B]
+            row = pos % bs
+
+            def body(carry, p, _, kind, l):
+                x, pk, pv = carry
+                with jax.named_scope("kv_gather"):
+                    kc = pk[l, tables]  # [B, MB, Hkv, bs, Dh]
+                    vc = pv[l, tables]
+                    b_, mb, hkv, _, dh = kc.shape
+                    kc = kc.transpose(0, 2, 1, 3, 4).reshape(
+                        b_, hkv, mb * bs, dh
+                    )
+                    vc = vc.transpose(0, 2, 1, 3, 4).reshape(
+                        b_, hkv, mb * bs, dh
+                    )
+                out, kc, vc = dec._block(
+                    p, x, kc, vc, pos,
+                    adapter_ids=adapter_ids, kind=kind, live=live, layer=l,
+                )
+                x, stats = out if experts else (out, None)
+                with jax.named_scope("kv_scatter"):
+                    pk = pk.at[l, blk, :, row, :].set(kc[rows, :, pos, :])
+                    pv = pv.at[l, blk, :, row, :].set(vc[rows, :, pos, :])
+                carry = (x, pk, pv)
+                return ((carry, stats) if experts else carry), None
+
+            (x, pk, pv), _, stats = dec.scan_layers(
+                body, (x, pk, pv), params["stack"]
+            )
+            logits = dec._final_logits(params, x)
+            return ((logits, stats) if experts else logits), pk, pv
 
         return step
 
@@ -4487,11 +4578,15 @@ class PagedDecodeServer:
                     small["adapter"] = jnp.full(
                         (1,), adapter_id, jnp.int32
                     )
+                if "moe_live" in small:
+                    # The rows past the prompt are the bucket's padding.
+                    small["moe_live"] = jnp.asarray(t0, jnp.int32)
                 logits, small = self._flat_dec().make_step(donate=False)(
                     self.params, small, padded
                 )
                 self._account_psums(1)
                 self._note_prefill_stall(1)
+                moe = small.get("moe")
             with spans.span("paged.admit.seat.insert"):
                 self.pool_k, self.pool_v = self._insert(
                     self.pool_k,
@@ -4548,6 +4643,10 @@ class PagedDecodeServer:
                 or slot["stop"] is not None
             )
             tok = int(first[0, 0]) if need_host else None
+            if self.dec.cfg.num_experts:
+                # The prefill's expert counters came back with its
+                # logits: ready where the host waited for the token.
+                self._account_moe(self.obs.moe_prefill, np.asarray(moe))
         self._emit_token(i, slot, tok)
 
     # -- mixed-mode admission + tick (prefill_budget=) ----------------
@@ -5039,6 +5138,9 @@ class PagedDecodeServer:
         with spans.span("paged.tick", live=live) as sp:
             sp.keep = live > 0  # a poll of an empty server leaves no record
             sp.counts.update(self._tick_variant())
+            if self.dec.cfg.num_experts:
+                lo, hi = self.dec.cfg.held
+                sp.counts["experts_held"] = hi - lo
 
     def _tick_variant(self) -> dict:
         """Run the tick this server's mode calls for; what to record
@@ -5105,6 +5207,11 @@ class PagedDecodeServer:
                 feed,
                 adapter,
             )
+            moe = None
+            if self.dec.cfg.num_experts:
+                # An expert decoder's step hands its counters back
+                # beside the logits (`_step_body_kinds`).
+                logits, moe = logits
         with spans.span("paged.tick.sample"):
             self.ticks += 1
             self.dispatches += 1
@@ -5144,6 +5251,14 @@ class PagedDecodeServer:
                 )
                 rows_read = int(np.sum(posm // self.bs - lo + 1)) * self.bs
             self._account_kv_rows(rows_read, baseline)
+            for w, _ in self.dec.cfg.layer_kinds or ():
+                if w is not None:
+                    # Rows behind this sliding layer's window (an idle
+                    # slot sits at position 0 and adds none).
+                    self.obs.kv_rows_window_masked.inc(
+                        int(np.maximum(posm + 1 - w, 0).sum())
+                        * (self.dec.cfg.num_layers // len(self.dec.cfg.layer_kinds))
+                    )
             ll = logits[:, -1, :]
             sm = self._sampler
             # Constrained rows (defer_tpu/constrain/): fold the DFA mask
@@ -5190,6 +5305,11 @@ class PagedDecodeServer:
             # when an eos/stop/stream consumer needs host tokens — the
             # sync this serving loop is designed around
             host_nxt = np.asarray(nxt) if need_host else None
+            if moe is not None:
+                # analysis: ignore[host-sync-in-hot-loop] [L, 2] int32
+                # of the step that made the logits above: ready with
+                # the tokens, at the same sync point
+                self._account_moe(self.obs.moe_decode, np.asarray(moe))
             if constrained:
                 # analysis: ignore[host-sync-in-hot-loop] one batched
                 # per-tick transfer of the dead-end flags + mask
