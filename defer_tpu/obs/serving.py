@@ -60,6 +60,13 @@ class ServingMetrics:
             "defer_tokens_generated_total",
             "Tokens emitted by decode slots (incl. first token)", labels,
         )
+        self.tokens_resolved_at_finish = reg.counter(
+            "defer_tokens_resolved_at_finish_total",
+            "Tokens whose value the host first read when their request "
+            "finished: no eos, stream or stop consumer needed it sooner "
+            "(over defer_tokens_generated_total: the share served "
+            "without a transfer per tick)", labels,
+        )
         self.prefill_tokens = reg.counter(
             "defer_prefill_tokens_total",
             "Prompt tokens run through prefill", labels,

@@ -994,6 +994,14 @@ class PagedDecodeServer:
     for later revival, and eviction happens only under pool pressure.
     This generalizes the constructor-level `prefix_ids` (one global
     system prompt, still supported, mutually exclusive).
+
+    A live slot (`slots[i]`) records its request on the host: `prompt`
+    as it arrived and `out`, the generated tokens as Python ints from
+    the tick's one batched transfer. With no eos, callback or stop
+    sequence a tick makes no transfer, and `out` holds `(device array,
+    index)` in a token's place, which `_finish` fetches. `done[rid]`
+    is the two joined on the host: a `[1, T0 + n]` array in the
+    prompt's dtype.
     """
 
     def __init__(
@@ -4177,8 +4185,8 @@ class PagedDecodeServer:
         slot = {
             "rid": rid,
             "remaining": steps - 1,
-            "last": first,
-            "toks": [prompt, first],
+            "prompt": prompt,
+            "out": [],
             "blocks": owned,
             "shared": shared,
             "sampling": samp is not None,
@@ -4200,14 +4208,7 @@ class PagedDecodeServer:
             time.perf_counter() - self._submit_t.pop(rid)
         )
         self._update_pool_gauges()
-        need_host = (
-            self.eos_id is not None
-            or self.on_token is not None
-            or slot["stop"] is not None
-        )
-        self._emit_token(
-            i, slot, int(first[0, 0]) if need_host else None
-        )
+        self._emit_token(i, slot, self._first_on_host(slot, first))
         return True
 
     def _ensure_insert_dyn(self):
@@ -4334,8 +4335,8 @@ class PagedDecodeServer:
         slot = {
             "rid": rid,
             "remaining": steps - 1,
-            "last": first,
-            "toks": [jnp.asarray(prompt), first],
+            "prompt": prompt,
+            "out": [],
             "blocks": owned,
             "sampling": samp is not None,
             "stop": matcher_or_none(entry["stop"]),
@@ -4358,14 +4359,7 @@ class PagedDecodeServer:
             time.perf_counter() - self._submit_t.pop(rid)
         )
         self._update_pool_gauges()
-        need_host = (
-            self.eos_id is not None
-            or self.on_token is not None
-            or slot["stop"] is not None
-        )
-        self._emit_token(
-            i, slot, int(first[0, 0]) if need_host else None
-        )
+        self._emit_token(i, slot, self._first_on_host(slot, first))
         return True
 
     def _first_token(self, i, samp, lrow, dtype, cid):
@@ -4393,6 +4387,24 @@ class PagedDecodeServer:
             self.obs.constrained_tokens.inc()
             self.constrained_tokens_n += 1
         return first
+
+    def _first_on_host(self, slot: dict, first) -> int | tuple:
+        """Admission's first token as `_emit_token` takes it: its value
+        where speculation has read it or eos, streaming or a stop
+        sequence consumes it (same guard as `_tick`), else where it
+        lies, so that the plain path stays async."""
+        if "pend" in slot:
+            return slot["pend"][0]
+        if (
+            self.eos_id is not None
+            or self.on_token is not None
+            or slot["stop"] is not None
+        ):
+            # analysis: ignore[host-sync-in-hot-loop] one scalar
+            # transfer per REQUEST (its first token; a mixed tick's
+            # flip comes here too), and only for a consumer
+            return int(first[0, 0])
+        return first, (0, 0)
 
     def _constrained_preds(self, logits, props, k):
         """Target-side constrained greedy walk for one speculative
@@ -4606,8 +4618,8 @@ class PagedDecodeServer:
             slot = {
                 "rid": rid,
                 "remaining": steps - 1,
-                "last": first,
-                "toks": [prompt, first],
+                "prompt": prompt,
+                "out": [],
                 "blocks": blocks,
                 "sampling": samp is not None,
                 "stop": matcher_or_none(stop_seqs),
@@ -4633,16 +4645,9 @@ class PagedDecodeServer:
                 time.perf_counter() - self._submit_t.pop(rid)
             )
             self._update_pool_gauges()
-            # Host transfer only when eos/streaming/stop matching
-            # consumes the value (same guard as _tick) — the plain
-            # path stays async. It is where the host waits for the
-            # prefill to end, so it closes the phase.
-            need_host = (
-                self.eos_id is not None
-                or self.on_token is not None
-                or slot["stop"] is not None
-            )
-            tok = int(first[0, 0]) if need_host else None
+            # Where a consumer needs the value the host waits here for
+            # the prefill to end, so it closes the phase.
+            tok = self._first_on_host(slot, first)
             if self.dec.cfg.num_experts:
                 # The prefill's expert counters came back with its
                 # logits: ready where the host waited for the token.
@@ -4889,8 +4894,8 @@ class PagedDecodeServer:
             ]
         first = self._first_token(i, samp, lrow, prompt.dtype, cid)
         slot["remaining"] = steps - 1
-        slot["last"] = first
-        slot["toks"] = [prompt, first]
+        slot["prompt"] = prompt
+        slot["out"] = []
         slot["stop"] = matcher_or_none(meta["stop"])
         slot["cid"] = cid
         self._feed = self._feed.at[i].set(first[0].astype(jnp.int32))
@@ -4901,17 +4906,7 @@ class PagedDecodeServer:
             time.perf_counter() - self._submit_t.pop(rid)
         )
         self._update_pool_gauges()
-        need_host = (
-            self.eos_id is not None
-            or self.on_token is not None
-            or slot["stop"] is not None
-        )
-        # analysis: ignore[host-sync-in-hot-loop] one scalar transfer
-        # per REQUEST (its first token), and only when an
-        # eos/stop/stream consumer needs the value — the admission
-        # sync every admit path already performs
-        tok = int(first[0, 0]) if need_host else None
-        self._emit_token(i, slot, tok)
+        self._emit_token(i, slot, self._first_on_host(slot, first))
 
     def _account_kv_rows_mixed(self, posm, t: int) -> None:
         """Pool rows one mixed dispatch's attention read (decode-tick
@@ -5080,7 +5075,7 @@ class PagedDecodeServer:
         # analysis: ignore[host-sync-in-hot-loop] single batched
         # transfer per mixed tick, and only when an eos/stop/stream
         # consumer needs host tokens — same guard as every tick path
-        host_nxt = np.asarray(nxt) if need_host else None
+        host_nxt = np.asarray(nxt).tolist() if need_host else None
         if constrained:
             # analysis: ignore[host-sync-in-hot-loop] one batched
             # per-tick transfer of the dead-end flags + mask
@@ -5109,16 +5104,11 @@ class PagedDecodeServer:
                 self.obs.constrain_masked_frac.observe(
                     float(mfrac_host[i])
                 )
-            tok = nxt[i][None, None].astype(slot["last"].dtype)
-            slot["last"] = tok
-            slot["toks"].append(tok)
             slot["remaining"] -= 1
             self.pos[i] += 1
             accepted += 1
             self._emit_token(
-                i,
-                slot,
-                int(host_nxt[i]) if host_nxt is not None else None,
+                i, slot, host_nxt[i] if need_host else (nxt, i)
             )
         # Seats advance AFTER the decode drain: pos moves chunk by
         # chunk, and the seat whose last chunk just landed flips to
@@ -5304,7 +5294,7 @@ class PagedDecodeServer:
             # transfer per WINDOW (a window of one token here), and only
             # when an eos/stop/stream consumer needs host tokens — the
             # sync this serving loop is designed around
-            host_nxt = np.asarray(nxt) if need_host else None
+            host_nxt = np.asarray(nxt).tolist() if need_host else None
             if moe is not None:
                 # analysis: ignore[host-sync-in-hot-loop] [L, 2] int32
                 # of the step that made the logits above: ready with
@@ -5341,13 +5331,10 @@ class PagedDecodeServer:
                     self.obs.constrain_masked_frac.observe(
                         float(mfrac_host[i])
                     )
-                tok = nxt[i][None, None].astype(slot["last"].dtype)
-                slot["last"] = tok
-                slot["toks"].append(tok)
                 slot["remaining"] -= 1
                 self.pos[i] += 1
                 self._emit_token(
-                    i, slot, int(host_nxt[i]) if host_nxt is not None else None
+                    i, slot, host_nxt[i] if need_host else (nxt, i)
                 )
         return nb * self.bs
 
@@ -5592,17 +5579,10 @@ class PagedDecodeServer:
                     self.obs.constrain_masked_frac.observe(
                         float(fracs_host[i][j])
                     )
-            # analysis: ignore[host-sync-in-hot-loop] emitted is a
-            # host int list — this UPLOADS the kept tokens, no fetch
-            kept_arr = np.asarray(emitted[:kept], np.int32)[None, :]
-            tok_block = jnp.asarray(kept_arr).astype(
-                slot["last"].dtype
-            )
-            slot["toks"].append(tok_block)
-            slot["last"] = tok_block[:, -1:]
+            toks_host[i] = emitted[:kept]
+            slot["out"] += toks_host[i]
             self.pos[i] += kept
             accepted[i] = kept
-            toks_host[i] = emitted[:kept]
             finishing[i] = slot["remaining"] == 0
             self.obs.tokens_generated.inc(kept)
             self.window_tokens += kept
@@ -5917,25 +5897,8 @@ class PagedDecodeServer:
             slot["remaining"] -= n_i
             if finishing[i] or not alive_h[i]:
                 slot["remaining"] = 0
-            # analysis: ignore[host-sync-in-hot-loop] packs already-
-            # fetched host token lists (no device fetch)
-            kept_arr = np.asarray(
-                [
-                    t
-                    for r in range(W)
-                    for t in (stream_toks[i][r]
-                              if stream_toks[i] else [])
-                ],
-                np.int32,
-            )[None, :]
-            # jnp.asarray is a host->device upload of the kept tokens
-            # (no fetch) — _tick_spec's idiom; not a sync hazard.
-            tok_block = jnp.asarray(kept_arr).astype(
-                slot["last"].dtype
-            )
-            if n_i:
-                slot["toks"].append(tok_block)
-                slot["last"] = tok_block[:, -1:]
+            for row in stream_toks[i] or ():
+                slot["out"] += row
             self.pos[i] += n_i
             finishing[i] = slot["remaining"] == 0
             self.obs.tokens_generated.inc(n_i)
@@ -6453,11 +6416,12 @@ class PagedDecodeServer:
                     self.obs.constrained_tokens.inc(a_i)
                 for fr in fracs_host[i][:a_i].tolist():
                     self.obs.constrain_masked_frac.observe(fr)
-            tok_block = toks[i, :a_i][None, :].astype(
-                slot["last"].dtype
-            )
-            slot["toks"].append(tok_block)
-            slot["last"] = tok_block[:, -1:]
+            if toks_host is not None:
+                slot["out"] += toks_host[i][:a_i]
+            elif a_i:
+                # No consumer asked for the values: `_finish` reads
+                # this slot's part of the window's buffer.
+                slot["out"].append((toks, (i, slice(0, a_i))))
             self.pos[i] += a_i
             finishing[i] = slot["remaining"] == 0
             self.obs.tokens_generated.inc(a_i)
@@ -6475,11 +6439,15 @@ class PagedDecodeServer:
             if finishing[i]:
                 self._finish(i)
 
-    def _emit_token(self, i: int, slot: dict, tok: int | None) -> None:
-        """Shared eos/streaming/finish bookkeeping for one emitted
-        token (admission first-token and every tick): `tok` is the
-        host-side token value, or None when neither eos nor streaming
-        needed the transfer."""
+    def _emit_token(self, i: int, slot: dict, tok: int | tuple) -> None:
+        """Record one emitted token in the slot and do the shared
+        eos/streaming/finish bookkeeping (admission first-token and
+        every tick): `tok` is the token's value on the host or, where
+        neither eos nor streaming nor a stop sequence needed the
+        transfer, where `_finish` will find it: (device array, index)."""
+        slot["out"].append(tok)
+        if isinstance(tok, tuple):
+            tok = None
         self.obs.tokens_generated.inc()
         if (
             self.eos_id is not None
@@ -6504,12 +6472,37 @@ class PagedDecodeServer:
 
     def _finish(self, i: int) -> None:
         slot = self.slots[i]
-        # Arrays joined below beside the prompt: one a token on the
-        # default path (the window and spec drains append several).
-        n_toks = len(slot["toks"]) - 1
-        with spans.span("paged.finish", rid=slot["rid"], tokens=n_toks):
+        with spans.span("paged.finish", rid=slot["rid"]) as sp:
             self.obs.requests_finished.inc()
-            self.done[slot["rid"]] = jnp.concatenate(slot["toks"], axis=1)
+            # Joined on the host, so no program is built whatever the
+            # lengths: a slot's tokens are ints, but for those no
+            # consumer needed before now. One transfer fetches each
+            # such array (jax keeps the host copy, and the slots of a
+            # tick share the array), one uploads the result.
+            # analysis: ignore[host-sync-in-hot-loop] once per REQUEST,
+            # of a prompt the device finished with at admission
+            prompt = np.asarray(slot["prompt"])
+            out: list[int] = []
+            late = 0
+            for tok in slot["out"]:
+                if isinstance(tok, tuple):
+                    arr, idx = tok
+                    # analysis: ignore[host-sync-in-hot-loop] once per
+                    # REQUEST, and only for tokens no consumer read as
+                    # they were made: the wait the ticks did not make
+                    got = np.asarray(arr)[idx].reshape(-1).tolist()
+                    late += len(got)
+                    out += got
+                else:
+                    out.append(tok)
+            if late:
+                self.obs.tokens_resolved_at_finish.inc(late)
+            n_toks = sp.counts["tokens"] = len(out)
+            # analysis: ignore[host-sync-in-hot-loop] a host int list
+            ids = np.asarray(out, prompt.dtype)[None, :]
+            self.done[slot["rid"]] = jax.device_put(
+                np.concatenate([prompt, ids], axis=1), self.device
+            )
             if self.radix is not None:
                 # Shared blocks deref (parking at refcount 0 for later
                 # revival); only privately owned blocks free immediately.
@@ -6534,7 +6527,7 @@ class PagedDecodeServer:
             spans.record(
                 "paged.request", slot["submit_t"], time.perf_counter(),
                 rid=slot["rid"], queue_s=slot["queue_s"],
-                prompt_tokens=slot["toks"][0].shape[1], tokens=n_toks,
+                prompt_tokens=prompt.shape[1], tokens=n_toks,
             )
 
 

@@ -38,6 +38,20 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 
 
+def pytest_configure(config):
+    """Under `--dist loadfile` pytest-xdist hands out the files with
+    the most tests first, so which files share a worker's process, and
+    in what order, moves with every test a PR adds. The registry of
+    `defer_tpu.obs` is one per process, and the benchmark's rehearsal
+    (`tests/perfbench_rehearsal/`) reads all of it: it has to run
+    before a file that starts a flat `DecodeServer` registers that
+    server's instruments beside the paged server's. In the order of
+    collection the rehearsal's files come first, each at the head of
+    a worker's queue."""
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
 @pytest.fixture(scope="session")
 def devices():
     devs = jax.devices()

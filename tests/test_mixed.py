@@ -243,9 +243,9 @@ def test_decode_never_skips_a_tick_while_prompt_prefills(model):
     ), "long prompt should be seated mid-prefill"
     prefill_ticks = 0
     while any(s is not None and "prefill" in s for s in srv.slots):
-        before = len(srv.slots[i0]["toks"])
+        before = len(srv.slots[i0]["out"])
         srv._tick()
-        assert len(srv.slots[i0]["toks"]) == before + 1, (
+        assert len(srv.slots[i0]["out"]) == before + 1, (
             "decode slot skipped a tick while the prompt prefilled"
         )
         prefill_ticks += 1

@@ -250,8 +250,8 @@ def test_no_program_is_built_while_depths_cross_rungs(ladder):
     assert [r.counts["span_rows"] for r in rungs] == [16, 24, 64]
     assert all(r.t1 <= ticks[0].t0 for r in rungs)
     # What the listener saw of them lies inside them, and nowhere else
-    # near the step: a tick's other builds are the drain's and the
-    # finish's small eager programs (PERF.md 7a).
+    # near the step: a tick's other builds are the small eager
+    # programs of its sampling (PERF.md 7a).
     inside = [r for r in builds if r.parent in {g.id for g in rungs}]
     assert {r.counts["kind"] for r in inside} >= {"lowered"}
     under = {by_id[r.parent].name for r in builds if r.parent in by_id}
