@@ -38,7 +38,7 @@ import time
 
 import numpy as np
 
-from perfbench import metrics, peaks, traffic, xplane
+from perfbench import metrics, peaks, program_spans, traffic, xplane
 
 clock = time.perf_counter
 
@@ -135,6 +135,10 @@ class Run:
     builds_open: dict = dataclasses.field(default_factory=dict)
     builds_close: dict = dataclasses.field(default_factory=dict)
     blocks_peak: int = 0
+    # Requests due as the window opens (a backlog's queue), and those
+    # still in `srv.pending` as it closes.
+    queued_at_open: int = 0
+    pending_at_close: int = 0
     trace: xplane.Reduced | None = None
 
     def in_window(self, t: float) -> bool:
@@ -339,26 +343,6 @@ def check_correct(srv, run, family, params, seed, span):
     }
 
 
-def prebuild_shapes(requests, mesh) -> None:
-    """The server joins a finished request's tokens with an eager
-    `jnp.concatenate` whose shape is (prompt, tokens), one small
-    program for each pair. Run the same call once for each pair of
-    this run, so that none is built inside the window. On a mesh the
-    server's tokens are replicated over it, and a program is keyed on
-    that too. A guess at what the program does: harmless where it is
-    wrong, and `compiles_in_window` then says so."""
-    import jax
-    import jax.numpy as jnp
-
-    one = jnp.zeros((1, 1), jnp.int32)
-    if mesh is not None:
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        one = jax.device_put(one, NamedSharding(mesh, PartitionSpec()))
-    for t0, steps in sorted({(r.prompt_tokens, r.output_tokens) for r in requests}):
-        jnp.concatenate([jnp.zeros((1, t0), jnp.int32)] + [one] * steps, axis=1)
-
-
 def read_trace(trace_dir: str):
     """What the trace held (for the details line) and its reduction;
     the trace itself is deleted."""
@@ -482,15 +466,13 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
     )
     prompts = [jnp.asarray(a) for a in ids]
     # Warm up this cell's shapes and no others. The program builds
-    # small programs for every new prompt length and every new pair of
-    # (prompt, tokens), and a run's lengths are nearly all different:
-    # so one request of a single token for each prompt length (its
-    # prefill bucket and the programs keyed on the length; it ends at
-    # admission and the decode step is warm from the check), then the
-    # joins of each pair. What this costs is in `setup_s`.
+    # small programs for every new prompt length, and a run's lengths
+    # are nearly all different: so one request of a single token for
+    # each prompt length (its prefill bucket and the programs keyed on
+    # the length; it ends at admission and the decode step is warm
+    # from the check). What this costs is in `setup_s`.
     by_length = {r.prompt_tokens: p for p, r in zip(prompts, requests)}
     serve_now(srv, run, [(by_length[t], 1) for t in sorted(by_length)], span)
-    prebuild_shapes(requests, mesh)
     t_warm = clock()
 
     # The standing population, seated now so that the window opens in
@@ -524,6 +506,7 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
     schedule = collections.deque(
         (run.t_open + r.due_s, p, r.output_tokens, note) for p, r in arrivals
     )
+    run.queued_at_open = sum(1 for _, r in arrivals if r.due_s <= 0)
     run.counters_open = counters(srv)
     run.builds_open = builds.snapshot()
     raised = 0
@@ -544,6 +527,7 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
     run.registry_close = registry()
     run.builds_close = builds.snapshot()
     run.blocks_peak = srv.blocks_peak
+    run.pending_at_close = len(srv.pending)
     # A request due after the window closed was never attempted.
     run.due = {rid: d for rid, d in run.due.items() if d <= run.t_close}
 
@@ -588,6 +572,9 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
     ticks = run.window_ticks()
     fifths = [run.t_open + run.seconds * k / 5 for k in range(1, 6)]
     first = {rid: s[0] for rid, s in rec.stamps.items()}
+    tick_spans = program_spans.in_window(run, "paged.tick")
+    admits = run.window_admits()
+    calls = sorted([k[:2] for k in ticks] + [a[:2] for a in admits])
     details = {
         "workload": workload["name"], "seed": args.seed,
         "seconds": run.t_close - run.t_open, "setup_s": setup_s,
@@ -597,7 +584,8 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
             1 for rid in rec.done if run.in_window(rec.stamps[rid][-1])
         ),
         "standing": len(standing),
-        "pending_at_close": len(srv.pending),
+        "queued_at_open": run.queued_at_open,
+        "pending_at_close": run.pending_at_close,
         # Requests due and not yet answered, and live slots, at each
         # fifth of the window: a queue that grows says the rate is
         # over the knee.
@@ -608,12 +596,31 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
         "live_at_fifths": [
             next((k[3] for k in reversed(ticks) if k[0] <= t), None) for t in fifths
         ],
+        # When the last request was seated, from the window's opening:
+        # a backlog that lasts seats one in the last seconds.
+        "last_seated_s": max((a[1] - run.t_open for a in admits), default=None),
         "ttft_p50_s": run.ttft_p50(),
         "itl_mean_s": sum(gaps) / len(gaps) if gaps else None,
         # The longest tick and when it began, from the window's opening.
         "tick_s_max": max(
             ((k[1] - k[0], k[0] - run.t_open) for k in ticks), default=None
         ),
+        # The longest admission that seated a request, and the longest
+        # stretch between two calls that emitted tokens (the loop, and
+        # `_admit` calls that seated nothing), each with when it began:
+        # with `tick_s_max` they say where a second-long stall fell.
+        "admit_s_max": max(
+            ((a[1] - a[0], a[0] - run.t_open) for a in admits), default=None
+        ),
+        "between_calls_s_max": max(
+            ((b[0] - a[1], a[1] - run.t_open) for a, b in zip(calls, calls[1:])),
+            default=None,
+        ),
+        # The window's ticks by the rung of the span ladder the program
+        # says it ran them on: the order of a queue moves this share.
+        "ticks_by_span_rows": dict(
+            collections.Counter(str(r.counts.get("span_rows")) for r in tick_spans)
+        ) if tick_spans is not None else None,
         "warm_up_s": t_warm - t_check,
         "prompt_lengths": len(by_length),
         "late_s_p50": metrics.median(run.late) if run.late else None,
@@ -623,11 +630,11 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
             "itl": len(gaps),
             "ttft": len(run.due),
             "ticks": len(ticks),
-            "admits": len(run.window_admits()),
+            "admits": len(admits),
             "dispatches": len(run.dispatches()),
         },
         "itl_s": {
-            f"p{q}": metrics.percentile(gaps, q) for q in (50, 90, 95, 97, 99)
+            f"p{q}": metrics.percentile(gaps, q) for q in (50, 90, 95, 96, 97, 98, 99)
         } if gaps else None,
         "pool_blocks": server_args["num_blocks"],
         "blocks_peak": run.blocks_peak,

@@ -6,7 +6,12 @@ What the traffic file and the cell fix, every seed shares: the set of
 (prompt, output) sizes, the set of gaps between arrivals, the standing
 population. `--seed` decides the order of both sets and the token ids.
 So two seeds offer the same work in another order, and the set of
-shapes the server sees is the same in every run.
+shapes the server sees is the same in every run. A backlog is the
+exception: its queue outlasts the window, so the order of the queue
+decides which requests are served at all, and the order is the work.
+Every seed gets the same queue, one random order drawn with the
+sizes; the seed draws the token ids, the weights and the order in
+which the standing population is seated.
 
 Vocabulary of a traffic file:
 
@@ -14,7 +19,8 @@ Vocabulary of a traffic file:
                                                      stratified set, mean 1/rate,
                                                      in seeded order (a Poisson
                                                      process held to its count)
-            {"kind": "backlog"}                      all due at time 0
+            {"kind": "backlog"}                      all due at time 0, in one
+                                                     random order for every seed
             {"kind": "bursts", "share_of_knee": s,
              "on_s": a, "off_s": b, "factor": f}     on at f x mean rate,
                                                      off at what keeps the mean
@@ -123,7 +129,10 @@ def generate(
         due = warp(np.cumsum(gaps), arrival, rate)
     prompts = draw_lengths(traffic["prompt_tokens"], n, fixed)
     outputs = draw_lengths(traffic["output_tokens"], n, fixed)
-    shuffle = order.permutation(n)
+    # Only a prefix of a backlog is served, so its order is the work
+    # (seeds in seeded order differed by 2.9% in tokens/s where one
+    # seed repeats to 0.3%: PERF.md, PR 31): one order for every seed.
+    shuffle = (fixed if rate is None else order).permutation(n)
     requests = [
         Request(float(due[i]), int(prompts[j]), int(outputs[j]), False)
         for i, j in enumerate(shuffle)

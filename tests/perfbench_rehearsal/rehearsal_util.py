@@ -31,13 +31,21 @@ def real_benchmark() -> dict:
         return json.load(f)
 
 
+TINY_BACKLOG = dict(
+    TINY_TRAFFIC, arrival={"kind": "backlog"}, standing={"population": "max_batch"}
+)
+TINY_CELL = {"knee_per_s": 8.0, "lifetime_s": 0.4}
+
+
 def tiny_root(
-    tmp: str, mesh=None, chips: int = 1, model: dict = TINY_MODEL, families=None
+    tmp: str, mesh=None, chips: int = 1, model: dict = TINY_MODEL, families=None,
+    traffic: dict = TINY_TRAFFIC, cell: dict = TINY_CELL,
 ) -> str:
-    """BENCHMARK.json's metrics over one toy cell of `model`, in `tmp`.
-    The code directories are links to the real ones; the family files
-    of the directory `families`, where one is given, are linked beside
-    the real families."""
+    """BENCHMARK.json's metrics over one toy cell of `model` under
+    `traffic`, sized by `cell`, in `tmp`. The code directories are
+    links to the real ones; the family files of the directory
+    `families`, where one is given, are linked beside the real
+    families."""
     bench = real_benchmark()
     real = os.path.join(REPO, "perfbench")
     os.makedirs(os.path.join(tmp, "perfbench"))
@@ -60,8 +68,8 @@ def tiny_root(
             json.dump(obj, f)
 
     dump(model, "configs", "tiny.json")
-    dump(TINY_TRAFFIC, "traffic", "toy.json")
-    dump({"knee_per_s": 8.0, "lifetime_s": 0.4}, "cells", "tiny.toy.json")
+    dump(traffic, "traffic", "toy.json")
+    dump(cell, "cells", "tiny.toy.json")
     for group in ("end_to_end", "per_layer"):
         for m in bench[group]:
             m.pop("workloads", None)

@@ -18,8 +18,8 @@ def cpu_peaks(monkeypatch):
     monkeypatch.setitem(peaks.PEAKS, "cpu", dict(peaks.PEAKS["TPU v5 lite"]))
 
 
-def run_tiny(tmp_path, trace, mesh=None, chips=1):
-    root = rehearsal_util.tiny_root(str(tmp_path), mesh=mesh, chips=chips)
+def run_tiny(tmp_path, trace, mesh=None, chips=1, **tree):
+    root = rehearsal_util.tiny_root(str(tmp_path), mesh=mesh, chips=chips, **tree)
     lines = []
     rc = run.main(
         ["--workload", "tiny.toy", "--seed", str(2**31 + 11), "--seconds", "2",
@@ -46,6 +46,12 @@ def test_last_line_has_exactly_the_contracts_keys(tmp_path, cpu_peaks):
     # Warm-up covered every shape: nothing was built inside the window.
     assert details["programs_built"]["window"]["lowered"] == 0
     assert details["standing"] >= 1 and details["late_s_max"] < 0.5
+    # Where a stall would show: every stretch of the window is a tick,
+    # a seating admission or the time between two calls.
+    for key in ("tick_s_max", "admit_s_max", "between_calls_s_max"):
+        assert 0 < details[key][0] < 2 and 0 <= details[key][1] < 2
+    # Every tick of the window under the rung the program ran it on.
+    assert sum(details["ticks_by_span_rows"].values()) >= details["samples"]["ticks"] - 1
 
 
 def test_traced_run_reports_per_layer_metrics_on_a_mesh(tmp_path, cpu_peaks):
@@ -60,6 +66,31 @@ def test_traced_run_reports_per_layer_metrics_on_a_mesh(tmp_path, cpu_peaks):
     assert got["compiles_in_window"]["value"] == 0
     assert "device_idle_share" not in got and "breakdown" not in result
     assert details["trace_lines"] == {}
+    # An open loop queues nothing at time 0: no share of a queue to read.
+    assert details["queued_at_open"] == 0 and "queue_left_share" not in got
+
+
+@pytest.mark.parametrize("queue, backlog_per_s", [("outlasts", 4000.0), ("is outlasted", 1.0)])
+def test_queue_left_share_says_what_is_left_of_a_backlog(tmp_path, cpu_peaks, queue, backlog_per_s):
+    result, details = run_tiny(
+        tmp_path, trace=1, traffic=rehearsal_util.TINY_BACKLOG,
+        cell={"backlog_per_s": backlog_per_s},
+    )
+    assert result["correct"] is True and result["failed"] == 0
+    queued = details["queued_at_open"]
+    assert queued == round(backlog_per_s * 2) and details["standing"] == 4
+    left = result["metrics"]["queue_left_share"]
+    assert left["unit"] == "%"
+    assert left["value"] == pytest.approx(100.0 * details["pending_at_close"] / queued)
+    if queue == "outlasts":
+        # The window closes on a queue and on full slots, a request
+        # seated in its last moments.
+        assert 0 < left["value"] < 100 and details["pending_at_close"] > 0
+        assert details["live_at_fifths"] == [4] * 5
+        assert details["last_seated_s"] > 1.0
+    else:
+        assert left["value"] == 0 and details["pending_at_close"] == 0
+        assert result["attempted"] == queued
 
 
 def test_refuses_anything_but_a_tpu_and_too_few_chips(tmp_path, cpu_peaks):
