@@ -2,13 +2,12 @@
 
 ROADMAP's hardware-tier item asks for tokens-per-dispatch and
 kv-rows-read budget checks "so a future PR can't silently regress the
-hot path". This pass makes those budgets DECLARED state instead of
-prose: ``budgets.toml`` names each contract, the obs counter that
-accounts for it, the hot functions that must feed that counter, and a
-numeric bound on a bench-artifact metric. ``defer-analyze --budget
-budgets.toml`` then enforces both halves:
+hot path". This pass makes the accounting behind those budgets
+DECLARED state instead of prose: ``budgets.toml`` names each contract,
+the obs counter that accounts for it and the hot functions that must
+feed that counter. ``defer-analyze --budget budgets.toml`` then checks,
+for every contract, that
 
-Static half (always runs)
     - the contract's counter is registered somewhere in the corpus
       (``reg.counter("defer_..."...)`` with a literal name);
     - every function the contract names exists AND reaches — through
@@ -16,20 +15,17 @@ Static half (always runs)
       one touch of the counter's pre-bound handle attribute
       (``self.obs.host_dispatches.inc()``). A hot loop that stops
       feeding its accounting counter is exactly the silent-regression
-      failure mode: the bench metric would go stale while still
-      looking green.
+      failure mode: the number the tests and the benchmark read would
+      go stale while still looking green.
 
-Measured half (when bench data exists)
-    - the contract's ``bench_metric`` dotted path is read out of the
-      latest ``BENCH_*.json`` (or an explicit ``--bench`` file, or the
-      in-memory result dict when bench.py itself calls in) and checked
-      against ``max``/``min``. A section the bench round never ran is
-      ``no-data`` — only a present-and-violated bound fails, so
-      CPU-tier rounds that skip the tp sweep don't fail the gate.
+The gate is static: it claims that the counter is fed, not what it
+reads. The values are asserted by the CPU tests each contract's
+description names and measured on the chip by ``perfbench/``. A file
+that still carries a bound is rejected, so that none reads as enforced.
 
-Both halves report through the normal Finding stream (rule
-``perf-contract``), so ``--strict --json`` consumers and the bench
-extras section see budget state next to lint state.
+Findings report through the normal Finding stream (rule
+``perf-contract``), so ``--strict --json`` consumers see budget state
+next to lint state.
 
 Python 3.10 has no ``tomllib``; a strict subset parser (tables,
 strings, numbers, booleans, flat arrays) backs it so the gate needs
@@ -40,9 +36,6 @@ from __future__ import annotations
 
 import ast
 import dataclasses
-import glob
-import json
-import os
 import re
 from typing import Any
 
@@ -155,10 +148,10 @@ class Contract:
     functions: tuple[str, ...]  # hot functions that must feed it
     line: int  # declaration line in budgets.toml (1 if unknown)
     description: str = ""
-    max_value: float | None = None  # bound on the bench metric
-    min_value: float | None = None
-    bench_section: str | None = None  # key in the bench result dict
-    bench_metric: str | None = None  # dotted path inside the section
+
+
+# Keys that bounded numbers out of an artifact nothing produces now.
+_MEASURED_KEYS = ("bench_section", "bench_metric", "max", "min")
 
 
 def load_budgets(path: str) -> list[Contract]:
@@ -186,21 +179,13 @@ def load_budgets(path: str) -> list[Contract]:
             raise BudgetError(
                 f"{where}: missing `functions` (array of strings)"
             )
-        bounds = {}
-        for key in ("max", "min"):
-            v = tab.get(key)
-            if v is not None and not isinstance(v, (int, float)):
-                raise BudgetError(f"{where}: `{key}` must be numeric")
-            bounds[key] = float(v) if v is not None else None
-        if (
-            bounds["max"] is not None or bounds["min"] is not None
-        ) and not (
-            isinstance(tab.get("bench_section"), str)
-            and isinstance(tab.get("bench_metric"), str)
-        ):
+        stale = [k for k in _MEASURED_KEYS if k in tab]
+        if stale:
             raise BudgetError(
-                f"{where}: a max/min bound needs `bench_section` and "
-                "`bench_metric` naming what it bounds"
+                f"{where}: {', '.join(f'`{k}`' for k in stale)}: the "
+                "gate's measured half is gone — it is static and bounds "
+                "no number. Assert the value in the test that produces "
+                "it and name that test in `description`"
             )
         out.append(
             Contract(
@@ -209,16 +194,12 @@ def load_budgets(path: str) -> list[Contract]:
                 functions=tuple(funcs),
                 line=int(tab.get("__line__", 1)),
                 description=str(tab.get("description", "")),
-                max_value=bounds["max"],
-                min_value=bounds["min"],
-                bench_section=tab.get("bench_section"),
-                bench_metric=tab.get("bench_metric"),
             )
         )
     return out
 
 
-# -- static half ------------------------------------------------------
+# -- the check --------------------------------------------------------
 
 
 def _metric_handles(ctx: Context) -> dict[str, set[str]]:
@@ -265,203 +246,96 @@ def _touches(fn_node: ast.AST, attrs: set[str]) -> bool:
 
 def check_static(
     ctx: Context, contracts: list[Contract], budget_path: str
-) -> list[Finding]:
-    """Registration + reachable-touch checks; findings point at the
-    contract declaration in budgets.toml."""
+) -> tuple[list[Finding], list[dict[str, str]]]:
+    """Registration + reachable-touch checks. Returns the findings,
+    which point at the contract's declaration in budgets.toml, and a
+    JSON-ready verdict per contract: ``fail`` where it raised any."""
     handles = _metric_handles(ctx)
     out: list[Finding] = []
+    verdicts: list[dict[str, str]] = []
     for c in contracts:
-        attrs = handles.get(c.counter)
-        if not attrs:
-            out.append(
-                Finding(
-                    "perf-contract",
-                    budget_path,
-                    c.line,
-                    0,
-                    f"[contract.{c.name}] accounts through "
-                    f"{c.counter!r} but no analyzed module registers "
-                    "that metric — the contract can never be measured",
-                )
-            )
-            continue
-        for fname in c.functions:
-            cands = ctx.graph.by_name.get(fname, [])
-            if not cands:
-                out.append(
-                    Finding(
-                        "perf-contract",
-                        budget_path,
-                        c.line,
-                        0,
-                        f"[contract.{c.name}] names hot function "
-                        f"{fname!r}, which does not exist in the "
-                        "analyzed corpus",
-                    )
-                )
-                continue
-            # BFS from the named functions; ANY candidate chain
-            # touching the handle satisfies the contract (both decode
-            # servers define `_tick`; each feeds the shared metric).
-            seen: set[int] = set()
-            frontier = list(cands)
-            found = False
-            while frontier and not found:
-                fi = frontier.pop()
-                if id(fi.node) in seen:
-                    continue
-                seen.add(id(fi.node))
-                if _touches(fi.node, attrs):
-                    found = True
-                    break
-                for bare, calls in (
-                    (True, fi.calls_bare),
-                    (False, fi.calls_attr),
-                ):
-                    for callee in calls:
-                        frontier.extend(
-                            r
-                            for r in ctx.graph.resolve_call(
-                                fi, callee, bare
-                            )
-                            if id(r.node) not in seen
-                        )
-            if not found:
-                out.append(
-                    Finding(
-                        "perf-contract",
-                        budget_path,
-                        c.line,
-                        0,
-                        f"[contract.{c.name}]: nothing reachable from "
-                        f"`{fname}` touches the {c.counter!r} handle "
-                        f"({'/'.join(sorted(attrs))}) — the hot loop "
-                        "stopped feeding its accounting counter, so "
-                        "the budget would go stale while looking green",
-                    )
-                )
-    return out
-
-
-# -- measured half ----------------------------------------------------
-
-
-def latest_bench_json(search_dir: str = ".") -> tuple[str, dict] | None:
-    """Newest BENCH_*.json under `search_dir` (non-recursive), parsed.
-    None when there is none or the newest one is unreadable."""
-    cands = sorted(
-        glob.glob(os.path.join(search_dir, "BENCH_*.json")),
-        key=lambda p: (os.path.getmtime(p), p),
-    )
-    for path in reversed(cands):
-        try:
-            with open(path, encoding="utf-8") as fh:
-                data = json.load(fh)
-        except (OSError, ValueError):
-            continue
-        if isinstance(data, dict):
-            return path, data
-    return None
-
-
-def _bench_sections(data: dict) -> dict:
-    """The dict bench sections live in: bench.py's in-memory result
-    holds them at top level; the committed round artifacts nest the
-    measurement under `parsed`."""
-    parsed = data.get("parsed")
-    if isinstance(parsed, dict):
-        return parsed
-    return data
-
-
-def _navigate(section: Any, dotted: str) -> Any:
-    """`windows.8.dispatches_per_token` through a JSON round-trip:
-    integer-looking segments try both the int and str key."""
-    cur = section
-    for part in dotted.split("."):
-        if not isinstance(cur, dict):
-            return None
-        if part in cur:
-            cur = cur[part]
-            continue
-        try:
-            ipart = int(part)
-        except ValueError:
-            return None
-        if ipart in cur:
-            cur = cur[ipart]
-        else:
-            return None
-    return cur
-
-
-def evaluate_bench(
-    contracts: list[Contract], bench: dict, source: str
-) -> list[dict[str, Any]]:
-    """Per-contract measured verdicts: status pass|fail|no-data plus
-    the observed value and the violated bound, JSON-ready."""
-    sections = _bench_sections(bench)
-    out: list[dict[str, Any]] = []
-    for c in contracts:
-        rec: dict[str, Any] = {
-            "contract": c.name,
-            "counter": c.counter,
-            "bench_section": c.bench_section,
-            "bench_metric": c.bench_metric,
-            "source": source,
-            "status": "no-data",
-            "value": None,
-        }
-        if c.bench_section is None or c.bench_metric is None:
-            rec["status"] = "static-only"
-            out.append(rec)
-            continue
-        section = sections.get(c.bench_section)
-        value = (
-            _navigate(section, c.bench_metric)
-            if isinstance(section, dict)
-            else None
+        found = _check_contract(ctx, c, handles.get(c.counter), budget_path)
+        out.extend(found)
+        verdicts.append(
+            {
+                "contract": c.name,
+                "counter": c.counter,
+                "status": "fail" if found else "pass",
+            }
         )
-        if not isinstance(value, (int, float)) or isinstance(
-            value, bool
-        ):
-            out.append(rec)
-            continue
-        rec["value"] = value
-        rec["status"] = "pass"
-        if c.max_value is not None and value > c.max_value:
-            rec["status"] = "fail"
-            rec["bound"] = {"max": c.max_value}
-        elif c.min_value is not None and value < c.min_value:
-            rec["status"] = "fail"
-            rec["bound"] = {"min": c.min_value}
-        out.append(rec)
-    return out
+    return out, verdicts
 
 
-def bench_findings(
-    verdicts: list[dict[str, Any]],
-    contracts: list[Contract],
-    budget_path: str,
+def _check_contract(
+    ctx: Context, c: Contract, attrs: set[str] | None, budget_path: str
 ) -> list[Finding]:
-    by_name = {c.name: c for c in contracts}
     out: list[Finding] = []
-    for v in verdicts:
-        if v["status"] != "fail":
-            continue
-        c = by_name[v["contract"]]
-        bound_kind, bound_val = next(iter(v["bound"].items()))
-        cmp = ">" if bound_kind == "max" else "<"
+    if not attrs:
         out.append(
             Finding(
                 "perf-contract",
                 budget_path,
                 c.line,
                 0,
-                f"[contract.{c.name}] violated by {v['source']}: "
-                f"{c.bench_section}.{c.bench_metric} = {v['value']} "
-                f"{cmp} {bound_kind} {bound_val} — the measured hot "
-                "path regressed past its declared budget",
+                f"[contract.{c.name}] accounts through "
+                f"{c.counter!r} but no analyzed module registers "
+                "that metric — the contract can never be measured",
             )
         )
+        return out
+    for fname in c.functions:
+        cands = ctx.graph.by_name.get(fname, [])
+        if not cands:
+            out.append(
+                Finding(
+                    "perf-contract",
+                    budget_path,
+                    c.line,
+                    0,
+                    f"[contract.{c.name}] names hot function "
+                    f"{fname!r}, which does not exist in the "
+                    "analyzed corpus",
+                )
+            )
+            continue
+        # BFS from the named functions; ANY candidate chain
+        # touching the handle satisfies the contract (both decode
+        # servers define `_tick`; each feeds the shared metric).
+        seen: set[int] = set()
+        frontier = list(cands)
+        found = False
+        while frontier and not found:
+            fi = frontier.pop()
+            if id(fi.node) in seen:
+                continue
+            seen.add(id(fi.node))
+            if _touches(fi.node, attrs):
+                found = True
+                break
+            for bare, calls in (
+                (True, fi.calls_bare),
+                (False, fi.calls_attr),
+            ):
+                for callee in calls:
+                    frontier.extend(
+                        r
+                        for r in ctx.graph.resolve_call(
+                            fi, callee, bare
+                        )
+                        if id(r.node) not in seen
+                    )
+        if not found:
+            out.append(
+                Finding(
+                    "perf-contract",
+                    budget_path,
+                    c.line,
+                    0,
+                    f"[contract.{c.name}]: nothing reachable from "
+                    f"`{fname}` touches the {c.counter!r} handle "
+                    f"({'/'.join(sorted(attrs))}) — the hot loop "
+                    "stopped feeding its accounting counter, so "
+                    "the budget would go stale while looking green",
+                )
+            )
     return out

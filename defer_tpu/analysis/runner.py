@@ -3,8 +3,8 @@
 ``python -m defer_tpu.analysis --strict defer_tpu/`` is part of the
 tier-1 verify recipe (ROADMAP.md): exit 0 means every rule is clean or
 carries a justified inline ignore. The obs registry gets
-``defer_analysis_findings_total{rule=...}`` so bench extras and
-``--json`` consumers can track finding counts over time (0 in CI).
+``defer_analysis_findings_total{rule=...}`` so ``--json`` consumers
+can track finding counts over time (0 in CI).
 """
 
 from __future__ import annotations
@@ -27,11 +27,7 @@ from defer_tpu.analysis.rules import RULES, Context, Finding, Module
 import defer_tpu.analysis.domains  # noqa: E402,F401
 import defer_tpu.analysis.shardcheck  # noqa: E402,F401
 from defer_tpu.analysis.budget import (  # noqa: E402
-    BudgetError,
-    bench_findings,
     check_static,
-    evaluate_bench,
-    latest_bench_json,
     load_budgets,
 )
 
@@ -42,7 +38,7 @@ class AnalysisReport:
     suppressed: list[tuple[Finding, Ignore]]
     files: int
     # Per-contract verdicts when the run carried a budgets file
-    # ({"path": ..., "bench": ..., "contracts": [...]}); None otherwise.
+    # ({"path": ..., "contracts": [...]}); None otherwise.
     budget: dict[str, Any] | None = None
 
     @property
@@ -97,15 +93,10 @@ def analyze_paths(
     roots: Sequence[str] = DEFAULT_ROOTS,
     strict: bool = False,
     budget: str | None = None,
-    bench: str | dict | None = None,
 ) -> AnalysisReport:
     """Run the (selected) rules over every .py file under `paths`.
 
-    `budget` names a contracts file (budgets.toml) to enforce; `bench`
-    optionally supplies measured numbers for its cross-check — a path
-    to a BENCH_*.json, or the in-memory result dict when bench.py
-    calls in on itself. With `budget` set and `bench` unset, the
-    newest BENCH_*.json in the current directory is used when present.
+    `budget` names a contracts file (budgets.toml) to enforce.
     Raises BudgetError (a ValueError) on a malformed contracts file.
     """
     unknown = set(rules or ()) - set(RULES)
@@ -136,30 +127,9 @@ def analyze_paths(
     budget_state: dict[str, Any] | None = None
     if budget is not None:
         contracts = load_budgets(budget)  # raises BudgetError
-        raw.extend(check_static(ctx, contracts, budget))
-        bench_data: dict | None = None
-        source_name = ""
-        if isinstance(bench, dict):
-            bench_data, source_name = bench, "<in-memory bench result>"
-        elif isinstance(bench, str):
-            with open(bench, encoding="utf-8") as fh:
-                bench_data = json.load(fh)
-            source_name = bench
-        else:
-            found = latest_bench_json(".")
-            if found is not None:
-                source_name, bench_data = found
-        verdicts = (
-            evaluate_bench(contracts, bench_data, source_name)
-            if bench_data is not None
-            else evaluate_bench(contracts, {}, "<no bench data>")
-        )
-        raw.extend(bench_findings(verdicts, contracts, budget))
-        budget_state = {
-            "path": budget,
-            "bench": source_name or None,
-            "contracts": verdicts,
-        }
+        found, verdicts = check_static(ctx, contracts, budget)
+        raw.extend(found)
+        budget_state = {"path": budget, "contracts": verdicts}
 
     active: list[Finding] = []
     suppressed: list[tuple[Finding, Ignore]] = []
@@ -189,7 +159,7 @@ def analyze_paths(
 
 def record_findings(report: AnalysisReport, registry: Any = None) -> None:
     """Publish per-rule finding counts to the obs registry (0 in CI;
-    bench extras and --json consumers watch the trend)."""
+    --json consumers watch the trend)."""
     from defer_tpu.obs.metrics import get_registry
 
     reg = registry if registry is not None else get_registry()
@@ -241,14 +211,9 @@ def main(argv: Sequence[str] | None = None) -> int:
     ap.add_argument(
         "--budget", default=None, metavar="BUDGETS_TOML",
         help=(
-            "enforce the perf contracts declared in this file "
-            "(static counter-touch checks always; measured bounds "
-            "against --bench or the newest BENCH_*.json in cwd)"
+            "enforce the perf contracts declared in this file: each "
+            "counter is registered and fed from its hot functions"
         ),
-    )
-    ap.add_argument(
-        "--bench", default=None, metavar="BENCH_JSON",
-        help="bench artifact for the --budget measured cross-check",
     )
     args = ap.parse_args(argv)
     if args.list_rules:
@@ -264,7 +229,6 @@ def main(argv: Sequence[str] | None = None) -> int:
             ),
             strict=args.strict,
             budget=args.budget,
-            bench=args.bench,
         )
     except (ValueError, OSError) as e:
         print(f"error: {e}", file=sys.stderr)
@@ -285,13 +249,10 @@ def main(argv: Sequence[str] | None = None) -> int:
             for rule, n in sorted(report.suppressed_by_rule.items()):
                 print(f"  {rule:24s} {n:3d}", file=sys.stderr)
         if report.budget is not None:
-            bench_src = report.budget["bench"] or "none found"
-            print(f"budget: {report.budget['path']} "
-                  f"(bench: {bench_src})", file=sys.stderr)
+            print(f"budget: {report.budget['path']}", file=sys.stderr)
             for v in report.budget["contracts"]:
-                val = "" if v["value"] is None else f" = {v['value']}"
                 print(
-                    f"  {v['contract']:28s} {v['status']}{val}",
+                    f"  {v['contract']:28s} {v['status']}",
                     file=sys.stderr,
                 )
         print(

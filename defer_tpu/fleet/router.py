@@ -117,7 +117,7 @@ class PrefixRouter:
     `policy="prefix"` is the real router; `policy="round_robin"`
     ignores the advertisements entirely (deterministic rotation over
     live replicas) and exists as the control arm every prefix-aware
-    claim is measured against (scripts/bench_fleet.py)."""
+    claim is measured against (tests/test_fleet.py)."""
 
     def __init__(
         self,

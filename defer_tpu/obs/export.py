@@ -5,7 +5,7 @@ Three ways out, all pull-based — the hot paths never format anything:
   * `prometheus_text(registry)` — text exposition format 0.0.4, the
     thing a Prometheus scrape endpoint would serve.
   * `MetricsRegistry.to_dict()` (in obs/metrics.py) — JSON-ready
-    snapshot for bench.py's JSON-line protocol.
+    snapshot, carried as `ServerStats.metrics`.
   * `PeriodicDumper` — a daemon thread that dumps one of the above to
     a logger or file every N seconds, for headless runs with no
     scraper attached.

@@ -197,7 +197,7 @@ class Histogram:
 
     def approx_quantile(self, q: float) -> float | None:
         """Bucket-interpolated quantile estimate (None when empty) —
-        good enough for a bench headline, not for SLO accounting."""
+        good enough for a headline, not for SLO accounting."""
         if not 0 <= q <= 1:
             raise ValueError(f"quantile {q} not in [0, 1]")
         with self._lock:

@@ -8,7 +8,7 @@ names and differ by the `server` label ("flat" | "paged"), so fleet
 dashboards aggregate across them for free.
 
 `ServerStats` is the one structured return-channel `serve_greedy` /
-`serve_paged` / bench.py report through. It subclasses dict so every
+`serve_paged` report through. It subclasses dict so every
 existing `stats["ticks"]` call site keeps working, and adds attribute
 access plus the registry snapshot under `stats.metrics`.
 """

@@ -2,12 +2,11 @@
 
 The reference streams batch-1 frames end to end (one image per queue
 item, reference src/test.py:52-54) — fine for CPUs, ruinous on a TPU:
-the measured single-chip gap is ~50x between batch-1 and batch-256
-ResNet50 throughput (bench.py sweep). This adapter coalesces adjacent
-queue items into one device batch under a latency SLO, and splits the
-batched output back into per-item results, so the reference's
-item-in/item-out queue contract survives while the MXU sees real
-batches.
+a batch-1 ResNet50 leaves most of the MXU idle. This adapter coalesces
+adjacent queue items into one device batch under a latency SLO, and
+splits the batched output back into per-item results, so the
+reference's item-in/item-out queue contract survives while the MXU
+sees real batches.
 
 Enable via DeferConfig(dynamic_batch_size=N, batch_wait_s=SLO):
 `DEFER.run_defer` then gathers up to N items per dispatch, waiting at
@@ -275,25 +274,6 @@ def accept_lengths(props, preds):
     # (full-accept) rows to k.
     first_bad = mismatch.argmax(axis=1)
     return np.where(mismatch.any(axis=1), first_bad, props.shape[1])
-
-
-def poisson_arrivals(n: int, rate: float, seed: int = 0) -> np.ndarray:
-    """Deterministic open-loop arrival schedule: `n` absolute arrival
-    offsets (seconds, float64, non-decreasing, starting at 0.0) drawn
-    from a Poisson process of `rate` requests/second. Open-loop means
-    arrivals do NOT wait for service — the schedule is fixed up front,
-    so a slow server accumulates backlog instead of throttling its
-    own offered load (the closed-loop artifact that hides stalls).
-    Seeded numpy, no wall clock: the same (n, rate, seed) is the same
-    trace everywhere it's replayed (scripts/bench_paged.py
-    --mixed-sweep prices prefill/decode interference against it)."""
-    if n < 1:
-        raise ValueError(f"need n >= 1 arrivals, got {n}")
-    if rate <= 0:
-        raise ValueError(f"arrival rate must be > 0, got {rate}")
-    gaps = np.random.default_rng(seed).exponential(1.0 / rate, size=n)
-    gaps[0] = 0.0  # first request arrives at t=0
-    return np.cumsum(gaps)
 
 
 def microbatch_groups(max_batch: int, num_groups: int) -> list[list[int]]:

@@ -53,7 +53,7 @@ Static-shape design (everything jits once):
     The win is observable: `defer_kv_rows_read_total` vs
     `defer_kv_rows_gathered_baseline_total` (obs/serving.py) count
     per-tick rows read vs the gathered baseline, and
-    scripts/bench_paged.py benches all modes side by side;
+    tests/test_paged_attention.py holds the ratio per mode;
   * block tables are a fixed [B, max_blocks] shape; unallocated
     entries point at the reserved TRASH block 0 (never allocated to a
     request), so out-of-budget writes land in scrap instead of another
@@ -436,7 +436,7 @@ class HostKVSpill:
 
     def flush(self) -> None:
         """Block until every offered payload has drained into the
-        store (tests / bench determinism; never on the tick path)."""
+        store (test determinism; never on the tick path)."""
         self._q.join()
 
     @property
@@ -2410,7 +2410,7 @@ class PagedDecodeServer:
         # Memoized ON THE DECODER (utils/memo.py): jit's cache is keyed
         # on the function object, so per-server closures would re-trace
         # and re-compile on every new server over the same decoder
-        # (e.g. back-to-back bench runs).
+        # (e.g. the servers one test file builds in turn).
         from defer_tpu.utils.memo import cached_step
 
         builders = {

@@ -71,8 +71,10 @@ def node_flops(
         in_features = k.shape[0]
         return 2.0 * out_elems * in_features
     if op == "mha" and "wq" in node_params:
+        # Head-count invariant: 4 QKVO projections at 2*B*S*D*D each
+        # + the two S x S contractions at 2*B*S*S*D each.
         b, s, d = out_shape[-3], out_shape[-2], out_shape[-1]
-        return attention_flops(batch=b, seq_len=s, dim=d)
+        return 2.0 * b * s * (4.0 * d * d + 2.0 * s * d)
     kernels = [
         node_params[p] for p in _CONTRACTION_PARAMS if p in node_params
     ]
@@ -115,13 +117,6 @@ def flops_by_node(
         )
         for node in graph.nodes
     }
-
-
-def graph_flops(
-    graph: Graph, params: GraphParams, input_shape: Sequence[int]
-) -> float:
-    """Total forward FLOPs for one input of `input_shape`."""
-    return sum(flops_by_node(graph, params, input_shape).values())
 
 
 def balanced_cuts(
@@ -181,39 +176,3 @@ def balanced_cuts(
         picks.append(best)
         prev = best
     return [candidates[i] for i in picks]
-
-
-def attention_flops(*, batch: int, seq_len: int, dim: int) -> float:
-    """One self-attention layer's forward FLOPs (head-count invariant):
-    4 QKVO projection matmuls at 2*B*S*D*D each + the two S x S
-    contractions (logits, weighted values) at 2*B*S*S*D each. The ONE
-    definition shared by per-node accounting (node_flops 'mha') and the
-    whole-stack formula (transformer_flops)."""
-    tokens = float(batch * seq_len)
-    return 2.0 * tokens * (4.0 * dim * dim) + 2.0 * tokens * (
-        2.0 * seq_len * dim
-    )
-
-
-def transformer_flops(
-    *,
-    num_layers: int,
-    dim: int,
-    ffn_dim: int,
-    seq_len: int,
-    batch: int,
-    vocab_size: int = 0,
-    num_experts_active: int = 1,
-) -> float:
-    """Analytic forward FLOPs for one transformer-encoder microbatch:
-    per layer 4 QKVO projections + 2 attention matmuls + 2 FFN matmuls
-    (the standard 2*(4*D^2 + 2*S*D)*S*B + 2*2*D*F*S*B accounting)."""
-    tokens = float(batch * seq_len)
-    per_layer = (
-        attention_flops(batch=batch, seq_len=seq_len, dim=dim)
-        + 2.0 * tokens * (2.0 * dim * ffn_dim) * num_experts_active
-    )
-    total = num_layers * per_layer
-    if vocab_size:
-        total += 2.0 * tokens * dim * vocab_size
-    return total

@@ -22,8 +22,8 @@ from defer_tpu.utils.logging import get_logger
 
 log = get_logger(__name__)
 
-# Env var consumed by bench.py and the api stream loop: set to a
-# directory to capture a device trace of the benchmark/stream.
+# Env var consumed by `trace()` and `WindowTrace` below: set to a
+# directory to capture a device trace of the stream.
 TRACE_ENV = "DEFER_TPU_TRACE"
 
 

@@ -2,11 +2,10 @@
 """Serving-loop demo: batch-1 clients, dynamically batched device work.
 
 The reference's driver streams one frame per queue item (reference
-src/test.py:52-54) — the natural serving shape, but worth ~2% of a TPU
-chip (bench sweep: ~255 img/s at batch 1 vs ~13,000 at batch 256 on
-v5e). This driver keeps the exact same client contract (put one item,
-get one result, in order) and lets the runtime coalesce items into
-device batches under a latency SLO:
+src/test.py:52-54) — the natural serving shape, but a batch of one
+leaves most of a TPU's MXU idle. This driver keeps the exact same
+client contract (put one item, get one result, in order) and lets the
+runtime coalesce items into device batches under a latency SLO:
 
     python examples/serving_batched.py --model resnet50 \
         --batch-size 32 --wait-ms 5 --seconds 20

@@ -1,25 +1,20 @@
 #!/bin/bash
 # The static-analysis CI gate, one command: strict lint + perf-contract
 # budgets over the serving package. Exit code is the gate verdict:
-#   0  clean (suppressions all justified; contracts pass or no-data)
-#   1  findings — a hazard landed without a reason, or a declared
-#      budget is violated by the newest BENCH_*.json (or $2)
+#   0  clean (suppressions all justified; every contract's counter is
+#      registered and fed from its hot functions)
+#   1  findings — a hazard landed without a reason, or a hot function
+#      stopped feeding a contract's counter
 #   2  usage/config error (malformed budgets.toml, bad path)
 #
-# Usage: scripts/analyze_gate.sh [OUT_JSON] [BENCH_JSON]
+# Usage: scripts/analyze_gate.sh [OUT_JSON]
 #   OUT_JSON    where to write the JSON report (default: stdout)
-#   BENCH_JSON  bench artifact for the measured half (default: the
-#               newest BENCH_*.json in the repo root)
 set -u
 cd "$(dirname "$0")/.."
 
 out="${1:-}"
-bench="${2:-}"
 
 args=(--strict --json --budget budgets.toml defer_tpu/)
-if [ -n "$bench" ]; then
-  args+=(--bench "$bench")
-fi
 
 if [ -n "$out" ]; then
   JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \

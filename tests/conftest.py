@@ -22,8 +22,7 @@ if "xla_force_host_platform_device_count" not in _flags:
 # traced programs and their results — is unchanged. Compile-heavy files
 # ran about 29% faster with this flag (test_paged + test_spec_compose
 # 102 s -> 72 s, test_train + test_api + test_checkpoint 176 s -> 125 s;
-# PR 21, 8 cores). How many tests the not-slow tier reaches before its
-# 870 s kill varies from run to run by more than that: see ROADMAP D9.
+# PR 21, 8 cores).
 if "xla_backend_optimization_level" not in _flags:
     _flags += " --xla_backend_optimization_level=0"
 os.environ["XLA_FLAGS"] = _flags.strip()
@@ -36,20 +35,6 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
-
-
-def pytest_configure(config):
-    """Under `--dist loadfile` pytest-xdist hands out the files with
-    the most tests first, so which files share a worker's process, and
-    in what order, moves with every test a PR adds. The registry of
-    `defer_tpu.obs` is one per process, and the benchmark's rehearsal
-    (`tests/perfbench_rehearsal/`) reads all of it: it has to run
-    before a file that starts a flat `DecodeServer` registers that
-    server's instruments beside the paged server's. In the order of
-    collection the rehearsal's files come first, each at the head of
-    a worker's queue."""
-    if hasattr(config.option, "loadscopereorder"):
-        config.option.loadscopereorder = False
 
 
 @pytest.fixture(scope="session")

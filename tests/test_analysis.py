@@ -158,6 +158,8 @@ def test_cli_exit_codes_and_json(capsys):
     assert main([neg, "--rules", "prng-key-reuse"]) == 0
     assert main(["--list-rules"]) == 0
     assert main([pos, "--rules", "bogus"]) == 2
+    with pytest.raises(SystemExit):  # the gate reads no bench artifact
+        main([pos, "--bench", "x.json"])
 
 
 def test_findings_metric_recorded():
@@ -181,13 +183,13 @@ BUDGET = FIXTURES / "budget"
 
 
 def test_budget_static_and_bench_pass():
-    """Healthy tree + healthy numbers: both halves green."""
+    """Healthy tree: every contract's counter is registered and fed."""
     rep = analyze_paths(
         [str(BUDGET / "hot.py")],
         budget=str(BUDGET / "budgets.toml"),
-        bench=str(BUDGET / "bench_ok.json"),
     )
     assert rep.findings == [], [f.format() for f in rep.findings]
+    assert set(rep.budget) == {"path", "contracts"}
     statuses = {
         c["contract"]: c["status"] for c in rep.budget["contracts"]
     }
@@ -198,48 +200,38 @@ def test_budget_static_and_bench_pass():
     }
 
 
-def test_budget_bench_violation_fails_cli(capsys):
-    """Acceptance check: a violated dispatches-per-token /
-    kv-rows-read bound exits non-zero with per-contract verdicts in
-    the JSON payload."""
-    rc = main([
-        str(BUDGET / "hot.py"),
-        "--budget", str(BUDGET / "budgets.toml"),
-        "--bench", str(BUDGET / "bench_bad.json"),
-        "--json",
-    ])
-    assert rc == 1
-    out = json.loads(capsys.readouterr().out)
-    assert out["counts"] == {"perf-contract": 3}
-    statuses = {
-        c["contract"]: c["status"] for c in out["budget"]["contracts"]
-    }
-    assert set(statuses.values()) == {"fail"}
-
-
 def test_budget_static_violation_needs_no_bench():
     """cold.py registers the metrics but its _tick feeds none of them:
-    every contract fails statically even with green bench numbers."""
+    every contract fails."""
     rep = analyze_paths(
         [str(BUDGET / "cold.py")],
         budget=str(BUDGET / "budgets.toml"),
-        bench=str(BUDGET / "bench_ok.json"),
     )
     assert [f.rule for f in rep.findings] == ["perf-contract"] * 3
     assert all("nothing reachable" in f.message for f in rep.findings)
+    assert {c["status"] for c in rep.budget["contracts"]} == {"fail"}
 
 
-def test_budget_missing_sections_are_no_data_not_fail():
-    """A bench round that never ran a section must not fail its
-    contract — only present-and-violated bounds do."""
-    rep = analyze_paths(
-        [str(BUDGET / "hot.py")],
-        budget=str(BUDGET / "budgets.toml"),
-        bench={"parsed": {"decode_window": {}}},
-    )
-    assert rep.findings == []
-    assert {c["status"] for c in rep.budget["contracts"]} == {"no-data"}
-    assert rep.budget["bench"] == "<in-memory bench result>"
+@pytest.mark.parametrize(
+    "stale",
+    [
+        'bench_metric = "windows.8.dispatches_per_token"',
+        'bench_section = "decode_window"',
+        "max = 0.25",
+        "min = 1.0",
+    ],
+)
+def test_budget_rejects_measured_keys(tmp_path, capsys, stale):
+    """The gate is static. A contracts file that still carries a key
+    of the measured half it once had fails loudly, and is not
+    half-read as if its bound were enforced."""
+    bad = tmp_path / "budgets.toml"
+    bad.write_text(f"{(BUDGET / 'budgets.toml').read_text()}{stale}\n")
+    key = stale.split()[0]
+    with pytest.raises(BudgetError, match=f"`{key}`.*measured half"):
+        analyze_paths([str(BUDGET / "hot.py")], budget=str(bad))
+    assert main([str(BUDGET / "hot.py"), "--budget", str(bad)]) == 2
+    assert "measured half is gone" in capsys.readouterr().err
 
 
 def test_budget_malformed_toml_rejected(tmp_path, capsys):
@@ -253,9 +245,8 @@ def test_budget_malformed_toml_rejected(tmp_path, capsys):
 
 def test_repo_budget_gate_and_suppression_ledger(capsys):
     """The shipped gate: --strict --budget over defer_tpu/ stays green
-    (static half holds; measured half is pass or no-data, never fail
-    on committed artifacts), and the JSON payload carries the per-rule
-    suppression ledger."""
+    (every contract's counter is fed from its hot functions), and the
+    JSON payload carries the per-rule suppression ledger."""
     rc = main([
         str(REPO / "defer_tpu"), "--strict", "--json",
         "--budget", str(REPO / "budgets.toml"),
@@ -269,14 +260,16 @@ def test_repo_budget_gate_and_suppression_ledger(capsys):
     verdicts = {
         c["contract"]: c["status"] for c in out["budget"]["contracts"]
     }
-    assert set(verdicts) == {
-        "dispatches_per_token_w8",
-        "kv_rows_per_shard_tp2",
-        "mixed",
-        "pp",
-        "window_drain_b_k",
-    }
-    assert all(s in ("pass", "no-data") for s in verdicts.values())
+    assert verdicts == dict.fromkeys(
+        (
+            "dispatches_per_token_w8",
+            "kv_rows_per_shard_tp2",
+            "mixed",
+            "pp",
+            "window_drain_b_k",
+        ),
+        "pass",
+    )
 
 
 # -- trace sanitizer ---------------------------------------------------

@@ -224,25 +224,3 @@ def test_deadline_budget_machinery():
     assert dl.expired()
     assert dl.remaining() <= 0
     assert dl.elapsed() >= 0.06
-
-
-def test_poisson_arrivals_deterministic_open_loop():
-    """The open-loop arrival trace the mixed-serving bench replays
-    (scripts/bench_paged.py --mixed-sweep): seeded, non-decreasing,
-    anchored at t=0, mean gap ~ 1/rate — and the same (n, rate, seed)
-    is bit-identical on every call, so a sweep's budgets all face the
-    SAME offered load."""
-    from defer_tpu.runtime.batching import poisson_arrivals
-
-    a = poisson_arrivals(500, rate=20.0, seed=3)
-    b = poisson_arrivals(500, rate=20.0, seed=3)
-    assert np.array_equal(a, b)
-    assert a[0] == 0.0
-    assert np.all(np.diff(a) >= 0)
-    gaps = np.diff(a)
-    assert 0.5 / 20.0 < gaps.mean() < 2.0 / 20.0  # ~1/rate
-    assert not np.array_equal(a, poisson_arrivals(500, 20.0, seed=4))
-    with pytest.raises(ValueError, match="rate"):
-        poisson_arrivals(5, rate=0.0)
-    with pytest.raises(ValueError, match="arrivals"):
-        poisson_arrivals(0, rate=1.0)

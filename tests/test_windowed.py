@@ -105,6 +105,39 @@ def test_paged_window_parity_matrix(llama, attention, prefix_cache, K):
     assert stats["tokens_per_dispatch"] > base["tokens_per_dispatch"]
 
 
+@pytest.mark.parametrize("attention", ["gathered", "blockwise"])
+def test_window8_dispatch_budget(llama, attention):
+    """The numbers budgets.toml's two window contracts account for, on
+    answers long enough to fill windows: at decode_window=8 at most
+    one dispatch per four tokens (this mix reads 0.07, which leaves
+    room for windows cut short; per-token dispatch reads 0.54) and at
+    least one accepted token a dispatch."""
+    dec, params = llama
+    rng = np.random.default_rng(7)
+    reqs = [
+        (
+            jnp.asarray(
+                rng.integers(1, dec.cfg.vocab_size, size=(1, 3 + 2 * i)),
+                jnp.int32,
+            ),
+            24 + 8 * i,
+        )
+        for i in range(4)
+    ]
+    total_tokens = sum(steps for _, steps in reqs)
+    with obs.counter_deltas() as d:
+        _, stats = serve_paged(
+            dec, params, reqs,
+            num_blocks=36, block_size=4, max_batch=2,
+            attention=attention, decode_window=8,
+        )
+    assert d['defer_host_dispatches_total{server="paged"}'] == (
+        stats["host_dispatches"]
+    )
+    assert stats["host_dispatches"] / total_tokens <= 0.25
+    assert stats["tokens_per_dispatch"] >= 1.0
+
+
 # -- flat server -------------------------------------------------------
 
 
