@@ -340,6 +340,18 @@ class ServingMetrics:
         self.pp_stage_occupancy: list = []
         self.pp_stage_dispatches: list = []
 
+    def step_temp_bytes(self, span_rows: int):
+        """The gauge of one rung of the paged decode step (labelled by
+        the rows its table spans, so the label set follows the
+        server's ladder: the per-stage idiom of `bind_pp`)."""
+        return self.registry.gauge(
+            "defer_paged_step_temp_bytes",
+            "Temporaries of the compiled paged decode step "
+            "(memory_analysis): under one KV pool's bytes, no second "
+            "pool exists and the step updates the pool in place",
+            {"span_rows": str(span_rows)},
+        )
+
     def bind_pp(self, num_stages: int) -> None:
         """Resolve the per-stage pipeline instruments (stage-labeled,
         so the label set depends on the server's stage count — the

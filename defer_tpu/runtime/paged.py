@@ -154,7 +154,7 @@ class _SpanSteps:
         return self.programs[tables.shape[1]](params, pk, pv, tables, *rest)
 
 
-def _pool_gather(pool_l, idx, dtype):
+def _pool_gather(pool_l, idx, dtype, layer=None):
     """Gather per-layer pool blocks at `idx` and widen to `dtype`.
     `pool_l` is [NB, Hkv, bs, Dh] — a plain fp array, or an int8
     {"q","s"} pair with [NB, Hkv] per-(block, head) scales
@@ -162,19 +162,32 @@ def _pool_gather(pool_l, idx, dtype):
     so every attend path downstream sees ordinary fp blocks and the
     attention math stays exactly the fp path's. idx may be [B] (one
     block per slot) or [B, MB] (a whole table): s broadcasts as
-    s[..., None, None] against q's trailing (bs, Dh) in either case."""
+    s[..., None, None] against q's trailing (bs, Dh) in either case.
+    With `layer` the pool is the whole [L, NB, ...] stack and the
+    blocks are read at [layer, idx] in one gather: no layer slice of
+    the pool is ever made."""
+    at = idx if layer is None else (layer, idx)
     if isinstance(pool_l, dict):
         return dequantize_symmetric(
-            pool_l["q"][idx], pool_l["s"][idx][..., None, None], dtype
+            pool_l["q"][at], pool_l["s"][at][..., None, None], dtype
         )
-    return pool_l[idx].astype(dtype)
+    return pool_l[at].astype(dtype)
 
 
-def _pool_write_rows(pool_l, dest, rowi, val):
+def _pool_write_rows(pool_l, dest, rowi, val, layer=None):
     """Scatter one fresh K/V row per batch entry into a per-layer
     pool slice: dest [N] block ids, rowi [N] rows-in-block, val
-    [N, Hkv, Dh]. For an fp pool this is exactly the historical
-    `.at[dest, :, rowi, :].set(val)` single-row scatter.
+    [N, Hkv, Dh]; with `layer`, into the whole [L, NB, ...] pool at
+    that layer, in place. For an fp pool the values and places are
+    exactly the historical `.at[dest, :, rowi, :].set(val)`'s, but
+    the head axis is INDEXED (block, head and row are all explicit,
+    the update window one head row of [Dh]): with the head axis a
+    slice between block and row the window is [Hkv, Dh], and the TPU
+    compiler re-lays the whole pool, rows ahead of heads, so that the
+    window is contiguous — a copy of the pool into that layout and
+    one back, per layer where the pool is scanned and at the
+    program's edge where it is carried (PERF.md PR 33). A window of
+    trailing elements scatters in the layout the pool is stored in.
 
     An int8 pool can't write a row in place — symmetric int8 keeps
     ONE scale per (block, head), so landing a row means re-deriving
@@ -183,17 +196,22 @@ def _pool_write_rows(pool_l, dest, rowi, val):
     previous tenant's garbage; folding them into amax would blow up
     the scale and crush the live rows' precision — in fp they hide
     behind the position mask, here they'd poison the whole block),
-    re-quantize over (bs, Dh), scatter payload + scale back.
+    re-quantize over (bs, Dh), scatter payload + scale back (whole
+    blocks: windows of trailing axes, so no re-lay either).
     Duplicate dest entries (trash block 0) race over garbage, the
     module invariant; radix-shared blocks are never a live dest, so
     no other request's scale is ever perturbed."""
+    pre = () if layer is None else (layer,)
     if not isinstance(pool_l, dict):
-        return pool_l.at[dest, :, rowi, :].set(val)
+        heads = jnp.arange(pool_l.shape[-3])
+        at = pre + (dest[:, None], heads[None, :], rowi[:, None])
+        return pool_l.at[at].set(val)
     n = dest.shape[0]
-    bs = pool_l["q"].shape[2]
+    bs = pool_l["q"].shape[-2]
+    at = pre + (dest,)
     blk = dequantize_symmetric(
-        pool_l["q"][dest],
-        pool_l["s"][dest][..., None, None],
+        pool_l["q"][at],
+        pool_l["s"][at][..., None, None],
         jnp.float32,
     )  # [N, Hkv, bs, Dh]
     blk = blk.at[jnp.arange(n), :, rowi, :].set(val.astype(jnp.float32))
@@ -201,8 +219,8 @@ def _pool_write_rows(pool_l, dest, rowi, val):
     blk = blk * live[:, None, :, None]
     q, s = quantize_symmetric(blk, axis=(-2, -1))  # s [N, Hkv]
     return {
-        "q": pool_l["q"].at[dest].set(q),
-        "s": pool_l["s"].at[dest].set(s),
+        "q": pool_l["q"].at[at].set(q),
+        "s": pool_l["s"].at[at].set(s),
     }
 
 
@@ -2474,16 +2492,21 @@ class PagedDecodeServer:
         def i32(*shape):
             return jax.ShapeDtypeStruct(shape, jnp.int32)
 
+        def temp_bytes(program) -> int:
+            return program.memory_analysis().temp_size_in_bytes
+
         def build(nb):
             with spans.span(
                 "jax.build", kind="paged_step", span_rows=nb * self.bs
-            ):
-                return jitted.lower(
+            ) as sp:
+                program = jitted.lower(
                     *fixed, i32(self.B, nb), i32(self.B),
                     i32(self.B, 1), i32(self.B),
                 ).compile()
+                sp.counts["temp_bytes"] = temp_bytes(program)
+                return program
 
-        return {
+        programs = {
             nb: cached_step(
                 self.dec,
                 step_key + (self.B, nb, treedef, tuple(leaves)),
@@ -2491,6 +2514,11 @@ class PagedDecodeServer:
             )
             for nb in self._rungs
         }
+        # What says whether the pool is updated in place: a step that
+        # holds a second pool reads a pool's bytes and more here.
+        for nb, program in programs.items():
+            self.obs.step_temp_bytes(nb * self.bs).set(temp_bytes(program))
+        return programs
 
     def _tp_axis(self):
         """The tp_axis threaded into the tick bodies: the mesh's model
@@ -2583,27 +2611,49 @@ class PagedDecodeServer:
         """The RAW (unjitted) gathered-attention step body — jitted
         standalone for the K=1 tick (_build_step) and traced inside
         the fused-window scan (_build_window) for decode_window > 1,
-        so both paths run identical math by construction."""
+        so both paths run identical math by construction. One body
+        for every stack `GptDecoder.scan_layers` scans: a homogeneous
+        dense one layer by layer (fp or int8 pool, LoRA banks, a
+        shard_map's local heads), one whose layers differ in kind
+        (`cfg.layer_kinds`: a window or none, rotary or not) or hold
+        experts period by period, each layer's window the
+        flash-decode kernel's static argument.
+
+        The pool rides in the scan's CARRY and is read and written at
+        [layer, block] in place, under the donation `_jit_tick` sets.
+        Scanned as an input and an output it is two buffers: XLA
+        slices a layer out, updates the slice and stacks it into a
+        second pool, a whole-pool copy a tick and a pool more of
+        temporaries (18 ms of a 77 ms tick at Mistral-7B's widths,
+        PERF.md PR 33). The layer's index is itself a scanned input,
+        since a dense stack's scan hands `layer` None.
+
+        Returns `(logits, stats)` first where the decoder has experts:
+        stats int32 [L, 2], per layer the assignments that fell on
+        held experts and the distinct held experts touched, over live
+        rows (an idle slot sits at position 0, which no live one
+        does)."""
         dec, bs = self.dec, self.bs
         tp = self._tp_axis()
-        if dec.cfg.layer_kinds is not None or dec.cfg.num_experts:
-            return self._step_body_kinds()
+        experts = bool(dec.cfg.num_experts)
 
         def step(params, pk, pv, tables, pos, ids, adapter_ids):
             b = ids.shape[0]
             x = dec._embed_tokens(params, ids, pos, tp)
             rows = jnp.arange(b)
+            live = (pos > 0)[:, None]
+            blk = tables[rows, pos // bs]  # [B]
+            row = pos % bs
 
-            def body(carry, layer):
-                x = carry
-                p, pk_l, pv_l = layer  # [NB, Hkv, bs, Dh]
+            def body(carry, p, l, kind, layer):
+                x, pk, pv = carry
                 # Gather this slot's pages into the contiguous view
                 # the flat block math expects: [B, Hkv, MB*bs, Dh].
                 # An int8 pool dequantizes AT the gather (scale folds
                 # into the block values), so _block sees fp blocks.
                 with jax.named_scope("kv_gather"):
-                    kc = _pool_gather(pk_l, tables, dec.compute_dtype)
-                    vc = _pool_gather(pv_l, tables, dec.compute_dtype)
+                    kc = _pool_gather(pk, tables, dec.compute_dtype, l)
+                    vc = _pool_gather(pv, tables, dec.compute_dtype, l)
                     b_, mb, hkv, _, dh = kc.shape
                     kc = kc.transpose(0, 2, 1, 3, 4).reshape(
                         b_, hkv, mb * bs, dh
@@ -2613,76 +2663,26 @@ class PagedDecodeServer:
                     )
                 out, kc, vc = dec._block(
                     p, x, kc, vc, pos, tp_axis=tp,
-                    adapter_ids=adapter_ids,
-                )
-                # Scatter ONLY the new row back to its page.
-                with jax.named_scope("kv_scatter"):
-                    blk = tables[rows, pos // bs]  # [B]
-                    row = pos % bs
-                    new_k = kc[rows, :, pos, :]  # [B, Hkv, Dh]
-                    new_v = vc[rows, :, pos, :]
-                    pk_l = _pool_write_rows(pk_l, blk, row, new_k)
-                    pv_l = _pool_write_rows(pv_l, blk, row, new_v)
-                return out, (pk_l, pv_l)
-
-            x, (pk, pv) = lax.scan(
-                body, x, (params["stack"], pk, pv)
-            )
-            logits = self._replicate_logits(dec._final_logits(params, x))
-            return logits, pk, pv
-
-        return step
-
-    def _step_body_kinds(self):
-        """The gathered step of a stack whose layers differ in kind
-        (`cfg.layer_kinds`: a window or none, rotary or not) or hold
-        experts: the same gather, `_block` and single-row scatter as
-        `_step_body`, under `GptDecoder.scan_layers` (periods, each
-        layer's window the flash-decode kernel's static argument). The
-        pool rides in the scan's carry and is read and written at
-        [layer, block] in place; its layout is `_step_body`'s. Returns
-        `(logits, stats)` first where the decoder has experts: stats
-        int32 [L, 2], per layer the assignments that fell on held
-        experts and the distinct held experts touched, over live rows
-        (an idle slot sits at position 0, which no live one does)."""
-        dec, bs = self.dec, self.bs
-        experts = bool(dec.cfg.num_experts)
-
-        def step(params, pk, pv, tables, pos, ids, adapter_ids):
-            b = ids.shape[0]
-            x = dec._embed_tokens(params, ids, pos, None)
-            rows = jnp.arange(b)
-            live = (pos > 0)[:, None]
-            blk = tables[rows, pos // bs]  # [B]
-            row = pos % bs
-
-            def body(carry, p, _, kind, l):
-                x, pk, pv = carry
-                with jax.named_scope("kv_gather"):
-                    kc = pk[l, tables]  # [B, MB, Hkv, bs, Dh]
-                    vc = pv[l, tables]
-                    b_, mb, hkv, _, dh = kc.shape
-                    kc = kc.transpose(0, 2, 1, 3, 4).reshape(
-                        b_, hkv, mb * bs, dh
-                    )
-                    vc = vc.transpose(0, 2, 1, 3, 4).reshape(
-                        b_, hkv, mb * bs, dh
-                    )
-                out, kc, vc = dec._block(
-                    p, x, kc, vc, pos,
-                    adapter_ids=adapter_ids, kind=kind, live=live, layer=l,
+                    adapter_ids=adapter_ids, kind=kind, live=live,
+                    layer=layer,
                 )
                 x, stats = out if experts else (out, None)
+                # Scatter ONLY the new row back to its page.
                 with jax.named_scope("kv_scatter"):
-                    pk = pk.at[l, blk, :, row, :].set(kc[rows, :, pos, :])
-                    pv = pv.at[l, blk, :, row, :].set(vc[rows, :, pos, :])
+                    pk = _pool_write_rows(
+                        pk, blk, row, kc[rows, :, pos, :], l
+                    )
+                    pv = _pool_write_rows(
+                        pv, blk, row, vc[rows, :, pos, :], l
+                    )
                 carry = (x, pk, pv)
                 return ((carry, stats) if experts else carry), None
 
             (x, pk, pv), _, stats = dec.scan_layers(
-                body, (x, pk, pv), params["stack"]
+                body, (x, pk, pv), params["stack"],
+                jnp.arange(_pool_arr(pk).shape[0]),
             )
-            logits = dec._final_logits(params, x)
+            logits = self._replicate_logits(dec._final_logits(params, x))
             return ((logits, stats) if experts else logits), pk, pv
 
         return step
@@ -5200,7 +5200,7 @@ class PagedDecodeServer:
             moe = None
             if self.dec.cfg.num_experts:
                 # An expert decoder's step hands its counters back
-                # beside the logits (`_step_body_kinds`).
+                # beside the logits (`_step_body`).
                 logits, moe = logits
         with spans.span("paged.tick.sample"):
             self.ticks += 1

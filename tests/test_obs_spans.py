@@ -266,6 +266,45 @@ def test_other_ticks_get_the_outer_span_and_no_phases(kind, kwargs):
     assert [r.rid for r in got["paged.finish"]] == [rid]
 
 
+def test_every_rung_says_what_its_step_keeps_beside_the_pool():
+    """`defer_paged_step_temp_bytes{span_rows=}` is set for every rung
+    when a server builds its programs, to the `temp_bytes` of the
+    rung's `jax.build` span, and under one pool's bytes: the step
+    holds no second pool. A second server of the same shapes builds
+    nothing (the programs are the decoder's) and still sets it."""
+    dec = tiny_gpt(64)
+    params = dec.init(jax.random.key(0))
+    reg = obs.get_registry()
+    # A server of its own shape, so that no test before it built these.
+    kw = dict(num_blocks=257, block_size=4, max_batch=2)
+
+    def gauges(srv):
+        return {
+            nb * srv.bs: reg.value(
+                "defer_paged_step_temp_bytes", span_rows=str(nb * srv.bs)
+            )
+            for nb in srv._rungs
+        }
+
+    srv = PagedDecodeServer(dec, params, **kw)
+    assert set(gauges(srv).values()) <= {None, 0}  # absent, or reset
+    srv._build()
+    built = {
+        r.counts["span_rows"]: r.counts["temp_bytes"]
+        for r in spans.snapshot().records
+        if r.name == "jax.build" and r.counts["kind"] == "paged_step"
+    }
+    assert sorted(built) == [16, 24, 64]
+    assert gauges(srv) == built
+    one_pool = srv.pool_k.size * srv.pool_k.dtype.itemsize
+    assert all(0 < t < one_pool for t in built.values()), (built, one_pool)
+    obs.reset()
+    again = PagedDecodeServer(dec, params, **kw)
+    again._build()
+    assert not [r for r in spans.snapshot().records if r.name == "jax.build"]
+    assert gauges(again) == built
+
+
 # -- named scopes: op metadata a device trace can be charged to a layer by --
 
 
