@@ -32,15 +32,19 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from defer_tpu.ops.gated_delta import gdn_chunked, gdn_step
 from defer_tpu.parallel.transformer_stack import (
     TransformerConfig,
     _layer_norm,
     _rms_norm,
     apply_rope,
     EXPERT_LEAVES,
+    LINEAR,
+    act_einsum,
     embed_lookup,
     held_experts_ffn,
     init_stack,
+    leaf_group,
     norm_apply,
 )
 
@@ -492,6 +496,22 @@ class GptDecoder:
     cfg: TransformerConfig
     compute_dtype: Any = jnp.bfloat16
     rolling_cache: bool = False
+    # The precision of every matrix product of a step that states none
+    # of its own ("high", "highest"; `jax.default_matmul_precision`'s
+    # names). None leaves the platform's default: on a TPU one bf16
+    # pass, which rounds float32 activations to bf16 on their way in.
+    # With compute_dtype float32 over bf16 weights this is how a model
+    # whose layers multiply a rounding (a recurrent layer's q.k nearly
+    # cancels) is served to float32's accuracy.
+    matmul_precision: str | None = None
+    # What K and V rows are stored as, in the flat cache and in the
+    # paged server's pool; None = compute_dtype. float32 activations
+    # over a bf16 cache keep a cached row at two bytes a lane.
+    cache_dtype: Any = None
+
+    @property
+    def kv_dtype(self):
+        return self.cache_dtype or self.compute_dtype
 
     def __post_init__(self):
         if self.cfg.norm_style != "pre":
@@ -538,9 +558,15 @@ class GptDecoder:
                 k_embed, (cfg.vocab_size, cfg.dim)
             )
             * 0.02,
-            "final_ln_scale": jnp.ones((cfg.dim,)),
+            "final_ln_scale": (
+                jnp.zeros if cfg.norm_offset else jnp.ones
+            )((cfg.dim,)),
             "stack": init_stack(k_stack, cfg),
         }
+        if cfg.untied_head:
+            p["lm_head"] = (
+                jax.random.normal(k_ln, (cfg.vocab_size, cfg.dim)) * 0.02
+            )
         if cfg.pos_style == "learned":
             p["pos_embedding"] = (
                 jax.random.normal(
@@ -573,20 +599,47 @@ class GptDecoder:
         # caches bound the slot count by the attention window instead
         # of max_len.
         slots = cfg.window if self.rolling_cache else cfg.max_len
-        shape = (cfg.num_layers, batch, cfg.kv_heads, slots, dh)
+        # Only the layers with keys and values have rows here.
+        shape = (cfg.layers_of("attn"), batch, cfg.kv_heads, slots, dh)
         cache = {
-            "k": jnp.zeros(shape, self.compute_dtype),
-            "v": jnp.zeros(shape, self.compute_dtype),
+            "k": jnp.zeros(shape, self.kv_dtype),
+            "v": jnp.zeros(shape, self.kv_dtype),
             "pos": jnp.zeros((), jnp.int32),
         }
-        if cfg.num_experts:
-            # The expert layer's counters ride in the cache: a step
-            # counts its first `moe_live` rows (the rest are padding)
-            # and leaves per layer [assignments on held experts,
-            # distinct held experts touched] in `moe`.
+        if cfg.has_linear:
+            # A recurrent layer's state does not grow with the
+            # sequence: the rule's S (float32) and the rows its
+            # convolution still needs.
+            cache["gdn_s"], cache["gdn_conv"] = self.init_linear_state(batch)
+        if cfg.num_experts or cfg.has_linear:
+            # A step's first `moe_live` rows are real, the rest the
+            # padding of a bucket: the expert layer's counters count
+            # the real ones, and a recurrent layer's state moves on
+            # them alone.
             cache["moe_live"] = jnp.full((), cfg.max_len, jnp.int32)
+        if cfg.num_experts:
+            # The expert layer's counters ride in the cache: per layer
+            # [assignments on held experts, distinct held experts
+            # touched].
             cache["moe"] = jnp.zeros((cfg.num_layers, 2), jnp.int32)
         return cache
+
+    def init_linear_state(self, batch: int) -> tuple:
+        """The recurrent layers' two states for `batch` sequences,
+        zeros: S [Ll, batch, Hv, dk, dv] float32 and the convolution's
+        last rows [Ll, batch, gdn_conv - 1, channels]."""
+        cfg = self.cfg
+        n = cfg.layers_of("linear")
+        return (
+            jnp.zeros(
+                (n, batch, cfg.gdn_v_heads, cfg.gdn_k_dim, cfg.gdn_v_dim),
+                jnp.float32,
+            ),
+            jnp.zeros(
+                (n, batch, cfg.gdn_conv - 1, cfg.gdn_channels),
+                self.compute_dtype,
+            ),
+        )
 
     def split_experts(self, stack: dict) -> tuple[dict, dict]:
         """(the leaves a layer scan slices, the expert leaves it must
@@ -602,49 +655,84 @@ class GptDecoder:
         (out, ys)` over the layers of `stack` and the layer-stacked
         `caches`; returns (carry, ys, stats). `carry` is the
         activations, or a tuple that holds more (the paged step's
-        pool). A homogeneous dense stack scans layer by layer with
+        pools). A homogeneous dense stack scans layer by layer with
         kind and layer None, as it always has. With `cfg.layer_kinds`
         the scan runs over PERIODS and the period's layers are
-        unrolled in its body, so that each layer's window and rotary
-        flag stay static. An expert decoder's `p` holds its expert
-        leaves whole beside the layer's own (`split_experts`) and its
-        `out` is `(carry, stats)`, stacked into stats [L, 2]; stats is
-        None for a dense one."""
+        unrolled in its body, so that each layer's kind (its window
+        and rotary flag, or "linear") stays static. An expert
+        decoder's `p` holds its expert leaves whole beside the layer's
+        own (`split_experts`) and its `out` is `(carry, stats)`,
+        stacked into stats [L, 2]; stats is None for a dense one.
+
+        A stack with recurrent layers is stacked by GROUP: the leaves
+        only an attention layer has over the attention layers, the
+        `gdn_*` ones over the recurrent layers, the rest over all
+        (`leaf_group`). Its `caches` and `ys` are dicts {"attn": ...,
+        "linear": ...}, each group's stacked over its own layers, and
+        a layer's body is handed its group's."""
         cfg = self.cfg
         if cfg.layer_kinds is None and not cfg.num_experts:
             carry, ys = lax.scan(
                 lambda c, xs: body(c, *xs, None, None), carry, (stack, caches)
             )
             return carry, ys, None
-        kinds = cfg.layer_kinds or (None,)
+        kinds = cfg.kinds
         per = len(kinds)
         n_per = cfg.num_layers // per
         sliced, whole = self.split_experts(stack)
+        by_group = cfg.has_linear
+        group_of = [LINEAR if k == LINEAR else "attn" for k in kinds]
+        # Where each layer of a period lies within each group.
+        place = {
+            g: [j for j in range(per) if g in ("all", group_of[j])]
+            for g in ("all", "attn", LINEAR)
+        }
+
+        def fold(tree, group):
+            return jax.tree.map(
+                lambda a: a.reshape(n_per, len(place[group]), *a.shape[1:]), tree
+            )
+
+        def pick(tree, group, j):
+            return jax.tree.map(lambda a: a[place[group].index(j)], tree)
 
         def period(carry, xs):
             layers, caches_p, first = xs
-            outs = []
+            ys = {g: [] for g in place}
+            stats = []
             for j, kind in enumerate(kinds):
-                p, caches_l = jax.tree.map(lambda a: a[j], (layers, caches_p))
-                out, ys = body(carry, {**p, **whole}, caches_l, kind, first + j)
+                p = {
+                    k: pick(v, leaf_group(k), j)
+                    for k, v in layers.items()
+                    if j in place[leaf_group(k)]
+                }
+                group = group_of[j] if by_group else "all"
+                caches_l = pick(
+                    caches_p[group] if by_group else caches_p, group, j
+                )
+                out, y = body(carry, {**p, **whole}, caches_l, kind, first + j)
                 carry, st = out if cfg.num_experts else (out, None)
-                outs.append((ys, st))
-            return carry, jax.tree.map(lambda *a: jnp.stack(a), *outs)
+                ys[group].append(y)
+                stats.append(st)
+            stack_up = lambda *a: jnp.stack(a)  # noqa: E731
+            return carry, (
+                {g: jax.tree.map(stack_up, *v) for g, v in ys.items() if v},
+                jax.tree.map(stack_up, *stats),
+            )
 
-        carry, outs = lax.scan(
+        carry, (ys, stats) = lax.scan(
             period, carry,
             (
-                *jax.tree.map(
-                    lambda a: a.reshape(n_per, per, *a.shape[1:]),
-                    (sliced, caches),
-                ),
+                {k: fold(v, leaf_group(k)) for k, v in sliced.items()},
+                {g: fold(c, g) for g, c in caches.items()}
+                if by_group else fold(caches, "all"),
                 jnp.arange(n_per) * per,
             ),
         )
         ys, stats = jax.tree.map(
-            lambda a: a.reshape(-1, *a.shape[2:]), outs
+            lambda a: a.reshape(-1, *a.shape[2:]), (ys, stats)
         )
-        return carry, ys, stats
+        return carry, (ys if by_group else ys["all"]), stats
 
     # -- one step (prefill or decode) -------------------------------------
 
@@ -678,7 +766,12 @@ class GptDecoder:
             parallel/lora.py::stack_adapters) are gathered by the
             slot's adapter id, so one weight read serves every tenant
             and only the two skinny per-row einsums differ."""
-            y = h @ W(name)
+            if getattr(p[name], "dtype", None) == jnp.bfloat16 != dt:
+                # float32 activations over a bf16 matrix: one pass of
+                # three bf16 pieces (`act_einsum`).
+                y = act_einsum("...d,df->...f", h, p[name])
+            else:
+                y = h @ W(name)
             a = p.get(f"{name}:a")
             if a is not None and adapter_ids is not None:
                 a_sel = a[adapter_ids].astype(dt)  # [B, in, r]
@@ -690,14 +783,20 @@ class GptDecoder:
         return bias, proj
 
     @jax.named_scope("attn_qkv")
-    def _attn_qkv(self, p: dict, x, pos, adapter_ids=None, kind=None):
+    def _attn_qkv(
+        self, p: dict, x, pos, adapter_ids=None, kind=None, with_gate=False
+    ):
         """ln1 + q/k/v projections (+rope at the step's absolute
         positions) + head split: everything a block does BEFORE the
         cache layout matters. Returns (q [B,Hq,T,Dh], k, v
         [B,Hkv,T,Dh]). Shared verbatim by `_block` and the paged
         block-native steps so their new K/V rows are bit-identical.
         `kind` is the layer's entry of `cfg.layer_kinds` (a layer that
-        is not rotary gets no positions at all)."""
+        is not rotary gets no positions at all). With `cfg.qk_norm` q
+        and k are RMS-normed per head before the rotation, with
+        `cfg.rotary_dim` only a head's first lanes rotate, and with
+        `cfg.attn_gate` wq holds per head [q | gate]: `with_gate` then
+        adds the gate [B, T, Hq*Dh] as a fourth result."""
         cfg = self.cfg
         dt = x.dtype
         dh = cfg.dh
@@ -707,26 +806,46 @@ class GptDecoder:
         qf = bias(proj(h, "wq"), "bq")
         kf = bias(proj(h, "wk"), "bk")
         vf = bias(proj(h, "wv"), "bv")
+        gate = None
+        if cfg.attn_gate:
+            b, t, _ = qf.shape
+            qf, gate = (
+                a.reshape(b, t, -1)
+                for a in jnp.split(qf.reshape(b, t, -1, 2 * dh), 2, axis=-1)
+            )
+        if cfg.qk_norm:
+
+            def head_norm(a, scale):
+                heads = a.reshape(*a.shape[:2], -1, dh)
+                return _rms_norm(
+                    heads, scale, cfg.layer_norm_eps, cfg.norm_offset
+                ).reshape(a.shape)
+
+            qf = head_norm(qf, p["q_norm_scale"])
+            kf = head_norm(kf, p["k_norm_scale"])
         if cfg.kind_of(kind)[1]:
             steps_r = jnp.arange(qf.shape[1])
             positions = (
                 pos[:, None] + steps_r[None] if per_slot else pos + steps_r
             )
             qf = apply_rope(
-                qf, dh, positions, cfg.rope_theta, cfg.rope_pairing
+                qf, dh, positions, cfg.rope_theta, cfg.rope_pairing,
+                cfg.rotary_dim,
             )
             kf = apply_rope(
-                kf, dh, positions, cfg.rope_theta, cfg.rope_pairing
+                kf, dh, positions, cfg.rope_theta, cfg.rope_pairing,
+                cfg.rotary_dim,
             )
-        return (
+        heads = (
             self._split_heads(qf),
             self._split_heads(kf),
             self._split_heads(vf),
         )
+        return heads + (gate,) if with_gate else heads
 
     def _attn_out(
         self, p: dict, x, attn, tp_axis=None, adapter_ids=None, live=None,
-        layer=None,
+        layer=None, gate=None, wo="wo",
     ):
         """Everything a block does AFTER attention: wo projection
         (+psum under tp), residual, ln2, FFN. `attn` is the merged
@@ -736,11 +855,19 @@ class GptDecoder:
         branches to it. An expert decoder returns `(out, stats)`, the
         expert layer's counters over the `live` rows
         (`held_experts_ffn`, which is handed `layer` where the expert
-        leaves of `p` are still layer-stacked: `split_experts`)."""
+        leaves of `p` are still layer-stacked: `split_experts`). `gate`
+        (`cfg.attn_gate`) scales the attention output by its sigmoid
+        before the projection, and `wo` names the projection's leaf (a
+        recurrent layer's is `gdn_out`)."""
         cfg = self.cfg
         bias, proj = self._proj_fns(p, x.dtype, adapter_ids)
+        if gate is not None:
+            with jax.named_scope("attn_gate"):
+                attn = attn * jax.nn.sigmoid(
+                    gate.astype(jnp.float32)
+                ).astype(attn.dtype)
         with jax.named_scope("attn_out"):
-            attn = proj(attn, "wo")
+            attn = proj(attn, wo)
             if tp_axis is not None:
                 attn = lax.psum(attn, tp_axis)
             attn = bias(attn, "bo")
@@ -803,12 +930,120 @@ class GptDecoder:
         dequantizes at its gather and requantizes the returned new
         rows at its scatter, so this read path — and the new_k/new_v
         it hands back — is storage-dtype-agnostic by construction."""
-        q, k, v = self._attn_qkv(p, x, pos, adapter_ids, kind)
+        q, k, v, gate = self._attn_qkv(
+            p, x, pos, adapter_ids, kind, with_gate=True
+        )
         attn, k_cache, v_cache = self._attn_core(
             q, k, v, k_cache, v_cache, pos, x.dtype, kind
         )
-        out = self._attn_out(p, x, attn, tp_axis, adapter_ids, live, layer)
+        out = self._attn_out(
+            p, x, attn, tp_axis, adapter_ids, live, layer, gate
+        )
         return out, k_cache, v_cache
+
+    def _linear_block(
+        self, p: dict, x, s_pool, c_pool, at, n_live=None, live=None,
+        layer=None,
+    ):
+        """One recurrent (Gated DeltaNet) block on [B, T, D]: the
+        mixer in the attention's place, then `_attn_out`'s residual,
+        second norm and FFN. Its two states are read and written at
+        index `at` of their POOLS, s_pool [n, B, Hv, dk, dv] float32
+        and c_pool [n, B, gdn_conv - 1, channels]: the paged step hands
+        its pools of every recurrent layer and slot and the layer's
+        index among them, so that they are updated where they lie; the
+        flat step one layer's with n = 1. Rows from `n_live` on (None =
+        none) are a bucket's padding and leave both states as the last
+        real row left them. Returns (out, s_pool, c_pool), `out` as
+        `_attn_out`'s."""
+        h = norm_apply(self.cfg, x, p, "ln1")
+        mix, s_pool, c_pool = self._gdn_mixer(p, h, s_pool, c_pool, at, n_live)
+        out = self._attn_out(
+            p, x, mix, live=live, layer=layer, wo="gdn_out"
+        )
+        return out, s_pool, c_pool
+
+    def _gdn_mixer(self, p: dict, h, s_pool, c_pool, at, n_live=None):
+        """The Gated DeltaNet mixer on the normed [B, T, D] (the
+        equations and the three forms of the rule: ops/gated_delta.py).
+        One projection holds per key head [q | k | v | z], one [b | a];
+        u = [q, k, v] over all heads goes through a causal depthwise
+        convolution and SiLU; the rule runs in float32 on L2-normed q
+        (scaled by dk^-0.5) and k, with beta = sigmoid(b) and g =
+        -exp(A_log) softplus(a + dt_bias); the output is RMS-normed
+        per head and gated by silu(z). T = 1 is the decode step (one
+        read and one write of each state), T > 1 the chunked rule.
+        Returns (mix [B, T, Hv*dv] before `gdn_out`, s_pool, c_pool)."""
+        cfg = self.cfg
+        dt = h.dtype
+        f32 = jnp.float32
+        hk, hv, dk, dv = (
+            cfg.gdn_k_heads, cfg.gdn_v_heads, cfg.gdn_k_dim, cfg.gdn_v_dim
+        )
+        r = hv // hk
+        taps = cfg.gdn_conv
+        b, t, _ = h.shape
+        _, proj = self._proj_fns(p, dt)
+        with jax.named_scope("gdn_proj"):
+            qkvz = proj(h, "gdn_qkvz").reshape(b, t, hk, 2 * dk + 2 * r * dv)
+            q, k, v, z = jnp.split(qkvz, (dk, 2 * dk, 2 * dk + r * dv), axis=-1)
+            u = jnp.concatenate(
+                [a.reshape(b, t, -1) for a in (q, k, v)], axis=-1
+            )
+            ba = proj(h, "gdn_ba").reshape(b, t, hk, 2 * r).astype(f32)
+            beta = jax.nn.sigmoid(ba[..., :r].reshape(b, t, hv))
+            g = -jnp.exp(p["gdn_A_log"].astype(f32)) * jax.nn.softplus(
+                ba[..., r:].reshape(b, t, hv) + p["gdn_dt_bias"].astype(f32)
+            )
+            if n_live is not None:
+                real = (jnp.arange(t) < n_live)[None, :, None]
+                beta, g = jnp.where(real, beta, 0.0), jnp.where(real, g, 0.0)
+        with jax.named_scope("gdn_conv"):
+            before = lax.dynamic_index_in_dim(c_pool, at, 0, keepdims=False)
+            rows = jnp.concatenate([before.astype(dt), u], axis=1)
+            w = p["gdn_conv"].astype(f32)
+            y = jax.nn.silu(
+                sum(rows[:, i : i + t].astype(f32) * w[i] for i in range(taps))
+            )
+            # The rows the next step's convolution still needs: the
+            # last `taps - 1` real ones.
+            c_pool = lax.dynamic_update_index_in_dim(
+                c_pool,
+                lax.dynamic_slice_in_dim(
+                    rows, t if n_live is None else n_live, taps - 1, axis=1
+                ).astype(c_pool.dtype),
+                at, 0,
+            )
+        with jax.named_scope("gdn_rule"):
+            q, k, v = jnp.split(y, (hk * dk, 2 * hk * dk), axis=-1)
+
+            def unit(a):
+                a = a.reshape(b, t, hk, dk)
+                return a * lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+
+            q, k = unit(q) * dk**-0.5, unit(k)
+            v = v.reshape(b, t, hv, dv)
+            if t == 1:
+                o, s_pool = gdn_step(
+                    s_pool, at, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                    beta[:, 0], _flash_decode_mode(),
+                )
+                o = o[:, None]
+            else:
+                o, s = gdn_chunked(
+                    jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2),
+                    v, g, beta,
+                    lax.dynamic_index_in_dim(s_pool, at, 0, keepdims=False),
+                )
+                s_pool = lax.dynamic_update_index_in_dim(s_pool, s, at, 0)
+        with jax.named_scope("gdn_out"):
+            o = o * lax.rsqrt(
+                jnp.mean(o * o, -1, keepdims=True) + cfg.layer_norm_eps
+            )
+            o = o * p["gdn_norm_scale"].astype(f32) * jax.nn.silu(
+                z.reshape(b, t, hv, dv).astype(f32)
+            )
+        return o.astype(dt).reshape(b, t, hv * dv), s_pool, c_pool
 
     @jax.named_scope("attn_core")
     def _attn_core(self, q, k, v, k_cache, v_cache, pos, dt, kind=None):
@@ -892,7 +1127,9 @@ class GptDecoder:
                     k_cache = k_cache.at[:, :, slots, :].set(k)
                     v_cache = v_cache.at[:, :, slots, :].set(v)
         else:
-            # Write the T new K/V rows at the cache head.
+            # Write the T new K/V rows at the cache head (in the
+            # cache's own dtype, where that is not the step's).
+            k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
             if per_slot:
                 upd = jax.vmap(
                     lambda c, new, pb: lax.dynamic_update_slice(
@@ -1032,6 +1269,7 @@ class GptDecoder:
         sharding around the embedding/tied head, and a shard_map
         wrapper around this."""
 
+        @self._with_precision
         def step(params, cache, ids):
             t = ids.shape[1]
             pos = cache["pos"]
@@ -1040,33 +1278,59 @@ class GptDecoder:
             adapter_ids = cache.get("adapter")
             x = self._embed_tokens(params, ids, pos, tp_axis)
 
-            live = None
+            live = n_live = None
             if "moe_live" in cache:
-                live = jnp.broadcast_to(
-                    jnp.arange(t) < cache["moe_live"], ids.shape
-                )
+                n_live = jnp.minimum(cache["moe_live"], t)
+                live = jnp.broadcast_to(jnp.arange(t) < n_live, ids.shape)
 
-            def body(x, p, kv, kind, layer):
+            def body(x, p, caches_l, kind, layer):
+                if kind == LINEAR:
+                    # One layer's states as a pool of one.
+                    s, c = (a[None] for a in caches_l)
+                    out, s, c = self._linear_block(
+                        p, x, s, c, 0, n_live=n_live, live=live, layer=layer
+                    )
+                    return out, (s[0], c[0])
                 out, kc, vc = self._block(
-                    p, x, *kv, pos,
+                    p, x, *caches_l, pos,
                     tp_axis=tp_axis, adapter_ids=adapter_ids,
                     kind=kind, live=live, layer=layer,
                 )
                 return out, (kc, vc)
 
-            x, (new_k, new_v), stats = self.scan_layers(
-                body, x, params["stack"], (cache["k"], cache["v"])
-            )
+            kv = (cache["k"], cache["v"])
+            caches = kv
+            if self.cfg.has_linear:
+                caches = {
+                    "attn": kv, LINEAR: (cache["gdn_s"], cache["gdn_conv"])
+                }
+            x, ys, stats = self.scan_layers(body, x, params["stack"], caches)
             logits = self._final_logits(params, x)
-            new_cache = {"k": new_k, "v": new_v, "pos": pos + t}
+            new_cache = {"pos": pos + t}
+            if self.cfg.has_linear:
+                new_cache["gdn_s"], new_cache["gdn_conv"] = ys[LINEAR]
+                ys = ys["attn"]
+            new_cache["k"], new_cache["v"] = ys
             if adapter_ids is not None:
                 new_cache["adapter"] = adapter_ids
-            if stats is not None:
+            if "moe_live" in cache:
                 new_cache["moe_live"] = cache["moe_live"]
+            if stats is not None:
                 new_cache["moe"] = stats
             return logits, new_cache
 
         return step
+
+    def _with_precision(self, step):
+        """`step` traced under `matmul_precision` (itself where None)."""
+        if self.matmul_precision is None:
+            return step
+
+        def precise(*args):
+            with jax.default_matmul_precision(self.matmul_precision):
+                return step(*args)
+
+        return precise
 
     @jax.named_scope("embed")
     def _embed_tokens(self, params, ids, pos, tp_axis=None):
@@ -1105,7 +1369,10 @@ class GptDecoder:
         cfg = self.cfg
         xf = x.astype(jnp.float32)
         if cfg.norm_type == "rms":
-            xn = _rms_norm(xf, params["final_ln_scale"], cfg.layer_norm_eps)
+            xn = _rms_norm(
+                xf, params["final_ln_scale"], cfg.layer_norm_eps,
+                cfg.norm_offset,
+            )
         else:
             xn = _layer_norm(
                 xf,
@@ -1114,6 +1381,12 @@ class GptDecoder:
                 cfg.layer_norm_eps,
             )
         head = params.get("lm_head", params["token_embedding"])
+        if self.matmul_precision is not None and getattr(
+            head, "dtype", None
+        ) == jnp.bfloat16:
+            # The step asks for float32's accuracy: three bf16 pieces
+            # of the norm against the bf16 head, in one pass.
+            return act_einsum("...d,vd->...v", xn, head)
         head = dequantize_leaf(head, jnp.float32)
         return xn @ head.T
 
@@ -1128,6 +1401,12 @@ class GptDecoder:
         leaf, so one tree_map covers float and quantized trees
         alike."""
         L = self.cfg.num_layers
+        if self.cfg.has_linear:
+            raise ValueError(
+                "stage_params slices every stack leaf by layer: a stack "
+                "with recurrent layers (cfg.layer_kinds 'linear') stacks "
+                "its leaves by kind and is not cut into stages"
+            )
         if not (0 <= first < last <= L):
             raise ValueError(
                 f"stage layer range [{first}, {last}) out of bounds "
@@ -1253,11 +1532,18 @@ class GptDecoder:
                     ],
                     axis=1,
                 )
+            if piece.shape[1] > real and "moe_live" in cache:
+                # The padded rows count for nothing and move no
+                # recurrent state.
+                every = cache["moe_live"]
+                cache = {**cache, "moe_live": jnp.asarray(real, jnp.int32)}
             logits, cache = step(params, cache, piece)
             last = logits[:, real - 1, :]
             if piece.shape[1] > real:
                 # Rewind the write head past the padded rows.
                 cache = {**cache, "pos": cache["pos"] - (chunk - real)}
+                if "moe_live" in cache:
+                    cache["moe_live"] = every
         return last, cache
 
     def generate(

@@ -133,6 +133,28 @@ class ServingMetrics:
             "payloads plus int8 block scales when kv_dtype='int8')",
             labels,
         )
+        # The recurrent layers' state pools (runtime/paged.py
+        # `pool_state`: per slot and layer of cfg.layer_kinds "linear"
+        # one state of fixed size, indexed by slot). Bytes of the
+        # pools as allocated, 0 for a stack with no such layer; over
+        # the server's max_batch and times the live slots it is what
+        # the live requests hold, the K/V pool's blocks-used beside it.
+        self.linear_state_pool_bytes = reg.gauge(
+            "defer_linear_state_pool_bytes",
+            "Total bytes of the recurrent layers' state pools as "
+            "allocated (float32 rule state plus convolution rows, all "
+            "slots)", labels,
+        )
+        self.linear_state_slots_live = reg.gauge(
+            "defer_linear_state_slots_live",
+            "Slots whose recurrent state a request holds", labels,
+        )
+        self.linear_prefill_chunks = reg.counter(
+            "defer_linear_prefill_chunks_total",
+            "Chunks of the chunked delta rule computed by admission "
+            "prefills, summed over recurrent layers (a padded bucket's "
+            "rows over the chunk length, per layer)", labels,
+        )
         self.prefix_spilled = reg.counter(
             "defer_prefix_spilled_total",
             "Evicted prefix blocks drained into the host-RAM spill "

@@ -16,7 +16,9 @@ of each of q, k, v, so attention is purely local and only the out/ffn
 row-parallel matmuls need a psum.
 
 All parameters are plain pytrees of arrays with a leading [L] layer
-axis; `stack_specs` gives the matching PartitionSpecs.
+axis; `stack_specs` gives the matching PartitionSpecs. The serving
+decoder's hybrid stacks (`layer_kinds` with "linear" entries) stack a
+leaf over the layers of the kind that has it (`leaf_group`).
 """
 
 from __future__ import annotations
@@ -30,6 +32,10 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from defer_tpu.ops.attention import multi_head_attention
+
+
+#: The entry of `TransformerConfig.layer_kinds` of a recurrent layer.
+LINEAR = "linear"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,6 +122,35 @@ class TransformerConfig:
     expert_dim: int | None = None  # one expert's width; None = ffn_dim
     num_shared_experts: int = 0
     shared_combine: str = "mean"  # "mean" | "sum" of the shared experts
+    # Each shared expert scaled by sigmoid(x @ sw_gate) before it is
+    # added (leaf `sw_gate` [D, num_shared_experts]).
+    shared_gate: bool = False
+    # -- a hybrid decoder: gated attention layers among recurrent
+    #    (Gated DeltaNet) ones. An entry of `layer_kinds` may be the
+    #    string "linear": that layer has no keys and values but a
+    #    state of fixed size (ops/gated_delta.py), `gdn_v_heads` x
+    #    [gdn_k_dim, gdn_v_dim] float32 and the last `gdn_conv - 1`
+    #    rows before its causal depthwise convolution. -----------------
+    gdn_k_heads: int = 0
+    gdn_v_heads: int = 0
+    gdn_k_dim: int = 0
+    gdn_v_dim: int = 0
+    gdn_conv: int = 4
+    # RMS norms scale by (1 + w), w starting at 0 (the block's two, the
+    # final one and the q/k norms; the recurrent layer's gated norm
+    # keeps a plain w).
+    norm_offset: bool = False
+    # q and k RMS-normed per head before the rotation (leaves
+    # `q_norm_scale`, `k_norm_scale` [head_dim]).
+    qk_norm: bool = False
+    # wq is [dim, num_heads * 2 * head_dim], read per head as
+    # [q | gate]: the attention output is scaled by sigmoid(gate).
+    attn_gate: bool = False
+    # Lanes of a head that rotate (the first `rotary_dim`, paired
+    # within themselves); None = all of them.
+    rotary_dim: int | None = None
+    # An output head of its own (`lm_head` [V, D]) beside the embedding.
+    untied_head: bool = False
 
     @property
     def kv_heads(self) -> int:
@@ -132,11 +167,37 @@ class TransformerConfig:
         return self.experts_held or (0, self.num_experts)
 
     def kind_of(self, layer_kind) -> tuple:
-        """(window, rotary) of a layer: its entry of `layer_kinds`, or
-        the stack's one `window` and `pos_style` where it has none."""
+        """(window, rotary) of an attention layer: its entry of
+        `layer_kinds`, or the stack's one `window` and `pos_style`
+        where it has none."""
         if layer_kind is None:
             return self.window, self.pos_style == "rope"
         return layer_kind
+
+    @property
+    def kinds(self) -> tuple:
+        """One period of layer kinds; (None,) for a homogeneous stack."""
+        return self.layer_kinds or (None,)
+
+    @property
+    def has_linear(self) -> bool:
+        return LINEAR in self.kinds
+
+    def layers_of(self, group: str) -> int:
+        """Layers of the stack in `group`: "linear" (the recurrent
+        ones), "attn" (those with keys and values) or "all"."""
+        kinds = self.kinds
+        n = {
+            "all": len(kinds),
+            "linear": sum(k == LINEAR for k in kinds),
+            "attn": sum(k != LINEAR for k in kinds),
+        }[group]
+        return self.num_layers // len(kinds) * n
+
+    @property
+    def gdn_channels(self) -> int:
+        """Channels of a recurrent layer's convolution: q, k and v."""
+        return 2 * self.gdn_k_heads * self.gdn_k_dim + self.gdn_v_heads * self.gdn_v_dim
 
     @property
     def lora_scale(self) -> float:
@@ -185,7 +246,10 @@ class TransformerConfig:
                     f"num_layers={self.num_layers} must be a whole "
                     "number of periods"
                 )
-            for w, rotary in self.layer_kinds:
+            for kind in self.layer_kinds:
+                if kind == LINEAR:
+                    continue
+                w, rotary = kind
                 if w is not None and (w < 1 or not self.causal):
                     raise ValueError(
                         f"layer_kinds window {w} needs causal=True and "
@@ -195,6 +259,25 @@ class TransformerConfig:
                     raise ValueError(
                         "a rotary layer kind needs pos_style='rope'"
                     )
+        if self.has_linear:
+            sizes = (self.gdn_k_heads, self.gdn_v_heads, self.gdn_k_dim, self.gdn_v_dim)
+            if min(sizes) < 1 or self.gdn_v_heads % self.gdn_k_heads or self.gdn_conv < 2:
+                raise ValueError(
+                    f"a 'linear' layer kind needs gdn_k_heads, gdn_v_heads "
+                    f"(a multiple of it), gdn_k_dim, gdn_v_dim >= 1 and "
+                    f"gdn_conv >= 2, got {sizes} and {self.gdn_conv}"
+                )
+        if self.rotary_dim is not None and not (
+            0 < self.rotary_dim <= self.dh and self.rotary_dim % 2 == 0
+        ):
+            raise ValueError(
+                f"rotary_dim={self.rotary_dim} must be even and within "
+                f"the head size {self.dh}"
+            )
+        if self.shared_gate and not self.num_shared_experts:
+            raise ValueError("shared_gate needs num_shared_experts >= 1")
+        if self.norm_offset and self.norm_type != "rms":
+            raise ValueError("norm_offset scales an RMS norm: norm_type='rms'")
         if self.lora_rank:
             if self.lora_rank < 1:
                 raise ValueError(f"lora_rank={self.lora_rank} must be >= 1")
@@ -236,7 +319,9 @@ class TransformerConfig:
 def refuse_mechanisms(cfg: TransformerConfig, option: str) -> None:
     """Raise, naming the mechanism and the option, where a path that
     computes one homogeneous dense stack is asked to serve a model with
-    layer kinds, experts or a parallel block. The default paged path
+    layer kinds, experts, a parallel block or recurrent layers (whose
+    state is per slot: a position cannot roll it back, a block hash
+    cannot share it, a block cannot spill it). The default paged path
     (`PagedDecodeServer` at its defaults) and the flat step serve them;
     nothing else has been held to the reference, and none may run such
     a model as a silently homogeneous stack."""
@@ -246,6 +331,12 @@ def refuse_mechanisms(cfg: TransformerConfig, option: str) -> None:
             ("layer kinds (cfg.layer_kinds)", cfg.layer_kinds is not None),
             ("experts (cfg.num_experts)", bool(cfg.num_experts)),
             ("a parallel block (cfg.parallel_block)", cfg.parallel_block),
+            (
+                "recurrent layers (cfg.layer_kinds 'linear'), whose state "
+                "no position rolls back, no block hash shares and no "
+                "block spills",
+                cfg.has_linear,
+            ),
         )
         if on
     ]
@@ -294,25 +385,60 @@ def init_stack(
     decoder's, `held_experts_ffn`) are the HELD experts' matrices under
     a router of the published width, plus the shared experts'."""
     L, D, F = cfg.num_layers, cfg.dim, cfg.ffn_dim
+    # The leaves of `ATTN_LEAVES` are stacked over the attention
+    # layers alone and the `gdn_*` ones over the recurrent layers
+    # (`leaf_group`); a stack with no recurrent layer has La == L.
+    La, Ll = cfg.layers_of("attn"), cfg.layers_of("linear")
     dq = cfg.num_heads * cfg.dh
     dkv = cfg.kv_heads * cfg.dh
     ks = jax.random.split(rng, 8)
     s = D**-0.5
     norms = ("ln1",) if cfg.parallel_block else ("ln1", "ln2")
+    # Under (1 + w) a norm's scale starts at 0.
+    unit = jnp.zeros if cfg.norm_offset else jnp.ones
     p = {
-        "wq": jax.random.normal(ks[0], (L, D, dq), dtype) * s,
-        "wk": jax.random.normal(ks[1], (L, D, dkv), dtype) * s,
-        "wv": jax.random.normal(ks[2], (L, D, dkv), dtype) * s,
-        "wo": jax.random.normal(ks[3], (L, dq, D), dtype) * dq**-0.5,
+        "wq": jax.random.normal(
+            ks[0], (La, D, dq * (2 if cfg.attn_gate else 1)), dtype
+        ) * s,
+        "wk": jax.random.normal(ks[1], (La, D, dkv), dtype) * s,
+        "wv": jax.random.normal(ks[2], (La, D, dkv), dtype) * s,
+        "wo": jax.random.normal(ks[3], (La, dq, D), dtype) * dq**-0.5,
     }
-    p.update({f"{n}_scale": jnp.ones((L, D), dtype) for n in norms})
+    p.update({f"{n}_scale": unit((L, D), dtype) for n in norms})
+    if cfg.qk_norm:
+        p["q_norm_scale"] = unit((La, cfg.dh), dtype)
+        p["k_norm_scale"] = unit((La, cfg.dh), dtype)
+    if Ll:
+        hk, hv, dk, dv = cfg.gdn_k_heads, cfg.gdn_v_heads, cfg.gdn_k_dim, cfg.gdn_v_dim
+        kg = jax.random.split(jax.random.fold_in(rng, 200), 5)
+        p.update(
+            {
+                # Per key head [q dk | k dk | v r dv | z r dv], r = hv / hk.
+                "gdn_qkvz": jax.random.normal(
+                    kg[0], (Ll, D, 2 * hk * dk + 2 * hv * dv), dtype
+                ) * s,
+                # Per key head [b r | a r].
+                "gdn_ba": jax.random.normal(kg[1], (Ll, D, 2 * hv), dtype) * s,
+                # Tap i multiplies the row gdn_conv - 1 - i back.
+                "gdn_conv": jax.random.normal(
+                    kg[2], (Ll, cfg.gdn_conv, cfg.gdn_channels), dtype
+                ) * cfg.gdn_conv**-0.5,
+                "gdn_A_log": jnp.log(
+                    jax.random.uniform(kg[3], (Ll, hv), dtype, 1e-3, 16.0)
+                ),
+                "gdn_dt_bias": jnp.ones((Ll, hv), dtype),
+                "gdn_norm_scale": jnp.ones((Ll, dv), dtype),
+                "gdn_out": jax.random.normal(kg[4], (Ll, hv * dv, D), dtype)
+                * (hv * dv) ** -0.5,
+            }
+        )
     if cfg.use_bias:
         p.update(
             {
-                "bq": jnp.zeros((L, dq), dtype),
-                "bk": jnp.zeros((L, dkv), dtype),
-                "bv": jnp.zeros((L, dkv), dtype),
-                "bo": jnp.zeros((L, D), dtype),
+                "bq": jnp.zeros((La, dq), dtype),
+                "bk": jnp.zeros((La, dkv), dtype),
+                "bv": jnp.zeros((La, dkv), dtype),
+                "bo": jnp.zeros((La, D), dtype),
             }
         )
     if cfg.norm_type == "layer" and cfg.norm_bias:
@@ -334,6 +460,10 @@ def init_stack(
         p["w1"], p["w3"], p["w2"] = experts(ks[4], hi - lo)
         if cfg.num_shared_experts:
             p["sw1"], p["sw3"], p["sw2"] = experts(ksh, cfg.num_shared_experts)
+        if cfg.shared_gate:
+            p["sw_gate"] = jax.random.normal(
+                jax.random.fold_in(ksh, 1), (L, D, cfg.num_shared_experts), dtype
+            ) * s
     elif cfg.num_experts:
         E = cfg.num_experts
         p.update(
@@ -702,17 +832,64 @@ def moe_ffn_a2a(
     return out.astype(dt).reshape(b, s, d)
 
 
+def act_einsum(spec: str, h: jax.Array, w: jax.Array) -> jax.Array:
+    """`jnp.einsum(spec, h, w)` of activations `h` with a stored weight
+    `w`, in h's dtype. Where h is float32 and w bf16 (float32
+    activations served over bf16 weights) the product is exact to
+    float32 in ONE bf16 pass of the matrix unit: h is split into three
+    bf16 pieces (h = hi + mid + lo to 24 bits), the pieces are stacked
+    as more rows against the one weight, and their three results
+    summed. The general "highest" precision splits the weight too (six
+    passes), of which a bf16 weight has nothing to give; a decode
+    step's few rows ride one pass for nothing, a prefill's pay three.
+    `spec` names h's operand first and may not use the letter P."""
+    if h.dtype != jnp.float32 or w.dtype != jnp.bfloat16:
+        return jnp.einsum(spec, h, w.astype(h.dtype))
+    # Rounded by `reduce_precision`, not by a cast to bf16 and back: XLA
+    # may keep a pair of converts in the wider type ("excess
+    # precision"), and the pieces below the first would then be zero
+    # (on the chip the served logits left the reference by 0.14; PR 34).
+    def piece(a):
+        return lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7)
+
+    hi = piece(h)
+    mid = piece(h - hi)
+    lo = piece(h - hi - mid)
+    ins, out = spec.split("->")
+    parts = jnp.einsum(
+        f"P{ins}->P{out}", jnp.stack([hi, mid, lo]).astype(jnp.bfloat16), w,
+        preferred_element_type=jnp.float32, precision=lax.Precision.DEFAULT,
+    )
+    return parts[0] + parts[1] + parts[2]
+
+
 # Rows of one tile of an expert's tokens in `held_experts_ffn`: an
 # expert's weights (100 MB at 4096 x 4096 SwiGLU in bf16) are read once
 # a tile, so a tile must hold enough rows to pay for the read, and a
 # step of fewer tokens than this is one tile an expert.
 _EXPERT_TILE = 256
+_DECODE_TILE = 32
 
 
 #: The leaves of `held_experts_ffn` that a layer scan must NOT slice:
 #: they stay layer-stacked and are indexed [layer, expert] where a
 #: product reads them (see its docstring).
 EXPERT_LEAVES = ("w1", "w3", "w2", "sw1", "sw3", "sw2")
+
+#: The leaves only a layer with keys and values has.
+ATTN_LEAVES = (
+    "wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo",
+    "q_norm_scale", "k_norm_scale",
+)
+
+
+def leaf_group(name: str) -> str:
+    """Which layers a stack leaf is stacked over: "attn" (those with
+    keys and values), "linear" (the recurrent ones) or "all"; see
+    `TransformerConfig.layers_of`."""
+    if name.startswith("gdn_"):
+        return "linear"
+    return "attn" if name.split(":")[0] in ATTN_LEAVES else "all"
 
 
 def held_experts_ffn(
@@ -723,16 +900,19 @@ def held_experts_ffn(
     router scores): it routes over ALL published experts, normalises
     the chosen `moe_top_k` weights over the chosen whether held or not,
     and returns the HELD experts' part of the routed sum plus the
-    shared experts' mean (or sum). What the absent experts would add
+    shared experts' mean (or sum; with `cfg.shared_gate` each scaled
+    by sigmoid(x @ sw_gate) first). What the absent experts would add
     is left out: on one chip the layer runs without its exchange.
 
-    No capacity and no dropped token. Each held expert's tokens are
-    brought to the front of an order (a stable argsort of its column of
-    the assignment matrix) and computed in tiles of `_EXPERT_TILE`
-    rows, as many tiles as its count needs (a traced trip count: an
-    expert nobody chose computes nothing), so the work follows the
-    assignments that fell here and every shape is static. One form for
-    decode (a tile is then the whole batch) and prefill.
+    No capacity and no dropped token. The (token, expert) assignments
+    are sorted by expert ONCE (stable, so an expert's tokens keep
+    their order; assignments on absent experts go last), and ONE loop
+    runs over tiles of `_EXPERT_TILE` assignments of one expert each,
+    in expert order, as many tiles as each expert's count needs: the
+    trip count is traced (an expert nobody chose computes nothing and
+    its weights are not read), every shape is static, and the program
+    is the same size whatever the number of experts held. One form
+    for decode (a tile is then the whole batch) and prefill.
 
     With `layer` given, the `EXPERT_LEAVES` of `p` are still
     layer-stacked ([L, E, ...]) and `layer` (an int or a traced scalar)
@@ -749,9 +929,13 @@ def held_experts_ffn(
     dt = x.dtype
     b, t, d = x.shape
     n = b * t
+    k = cfg.moe_top_k
     xf = x.reshape(n, d)
     lo, hi = cfg.held
     eh = hi - lo
+    # A decode step's rows (t == 1) are a few an expert: tiles of
+    # `_DECODE_TILE` keep the products under the weights' read.
+    tile = min(n, _DECODE_TILE if t == 1 else _EXPERT_TILE)
     with jax.named_scope("moe_router"):
         logits = jnp.dot(
             xf.astype(jnp.float32),
@@ -763,59 +947,70 @@ def held_experts_ffn(
             if cfg.moe_gate == "sigmoid"
             else jax.nn.softmax(logits, axis=-1)
         )
-        w, idx = lax.top_k(scores, cfg.moe_top_k)  # (N, k)
-        if cfg.moe_top_k > 1:
+        w, idx = lax.top_k(scores, k)  # (N, k)
+        if k > 1:
             w = w / jnp.sum(w, axis=-1, keepdims=True)
-        # gate[n, e]: token n's weight on held expert lo + e, 0 where
-        # it did not choose it (a chosen weight is never 0: sigmoid
-        # and softmax are positive).
-        sel = jax.nn.one_hot(idx - lo, eh, dtype=jnp.float32)  # (N, k, eh)
-        gate = (sel * w[..., None]).sum(axis=1)  # (N, eh)
-        chosen = gate > 0
-        counted = chosen if live is None else chosen & live.reshape(n, 1)
-        stats = jnp.stack(
-            [counted.sum(), counted.any(axis=0).sum()]
-        ).astype(jnp.int32)
+        # Assignment a = token a // k's a % k-th choice. Held experts
+        # keep their number, absent ones sort behind them all.
+        expert = (idx - lo).reshape(n * k)
+        held = (expert >= 0) & (expert < eh)
+        order = jnp.argsort(jnp.where(held, expert, eh), stable=True)
+        token = order // k
+        weight = w.reshape(n * k)[order]
+        mine = jax.nn.one_hot(expert, eh, dtype=jnp.int32)  # 0 where absent
+        count = mine.sum(axis=0)  # (eh,)
+        first = jnp.cumsum(count) - count  # an expert's place in `order`
+        tiles = -(-count // tile)
+        tiles_end = jnp.cumsum(tiles)
+        counted = mine if live is None else mine * jnp.repeat(
+            live.reshape(n), k
+        ).astype(jnp.int32)[:, None]
+        counted = counted.sum(axis=0)
+        stats = jnp.stack([counted.sum(), (counted > 0).sum()]).astype(jnp.int32)
 
-    def weight(name, e=None):
-        idx = tuple(i for i in (layer, e) if i is not None)
-        return p[name][idx].astype(dt)
+    def weight_of(name, e=None):
+        at = tuple(i for i in (layer, e) if i is not None)
+        return p[name][at]
 
-    def swiglu(rows, e):
-        h = jax.nn.silu(rows @ weight("w1", e)) * (rows @ weight("w3", e))
-        return h @ weight("w2", e)
+    def one_tile(j, out):
+        # Tile j is tile i of expert e: the first whose tiles end past j.
+        e = jnp.sum(tiles_end <= j)
+        i = j - (tiles_end[e] - tiles[e])
+        at = i * tile + jnp.arange(tile)
+        # Past the expert's count the tile runs on into whatever
+        # follows in the order: computed, and weighted 0.
+        a = jnp.minimum(first[e] + at, n * k - 1)
+        rows = token[a]
+        wt = jnp.where(at < count[e], weight[a], 0.0)
+        h = xf[rows]
+        h = jax.nn.silu(act_einsum("nd,df->nf", h, weight_of("w1", e))) * (
+            act_einsum("nd,df->nf", h, weight_of("w3", e))
+        )
+        y = act_einsum("nf,fd->nd", h, weight_of("w2", e))
+        return out.at[rows].add(y.astype(jnp.float32) * wt[:, None])
 
-    tile = min(n, _EXPERT_TILE)
     with jax.named_scope("moe_experts"):
-        out = jnp.zeros((n, d), jnp.float32)
-        for e in range(eh):
-            mine = chosen[:, e]
-            count = mine.sum()
-            # This expert's tokens first, in their order; past `count`
-            # the order holds tokens that did not choose it, and the
-            # tile's last rows are masked by `count`.
-            order = jnp.argsort(~mine, stable=True)
-            if n % tile:
-                order = jnp.pad(order, (0, tile - n % tile))
-
-            def one_tile(i, out, e=e, order=order, count=count):
-                rows = lax.dynamic_slice_in_dim(order, i * tile, tile)
-                y = swiglu(xf[rows], e)
-                wt = jnp.where(
-                    i * tile + jnp.arange(tile) < count, gate[rows, e], 0.0
-                )
-                return out.at[rows].add(y.astype(jnp.float32) * wt[:, None])
-
-            out = lax.fori_loop(0, -(-count // tile), one_tile, out)
+        out = lax.fori_loop(
+            0, tiles_end[-1], one_tile, jnp.zeros((n, d), jnp.float32)
+        )
     if "sw1" in p:
         with jax.named_scope("moe_shared"):
-            ys = jnp.einsum(
-                "snf,sfd->nd",
-                jax.nn.silu(jnp.einsum("nd,sdf->snf", xf, weight("sw1")))
-                * jnp.einsum("nd,sdf->snf", xf, weight("sw3")),
-                weight("sw2"),
-                preferred_element_type=jnp.float32,
-            )
+            hs = jax.nn.silu(
+                act_einsum("nd,sdf->snf", xf, weight_of("sw1"))
+            ) * act_einsum("nd,sdf->snf", xf, weight_of("sw3"))
+            if cfg.shared_gate:
+                gate = jax.nn.sigmoid(
+                    xf.astype(jnp.float32) @ p["sw_gate"].astype(jnp.float32)
+                )  # (N, S)
+                ys = act_einsum("snf,sfd->snd", hs, weight_of("sw2"))
+                ys = jnp.sum(
+                    ys.astype(jnp.float32) * gate.T[:, :, None], axis=0
+                )
+            else:
+                ys = jnp.einsum(
+                    "snf,sfd->nd", hs, weight_of("sw2").astype(dt),
+                    preferred_element_type=jnp.float32,
+                )
             if cfg.shared_combine == "mean":
                 ys = ys / cfg.num_shared_experts
             out = out + ys
@@ -863,17 +1058,21 @@ def _layer_norm(x, scale, bias, eps):
     return out.astype(x.dtype)
 
 
-def _rms_norm(x, scale, eps):
-    """Scale-only RMS normalization (llama), fp32 statistics."""
+def _rms_norm(x, scale, eps, offset: bool = False):
+    """Scale-only RMS normalization (llama), fp32 statistics; with
+    `offset` the scale is (1 + w)."""
     xf = x.astype(jnp.float32)
     out = xf * lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
-    return (out * scale.astype(jnp.float32)).astype(x.dtype)
+    scale = scale.astype(jnp.float32)
+    return (out * (1.0 + scale if offset else scale)).astype(x.dtype)
 
 
 def norm_apply(cfg: TransformerConfig, x, p: dict, which: str):
     """The config's normalization ("ln1"/"ln2" param group)."""
     if cfg.norm_type == "rms":
-        return _rms_norm(x, p[f"{which}_scale"], cfg.layer_norm_eps)
+        return _rms_norm(
+            x, p[f"{which}_scale"], cfg.layer_norm_eps, cfg.norm_offset
+        )
     return _layer_norm(
         x, p[f"{which}_scale"], p.get(f"{which}_bias"), cfg.layer_norm_eps
     )
@@ -885,6 +1084,7 @@ def apply_rope(
     positions: jax.Array,
     theta: float,
     pairing: str = "half",
+    rotary_dim: int | None = None,
 ) -> jax.Array:
     """Rotary position embedding on a flat (B, T, H*Dh) projection.
 
@@ -899,8 +1099,19 @@ def apply_rope(
     (B, T) per batch element (continuous batching, where every slot
     sits at its own depth). `pairing="interleaved"` rotates lanes
     (2i, 2i + 1) together instead (GPT-J's convention), at the same
-    frequencies."""
+    frequencies. With `rotary_dim` only a head's first `rotary_dim`
+    lanes rotate, paired and timed within themselves (frequency theta
+    ** (-2i / rotary_dim)); the rest pass."""
     b, t, d = x_flat.shape
+    if rotary_dim is not None and rotary_dim != head_dim:
+        x = x_flat.reshape(b, t, d // head_dim, head_dim)
+        turned = apply_rope(
+            x[..., :rotary_dim].reshape(b, t, -1), rotary_dim, positions,
+            theta, pairing,
+        ).reshape(b, t, -1, rotary_dim)
+        return jnp.concatenate(
+            [turned, x[..., rotary_dim:]], axis=-1
+        ).reshape(b, t, d)
     x = x_flat.reshape(b, t, d // head_dim, head_dim)
     half = head_dim // 2
     if pairing == "interleaved":

@@ -575,6 +575,13 @@ class DecodeServer:
             raise ValueError(
                 f"decode_window must be >= 1, got {decode_window}"
             )
+        if getattr(dec.cfg, "has_linear", False):
+            raise ValueError(
+                "DecodeServer keeps K and V lanes per slot and no other "
+                "state: it does not serve a model with recurrent layers "
+                "(cfg.layer_kinds 'linear'). Serve this model on "
+                "PagedDecodeServer's default path."
+            )
         self.decode_window = decode_window
         if decode_window > 1:
             raw = getattr(dec, "decode_step_fn", None)

@@ -98,7 +98,9 @@ from defer_tpu.models.quant import (
 )
 from defer_tpu.obs import spans
 from defer_tpu.obs.serving import ServerStats, ServingMetrics
+from defer_tpu.ops.gated_delta import CHUNK
 from defer_tpu.ops.pallas_attention import _MASK_VALUE
+from defer_tpu.parallel.transformer_stack import LINEAR
 from defer_tpu.runtime.batching import (
     accept_lengths,
     microbatch_groups,
@@ -881,10 +883,10 @@ class _PPLocalStage:
             self._pool_spec = PSpec(None, None, model_axis, None, None)
             pool_sh = NamedSharding(submesh, self._pool_spec)
             self.pk = jnp.zeros(
-                pool_shape, dec.compute_dtype, device=pool_sh
+                pool_shape, dec.kv_dtype, device=pool_sh
             )
             self.pv = jnp.zeros(
-                pool_shape, dec.compute_dtype, device=pool_sh
+                pool_shape, dec.kv_dtype, device=pool_sh
             )
             self._sink = NamedSharding(submesh, PSpec())
         else:
@@ -894,8 +896,8 @@ class _PPLocalStage:
             self.params = sp
             self._param_specs = None
             self._pool_spec = None
-            self.pk = jnp.zeros(pool_shape, dec.compute_dtype)
-            self.pv = jnp.zeros(pool_shape, dec.compute_dtype)
+            self.pk = jnp.zeros(pool_shape, dec.kv_dtype)
+            self.pv = jnp.zeros(pool_shape, dec.kv_dtype)
             if device is not None:
                 self.pk = jax.device_put(self.pk, device)
                 self.pv = jax.device_put(self.pv, device)
@@ -1012,6 +1014,16 @@ class PagedDecodeServer:
     for later revival, and eviction happens only under pool pressure.
     This generalizes the constructor-level `prefix_ids` (one global
     system prompt, still supported, mutually exclusive).
+
+    A stack with recurrent layers (`cfg.layer_kinds` entries
+    "linear") is served on the default path with a second kind of
+    cache beside the block pool: `pool_state`, per slot and recurrent
+    layer a state of fixed size, indexed by slot and not by block
+    table. The K/V pool's layer axis then counts only the layers that
+    have keys and values; the step carries both through its layer loop
+    and updates both in place, and admission copies the prefill's final
+    states into the slot's row (nothing clears it: the next admission
+    overwrites it).
 
     A live slot (`slots[i]`) records its request on the host: `prompt`
     as it arrived and `out`, the generated tokens as Python ints from
@@ -1576,15 +1588,19 @@ class PagedDecodeServer:
         dh = cfg.dh
         self.kv_dtype = kv_dtype
         self.num_blocks = num_blocks
+        # The pool's layer axis counts the layers that have keys and
+        # values: a recurrent layer (cfg.layer_kinds "linear") owns no
+        # rows here but a state of fixed size in `pool_state` below.
+        kv_layers = cfg.layers_of("attn")
         pool_shape = (
-            cfg.num_layers, num_blocks, cfg.kv_heads, block_size, dh,
+            kv_layers, num_blocks, cfg.kv_heads, block_size, dh,
         )
         # int8 pools are a {"q", "s"} pytree: int8 rows plus one fp32
         # scale per (layer, block, kv_head). Scales start at 1.0 so a
         # never-written block dequantizes to the zeros an fp pool
         # holds. The fp pool stays a PLAIN array — its jitted
         # programs trace byte-identical to pre-int8 builds.
-        scale_shape = (cfg.num_layers, num_blocks, cfg.kv_heads)
+        scale_shape = (kv_layers, num_blocks, cfg.kv_heads)
         if self.pp > 1:
             # Pipeline-parallel: the pool never exists monolithically
             # — each _PPLocalStage allocates its own layer slice on
@@ -1623,10 +1639,10 @@ class PagedDecodeServer:
                 }
             else:
                 self.pool_k = jnp.zeros(
-                    pool_shape, dec.compute_dtype, device=pool_sh
+                    pool_shape, dec.kv_dtype, device=pool_sh
                 )
                 self.pool_v = jnp.zeros(
-                    pool_shape, dec.compute_dtype, device=pool_sh
+                    pool_shape, dec.kv_dtype, device=pool_sh
                 )
             self.params = self._sdec.shard_params(params)
         else:
@@ -1642,8 +1658,8 @@ class PagedDecodeServer:
                     "s": jnp.ones(scale_shape, jnp.float32),
                 }
             else:
-                self.pool_k = jnp.zeros(pool_shape, dec.compute_dtype)
-                self.pool_v = jnp.zeros(pool_shape, dec.compute_dtype)
+                self.pool_k = jnp.zeros(pool_shape, dec.kv_dtype)
+                self.pool_v = jnp.zeros(pool_shape, dec.kv_dtype)
             if device is not None:
                 self.pool_k = jax.device_put(self.pool_k, device)
                 self.pool_v = jax.device_put(self.pool_v, device)
@@ -1660,6 +1676,17 @@ class PagedDecodeServer:
             leaf.nbytes
             for leaf in jax.tree.leaves((self.pool_k, self.pool_v))
         )
+        # The second kind of cache: per slot and recurrent layer the
+        # rule's state S (float32) and the rows its convolution still
+        # needs, indexed by SLOT, not by block table. Its size is
+        # fixed; admission overwrites a slot's row and finishing need
+        # not clear it. Empty where the stack has no such layer.
+        self.pool_state: tuple = ()
+        if cfg.has_linear:
+            self.pool_state = dec.init_linear_state(max_batch)
+            if device is not None:
+                self.pool_state = jax.device_put(self.pool_state, device)
+        self.state_bytes = sum(a.nbytes for a in self.pool_state)
         # Pipeline-parallel stage chain (pp_stages > 1): resolve the
         # layer cuts, build one stage per contiguous layer range, and
         # account the pool as the sum of the per-stage slices.
@@ -1794,6 +1821,7 @@ class PagedDecodeServer:
         # pre-bound attributes only (obs/serving.py).
         self.obs = ServingMetrics("paged", mesh_shape=self.mesh_label)
         self.obs.kv_pool_bytes.set(self.pool_bytes)
+        self.obs.linear_state_pool_bytes.set(self.state_bytes)
         if self.pp > 1:
             # Stage-labeled pp instruments (occupancy gauges + dispatch
             # counters per stage) bind once the stage count is known.
@@ -1835,6 +1863,7 @@ class PagedDecodeServer:
         self.constraint_dead_ends_n = 0
         self._step = None
         self._insert = None
+        self._insert_state = None
         self._insert_dyn = None
         self._import = None
         self._mt = None
@@ -2450,6 +2479,10 @@ class PagedDecodeServer:
             ("paged_insert", self.bs, skip, self.kv_dtype, self._mesh_key),
             lambda: self._build_insert(skip),
         )
+        if self.pool_state:
+            self._insert_state = cached_step(
+                self.dec, ("paged_insert_state",), self._build_insert_state
+            )
         if self.radix is not None and self._gather is None:
             self._gather = cached_step(
                 self.dec,
@@ -2485,9 +2518,10 @@ class PagedDecodeServer:
                 sharding=a.sharding
                 if getattr(a, "committed", False) else None,
             ),
-            (self.params, self.pool_k, self.pool_v),
+            (self.params, self.pool_k, self.pool_v, self.pool_state),
         )
         leaves, treedef = jax.tree.flatten(fixed)
+        *fixed, state = fixed
 
         def i32(*shape):
             return jax.ShapeDtypeStruct(shape, jnp.int32)
@@ -2502,6 +2536,7 @@ class PagedDecodeServer:
                 program = jitted.lower(
                     *fixed, i32(self.B, nb), i32(self.B),
                     i32(self.B, 1), i32(self.B),
+                    *((state,) if state else ()),
                 ).compile()
                 sp.counts["temp_bytes"] = temp_bytes(program)
                 return program
@@ -2605,6 +2640,10 @@ class PagedDecodeServer:
         return logits[..., : self.dec.cfg.vocab_size]
 
     def _build_step(self):
+        if self.pool_state:
+            # The state pools are one more donated operand, after the
+            # four host-fed ones (no mesh serves such a stack).
+            return jax.jit(self._step_body(), donate_argnums=(1, 2, 7))
         return self._jit_tick(self._step_body(), n_rep=4)
 
     def _step_body(self):
@@ -2628,6 +2667,10 @@ class PagedDecodeServer:
         PERF.md PR 33). The layer's index is itself a scanned input,
         since a dense stack's scan hands `layer` None.
 
+        Where the stack has recurrent layers their state pools are
+        one more carried operand (`state`, last in and last out,
+        donated): read and written at [layer, slot] by the same rule.
+
         Returns `(logits, stats)` first where the decoder has experts:
         stats int32 [L, 2], per layer the assignments that fell on
         held experts and the distinct held experts touched, over live
@@ -2636,8 +2679,10 @@ class PagedDecodeServer:
         dec, bs = self.dec, self.bs
         tp = self._tp_axis()
         experts = bool(dec.cfg.num_experts)
+        recurrent = dec.cfg.has_linear
 
-        def step(params, pk, pv, tables, pos, ids, adapter_ids):
+        @dec._with_precision
+        def step(params, pk, pv, tables, pos, ids, adapter_ids, state=()):
             b = ids.shape[0]
             x = dec._embed_tokens(params, ids, pos, tp)
             rows = jnp.arange(b)
@@ -2646,14 +2691,24 @@ class PagedDecodeServer:
             row = pos % bs
 
             def body(carry, p, l, kind, layer):
-                x, pk, pv = carry
+                x, pk, pv, *state = carry
+                if kind == LINEAR:
+                    # A recurrent layer reads and writes its slot rows
+                    # of the state pools at [l], in place like the K/V
+                    # pool, and touches no block.
+                    out, *state = dec._linear_block(
+                        p, x, *state, l, live=live, layer=layer
+                    )
+                    x, stats = out if experts else (out, None)
+                    carry = (x, pk, pv, *state)
+                    return ((carry, stats) if experts else carry), None
                 # Gather this slot's pages into the contiguous view
                 # the flat block math expects: [B, Hkv, MB*bs, Dh].
                 # An int8 pool dequantizes AT the gather (scale folds
                 # into the block values), so _block sees fp blocks.
                 with jax.named_scope("kv_gather"):
-                    kc = _pool_gather(pk, tables, dec.compute_dtype, l)
-                    vc = _pool_gather(pv, tables, dec.compute_dtype, l)
+                    kc = _pool_gather(pk, tables, dec.kv_dtype, l)
+                    vc = _pool_gather(pv, tables, dec.kv_dtype, l)
                     b_, mb, hkv, _, dh = kc.shape
                     kc = kc.transpose(0, 2, 1, 3, 4).reshape(
                         b_, hkv, mb * bs, dh
@@ -2675,15 +2730,21 @@ class PagedDecodeServer:
                     pv = _pool_write_rows(
                         pv, blk, row, vc[rows, :, pos, :], l
                     )
-                carry = (x, pk, pv)
+                carry = (x, pk, pv, *state)
                 return ((carry, stats) if experts else carry), None
 
-            (x, pk, pv), _, stats = dec.scan_layers(
-                body, (x, pk, pv), params["stack"],
-                jnp.arange(_pool_arr(pk).shape[0]),
+            # Each layer's index into the pool of its kind.
+            layers = jnp.arange(_pool_arr(pk).shape[0])
+            if recurrent:
+                layers = {
+                    "attn": layers, LINEAR: jnp.arange(state[0].shape[0])
+                }
+            (x, pk, pv, *state), _, stats = dec.scan_layers(
+                body, (x, pk, pv, *state), params["stack"], layers
             )
             logits = self._replicate_logits(dec._final_logits(params, x))
-            return ((logits, stats) if experts else logits), pk, pv
+            out = (logits, stats) if experts else logits
+            return (out, pk, pv, tuple(state)) if recurrent else (out, pk, pv)
 
         return step
 
@@ -3806,6 +3867,17 @@ class PagedDecodeServer:
 
         return jax.jit(insert, donate_argnums=(0, 1))
 
+    def _build_insert_state(self):
+        """Jitted (state pools, a one-request prefill's final states
+        [Ll, 1, ...], slot) -> pools with the slot's row of every
+        recurrent layer overwritten, in place."""
+
+        @jax.named_scope("state_insert")
+        def insert(ps, pc, s, c, slot):
+            return ps.at[:, slot].set(s[:, 0]), pc.at[:, slot].set(c[:, 0])
+
+        return jax.jit(insert, donate_argnums=(0, 1))
+
     def _build_gather(self):
         """Jitted (pool_k, pool_v, table_row [MB]) -> flat single-lane
         K/V ([L, 1, Hkv, MB*bs, Dh]) — the exact inverse layout of
@@ -4608,6 +4680,20 @@ class PagedDecodeServer:
                     jnp.asarray(table_row),
                 )
                 logits_row = logits[:, t0 - 1, :]
+                if self.pool_state:
+                    # The prompt's final recurrent states (what its last
+                    # real row left) into the slot's row of their pools.
+                    with spans.span(
+                        "paged.admit.seat.state",
+                        state_bytes=self.state_bytes // self.B,
+                    ):
+                        self.pool_state = self._insert_state(
+                            *self.pool_state, small["gdn_s"],
+                            small["gdn_conv"], jnp.asarray(i, jnp.int32),
+                        )
+                    self.obs.linear_prefill_chunks.inc(
+                        -(-pad // CHUNK) * self.dec.cfg.layers_of(LINEAR)
+                    )
         with spans.span("paged.admit.seat.first_token"):
             first = self._first_token(
                 i, samp, logits_row, prompt.dtype, cid
@@ -5131,6 +5217,9 @@ class PagedDecodeServer:
             if self.dec.cfg.num_experts:
                 lo, hi = self.dec.cfg.held
                 sp.counts["experts_held"] = hi - lo
+            if self.pool_state:
+                # Slots whose recurrent states the tick read and wrote.
+                sp.counts["state_slots"] = live
 
     def _tick_variant(self) -> dict:
         """Run the tick this server's mode calls for; what to record
@@ -5188,7 +5277,9 @@ class PagedDecodeServer:
             tables = jnp.asarray(self.tables[:, :nb].copy())
             adapter = jnp.asarray(self.adapter.copy())
         with spans.span("paged.tick.dispatch"):
-            logits, self.pool_k, self.pool_v = self._step(
+            # The recurrent layers' state pools, where the stack has
+            # them, are the step's last operand and last result.
+            logits, self.pool_k, self.pool_v, *state = self._step(
                 self.params,
                 self.pool_k,
                 self.pool_v,
@@ -5196,7 +5287,10 @@ class PagedDecodeServer:
                 pos,
                 feed,
                 adapter,
+                *((self.pool_state,) if self.pool_state else ()),
             )
+            if state:
+                (self.pool_state,) = state
             moe = None
             if self.dec.cfg.num_experts:
                 # An expert decoder's step hands its counters back
@@ -5241,7 +5335,9 @@ class PagedDecodeServer:
                 )
                 rows_read = int(np.sum(posm // self.bs - lo + 1)) * self.bs
             self._account_kv_rows(rows_read, baseline)
-            for w, _ in self.dec.cfg.layer_kinds or ():
+            for w in (
+                k[0] for k in self.dec.cfg.layer_kinds or () if k != LINEAR
+            ):
                 if w is not None:
                     # Rows behind this sliding layer's window (an idle
                     # slot sits at position 0 and adds none).
@@ -6469,6 +6565,10 @@ class PagedDecodeServer:
     def _update_pool_gauges(self) -> None:
         self.obs.pool_blocks_free.set(len(self.free))
         self.obs.pool_blocks_used.set(self.blocks_in_use)
+        if self.pool_state:
+            self.obs.linear_state_slots_live.set(
+                sum(s is not None for s in self.slots)
+            )
 
     def _finish(self, i: int) -> None:
         slot = self.slots[i]

@@ -11,10 +11,15 @@ admission, the rows paged into the pool, then decode steps through the
 pool. The logits row every token was chosen from (the prefill's last
 row, then each decode step's) is held to the family's
 `reference_logits` over the same ids: max|d| / max|ref| over those rows
-must stay inside the tolerance, and every control must fall outside it:
+must stay inside the tolerance, and every control must fall outside it.
+The family's file names its controls (`CONTROLS`: a label and the fault
+its `reference_logits` plants for it); a family that names none gets
+`cohere2_moe`'s two. The last control is the same for every family:
 
   window ignored   the reference attends every j <= i in every layer
   shared sum       the reference adds the shared experts' sum, not mean
+  (qwen3_next)     the decay ignored (g = 0), the attention output's
+                   gate left out, the shared expert added ungated
   int8 weights     the reference computes with every matrix rounded to
                    int8 (symmetric, a scale per output channel): one
                    precision below the bf16 the configuration states
@@ -96,7 +101,11 @@ def fake_int8(params):
     out = []
     while leaves:
         path, a = leaves.pop(0)
-        scale_only = a.ndim < 2 or str(path[-1].key).endswith("_scale")
+        # A matrix has two axes of its own: under "stack" a leaf's
+        # first axis counts layers (a norm's scale, a decay per head
+        # are [L, n]: no matrix).
+        own = a.ndim - (str(path[0].key) == "stack")
+        scale_only = own < 2 or str(path[-1].key).endswith("_scale")
         out.append(a if scale_only else rounded(a))
         del a
     return jax.tree_util.tree_unflatten(treedef, out)
@@ -156,13 +165,13 @@ def main(argv=None) -> int:
         return rel
 
     served = against("served against the reference", params)
+    planted = getattr(family, "CONTROLS", {
+        "window_ignored": {"ignore_window": True},
+        "shared_sum": {"shared_sum": True},
+    })
     controls = {
-        "window_ignored": against(
-            "control, window ignored", params, ignore_window=True
-        ),
-        "shared_sum": against(
-            "control, shared experts summed", params, shared_sum=True
-        ),
+        name: against(f"control, {name}", params, **fault)
+        for name, fault in planted.items()
     }
     # The rounded weights take the place of the served ones, which the
     # server holds too: it goes first.

@@ -118,6 +118,77 @@ def test_the_step_compiled_for_a_v5e_holds_no_copy_of_the_pool(
     assert program.memory_analysis().temp_size_in_bytes < one_pool
 
 
+def recurrent_and_attention():
+    """Family `qwen3_next` at the sizes its two kernels are built for
+    (head size 256 in `flash_decode`, 128 x 128 states in `gdn_step`),
+    two periods of [linear, linear, linear, full]."""
+    from perfbench import harness
+    from tests.test_qwen3_next import REPO, TOY as HYBRID
+
+    qwen3_next = harness.load_module(
+        f"{REPO}/perfbench/families/qwen3_next.py"
+    )
+    return qwen3_next.build_decoder({
+        **HYBRID, "hidden_size": 256, "head_dim": 256,
+        "linear_key_head_dim": 128, "linear_value_head_dim": 128,
+        "linear_num_key_heads": 4, "linear_num_value_heads": 8,
+        "max_position_embeddings": 1024, "vocab_size": 512,
+    })
+
+
+def test_the_step_compiled_for_a_v5e_holds_no_copy_of_the_state_pool(
+    one_chip, compiled_for_the_chip
+):
+    """The recurrent layers' state pool rides in the layer loop's carry
+    beside the K/V pool and is donated: the `gdn_step` kernel rewrites
+    one layer's cells where they lie (its output aliases its operand),
+    no instruction makes a second array of the pool's shape or of a
+    layer's slice of it, and the program's temporaries stay under the
+    state pool's bytes and under one K/V pool's."""
+    dec = recurrent_and_attention()
+    # 32 slots: a state pool of 100 MB, which the compiler cannot keep
+    # in its on-chip memory (a pool of 8 slots it moves there and back).
+    nb, bs, b = 2048, 16, 32
+    shapes = jax.eval_shape(dec.init, jax.random.key(0))
+    srv = PagedDecodeServer(
+        dec,
+        jax.tree.map(lambda s: jnp.zeros(s.shape, jnp.bfloat16), shapes),
+        num_blocks=nb, block_size=bs, max_batch=b,
+    )
+    assert srv.pool_k.shape == (2, nb, 2, bs, 256)
+    assert srv.pool_k.dtype == jnp.bfloat16
+    state, rows = srv.pool_state
+    assert state.shape == (6, b, 8, 128, 128) and state.dtype == jnp.float32
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    program = srv._build_step().lower(
+        jax.tree.map(on_chip, srv.params), on_chip(srv.pool_k),
+        on_chip(srv.pool_v), i32(b, srv._rungs[0]), i32(b), i32(b, 1), i32(b),
+        jax.tree.map(on_chip, srv.pool_state),
+    ).compile()
+    text = program.as_text()
+    made = set(re.findall(
+        rf"= f32\[(?:6|1),{b},8,128,128\]\{{[^}}]*\}} ([\w\-]+)\(", text
+    ))
+    # The kernel's aliased output is read out of its tuple; nothing
+    # else makes an array of the pool's shape or of a layer's slice.
+    assert made <= {"get-tuple-element", "parameter"}, made
+    assert len(re.findall(r" custom-call\([^\n]*gdn_step", text)) >= 3
+    assert "flash_decode" in text
+    temp = program.memory_analysis().temp_size_in_bytes
+    assert temp < state.size * 4
+    assert temp < srv.pool_k.size * 2
+    # Every pool is handed back in the buffer it came in.
+    assert program.memory_analysis().alias_size_in_bytes >= (
+        2 * srv.pool_k.size * 2 + state.size * 4 + rows.size * 4
+    )
+
+
 @pytest.mark.parametrize("layer", [None, 2])
 def test_the_head_row_write_is_the_row_write(layer):
     """`_pool_write_rows` on an fp pool against the form it replaced,
