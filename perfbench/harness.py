@@ -528,6 +528,9 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
     run.builds_close = builds.snapshot()
     run.blocks_peak = srv.blocks_peak
     run.pending_at_close = len(srv.pending)
+    # Live slots at the last tick; 0 where the server stands idle.
+    idle = all(s is None for s in srv.slots)
+    live_at_close = 0 if idle or not run.ticks else run.ticks[-1][3]
     # A request due after the window closed was never attempted.
     run.due = {rid: d for rid, d in run.due.items() if d <= run.t_close}
 
@@ -560,6 +563,20 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
             "device_ops": xplane.top_device_ops(run.trace),
             "idle_gaps": xplane.idle_by_span(run.trace),
         }
+    # How much of its queue the run used: a backlog served dry reads
+    # `pending_at_close` 0, no live slot and an idle tail of seconds,
+    # and its traced slice may hold no device operation at all.
+    stamps = run.window_stamps()
+    last_token = max((s[-1] for s in stamps.values()), default=run.t_open)
+    admits = run.window_admits()
+    last_seated = max((a[1] - run.t_open for a in admits), default=None)
+    result["sizing"] = {
+        "queued_at_open": run.queued_at_open,
+        "pending_at_close": run.pending_at_close,
+        "last_seated_s": last_seated,
+        "live_at_close": live_at_close,
+        "idle_tail_s": run.t_close - last_token,
+    }
     # Each number `correct` compared, beside its limit: last in the
     # result line, and the last lines of standard error.
     result["compared"] = compared = {
@@ -567,13 +584,11 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
             "value": check_detail.get("behind_best_max"), "limit": MODEL_TOL,
         },
     }
-    stamps = run.window_stamps()
     gaps = metrics.inter_token_gaps(stamps)
     ticks = run.window_ticks()
     fifths = [run.t_open + run.seconds * k / 5 for k in range(1, 6)]
     first = {rid: s[0] for rid, s in rec.stamps.items()}
     tick_spans = program_spans.in_window(run, "paged.tick")
-    admits = run.window_admits()
     calls = sorted([k[:2] for k in ticks] + [a[:2] for a in admits])
     details = {
         "workload": workload["name"], "seed": args.seed,
@@ -598,7 +613,7 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
         ],
         # When the last request was seated, from the window's opening:
         # a backlog that lasts seats one in the last seconds.
-        "last_seated_s": max((a[1] - run.t_open for a in admits), default=None),
+        "last_seated_s": last_seated,
         "ttft_p50_s": run.ttft_p50(),
         "itl_mean_s": sum(gaps) / len(gaps) if gaps else None,
         # The longest tick and when it began, from the window's opening.
@@ -651,6 +666,16 @@ def run_cell(args, *, root: str, t_start: float, devices=None, out=print) -> int
     }
     out("details: " + json.dumps(details))
     out(json.dumps(result))
+    if tracing and run.trace is None:
+        queue = (
+            f"queue dry at {last_seated} s" if not run.pending_at_close
+            else f"{run.pending_at_close} still queued"
+        )
+        print(
+            f"traced slice empty: last token at {last_token - run.t_open} s"
+            f" of {run.t_close - run.t_open} s, {queue}",
+            file=sys.stderr,
+        )
     for name, c in compared.items():
         print(f"compared: {name} {c['value']} limit {c['limit']}", file=sys.stderr)
     return 0

@@ -158,9 +158,19 @@ def test_the_new_entries_keep_the_contract_with_no_edit_to_it():
     assert harness.load_json(
         harness.find(rehearsal_util.REPO, BENCH, "cells", CELL + ".json")
     )["backlog_per_s"] > 0
+    # The two readers are listed for every cell whose configuration
+    # has experts, and for no other.
+    files = {c["name"]: c["file"] for c in BENCH["configs"]}
+    expert_cells = [
+        w["name"] for w in BENCH["workloads"]
+        if "num_experts" in harness.load_json(
+            os.path.join(rehearsal_util.REPO, files[w["config"]])
+        )
+    ]
+    assert CELL in expert_cells and len(expert_cells) >= 2
     for m in BENCH["per_layer"]:
         if m["name"] in READERS:
-            assert m["workloads"] == [CELL] and m["layer"] == "experts"
+            assert m["workloads"] == expert_cells and m["layer"] == "experts"
             assert m["moves"] == "tpot_p50_s"
     reports = {m["name"] for m in harness.cell_metrics(BENCH, "end_to_end", CELL)}
     assert reports == {"tpot_p50_s", "tokens_per_s", "setup_s"}

@@ -192,10 +192,11 @@ def test_the_new_entries_keep_the_contract_with_no_edit_to_it():
     for m in BENCH["per_layer"]:
         if m["name"] in READERS:
             assert m["workloads"] == [CELL] and m["moves"] == "tpot_p50_s"
-    # The two `moe_*` readers' lists stay as PR 28's rehearsal holds
-    # them (to its one cell): they read in this cell's traced runs on
-    # the day a `benchmark` PR appends it (`CHANGES.md`, PR 34).
-    for name in ("tokens_per_s", "tokens_per_s_slice_p50", "queue_left_share"):
+    # The two `moe_*` readers list this cell too since PR 36 (PR 28's
+    # rehearsal holds them to every cell whose configuration has
+    # experts).
+    for name in ("tokens_per_s", "tokens_per_s_slice_p50", "queue_left_share",
+                 "moe_tokens_per_held_expert", "moe_experts_touched_share"):
         m = next(m for g in ("end_to_end", "per_layer") for m in BENCH[g] if m["name"] == name)
         assert m["workloads"][-1] == CELL
     reports = {m["name"] for m in harness.cell_metrics(BENCH, "end_to_end", CELL)}

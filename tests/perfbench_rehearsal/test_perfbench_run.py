@@ -32,7 +32,7 @@ def run_tiny(tmp_path, trace, mesh=None, chips=1, **tree):
 
 def test_last_line_has_exactly_the_contracts_keys(tmp_path, cpu_peaks):
     result, details = run_tiny(tmp_path, trace=0)
-    assert list(result) == ["correct", "attempted", "failed", "metrics", "device", "compared"]
+    assert list(result) == ["correct", "attempted", "failed", "metrics", "device", "sizing", "compared"]
     # A configuration that states no `check_prompt_tokens`: the default
     # 256, capped at half of the toy table.
     assert details["correct"]["prompt_tokens"] == 64
