@@ -17,7 +17,9 @@ Grid semantics every kernel here declares to Mosaic: the batch and head
 axes are "parallel" (cells share nothing), and the decode/prefill
 kernels' innermost K-block axis is "arbitrary" — it must run in order
 on one core, because the online-softmax carry lives in VMEM scratch
-across it.
+across it. `table_flash_decode` declares both of its axes "arbitrary":
+each cell starts the copies of the next live cell, in its slot or the
+next, so the whole grid runs in order.
 
 Differentiable: a custom VJP recomputes attention with the XLA
 reference implementation in the backward pass (flash-style
@@ -604,6 +606,259 @@ def paged_flash_decode(
         interpret=interpret,
         name="paged_flash_decode",
     )(tables, pos1, *operands)
+    return out[:, :, :g, :].reshape(b, hq, d)
+
+
+# Rows of every KV head that one `table_flash_decode` cell folds: the
+# K block `flash_decode` tiles a gathered span with, so that both fold
+# the same rows in the same order.
+_TABLE_ROWS = 256
+
+
+def table_decode_pages(block_size: int, cols: int) -> int | None:
+    """Pages of `block_size` rows that one `table_flash_decode` cell
+    folds, for a block table of `cols` columns; None where the table is
+    no whole number of such chunks, which the caller attends otherwise."""
+    if _TABLE_ROWS % block_size or cols * block_size % _TABLE_ROWS:
+        return None
+    return _TABLE_ROWS // block_size
+
+
+def _table_decode_kernel(
+    layer_ref,
+    tables_ref,
+    pos_ref,
+    q_ref,
+    k_hbm,
+    v_hbm,
+    o_ref,
+    k_buf,
+    v_buf,
+    sems,
+    buf_ref,
+    m_scr,
+    l_scr,
+    acc_scr,
+    *,
+    sm_scale: float,
+    block_size: int,
+    pages: int,
+    window: int | None,
+    num_chunks: int,
+    batch: int,
+):
+    """One (slot, chunk) cell of `table_flash_decode`: every KV head's
+    query group folds the chunk's 256 rows into its online-softmax
+    carry, `_decode_kernel`'s fold for all heads at once (one batched
+    product over the head axis: head by head, the products left the
+    matrix unit idle between them and took twice the time). The
+    chunk's pages are copied straight out of the pool, one copy a live
+    page of all heads ([Hkv, bs, Dh], contiguous in the pool) into its
+    rows of a [Hkv, 256, Dh] buffer; the next live cell's copies, in
+    this slot or the next, start before this one is folded, into the
+    other of two buffers (so the grid runs in order on one core). A
+    dead chunk, wholly outside [pos-window+1, pos], copies and folds
+    nothing. Rows of a chunk that no live page filled hold what the
+    buffer held before: masked out of the scores, zeroed out of V."""
+    i, c = pl.program_id(0), pl.program_id(1)
+    rows = pages * block_size
+    layer = layer_ref[0]
+    p_b = pos_ref[i]
+    lo, hi = _decode_lo_hi(p_b, rows, window)
+    live = (c >= lo) & (c <= hi)
+
+    def live_pages(j, chunk, buf, act):
+        """`act` on the K and V copies of every live page of slot j's
+        chunk `chunk` into buffer `buf`: a loop over the live ones, so
+        that the kernel lowers to a few operations whatever a chunk's
+        page count."""
+        first, last = _decode_lo_hi(pos_ref[j], block_size, window)
+        base = chunk * pages
+
+        def page(p, carry):
+            blk = tables_ref[j, base + p]
+            at = pl.ds(pl.multiple_of(p * block_size, block_size), block_size)
+            for kv, (src, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                act(
+                    pltpu.make_async_copy(
+                        src.at[layer, blk], dst.at[buf, :, at], sems.at[buf, kv]
+                    )
+                )
+            return carry
+
+        lax.fori_loop(
+            jnp.clip(first - base, 0, pages),
+            jnp.clip(last - base + 1, 0, pages),
+            page,
+            0,
+        )
+
+    def start(dma):
+        dma.start()
+
+    def wait(dma):
+        dma.wait()
+
+    @pl.when(c == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, _MASK_VALUE, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    @pl.when(live & (i == 0) & (c == lo))
+    def _first():
+        buf_ref[0] = 0
+        live_pages(i, c, 0, start)
+
+    @pl.when(live)
+    def _fold():
+        cur = buf_ref[0]
+        last = c == hi
+        nxt = jnp.where(last, i + 1, i)
+
+        @pl.when(nxt < batch)
+        def _prefetch():
+            j = jnp.minimum(nxt, batch - 1)
+            first = _decode_lo_hi(pos_ref[j], rows, window)[0]
+            live_pages(j, jnp.where(last, first, c + 1), 1 - cur, start)
+
+        live_pages(i, c, cur, wait)
+        buf_ref[0] = 1 - cur
+        q = q_ref[0].astype(jnp.float32) * sm_scale  # (Hkv, G, d)
+        k = k_buf[cur].astype(jnp.float32)  # (Hkv, rows, d)
+        v = v_buf[cur].astype(jnp.float32)
+        kept = c * rows + lax.broadcasted_iota(jnp.int32, v.shape[1:], 0)
+        keep = kept <= p_b
+        if window is not None:
+            keep &= kept > p_b - window
+        v = jnp.where(keep[None], v, 0.0)
+        s = lax.dot_general(
+            q,
+            k,
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # (Hkv, G, rows)
+        cols = c * rows + lax.broadcasted_iota(jnp.int32, s.shape[1:], 1)
+        mask = cols <= p_b
+        if window is not None:
+            mask &= cols > p_b - window
+        s = jnp.where(mask[None], s, _MASK_VALUE)
+        m = m_scr[...]  # (Hkv, G, 1)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        m_scr[...] = m_new
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
+            p,
+            v,
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+
+    @pl.when(c == num_chunks - 1)
+    def _emit():
+        o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+def table_flash_decode(
+    q: jax.Array,
+    pool_k: jax.Array,
+    pool_v: jax.Array,
+    layer: jax.Array,
+    tables: jax.Array,
+    pos: jax.Array,
+    *,
+    window: int | None = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Flash-decode through the block table, a chunk of 256 rows of
+    every KV head a grid cell: one query token per slot attends its
+    own pages of one layer of the whole pool, and no contiguous span
+    of them is ever made in HBM.
+
+    q [B, Hq, Dh], any float dtype (the fold is float32); pool_k /
+    pool_v [L, NB, Hkv, bs, Dh], the whole floating-point pool, read in
+    place (`memory_space=ANY`); layer: the pool's layer to read;
+    tables [B, MB] int32 pool indices (unallocated entries = trash
+    block 0), MB a whole number of 256-row chunks
+    (`table_decode_pages`); pos [B] int32 = each slot's last valid
+    key, INCLUSIVE, its row already written. Returns [B, Hq, Dh] in
+    q.dtype.
+
+    The grid is (slot, chunk); a cell copies the chunk's live pages
+    and folds 256 rows per KV head for its query group (zero-padded
+    to 8 rows and sliced back), in the order `flash_decode` folds a
+    gathered span of the same table with its 256-row K blocks, with
+    the same mask (`cols <= pos`, and `> pos - window` under a
+    window) and the same live range (`_decode_lo_hi`). VMEM holds two
+    K and two V buffers of [Hkv, 256, Dh]."""
+    b, hq, d = q.shape
+    _, _, hkv, bs, _ = pool_k.shape
+    if hq % hkv:
+        raise ValueError(f"Hq={hq} must be a multiple of Hkv={hkv}")
+    if tables.ndim != 2 or tables.shape[0] != b:
+        raise ValueError(f"tables must be [B={b}, MB], got {tables.shape}")
+    cols = tables.shape[1]
+    pages = table_decode_pages(bs, cols)
+    if pages is None:
+        raise ValueError(
+            f"a table of {cols} columns of {bs} rows is no whole number "
+            f"of {_TABLE_ROWS}-row chunks"
+        )
+    g = hq // hkv
+    g_pad = max(g, 8)
+    qg = q.reshape(b, hkv, g, d)
+    if g_pad != g:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - g), (0, 0)))
+    num_chunks = cols // pages
+    kernel = functools.partial(
+        _table_decode_kernel,
+        sm_scale=d**-0.5,
+        block_size=bs,
+        pages=pages,
+        window=window,
+        num_chunks=num_chunks,
+        batch=b,
+    )
+
+    def slot_block(i, c, *_):
+        return (i, 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, num_chunks),
+        in_specs=[
+            pl.BlockSpec((1, hkv, g_pad, d), slot_block),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, hkv, g_pad, d), slot_block),
+        scratch_shapes=[
+            pltpu.VMEM((2, hkv, _TABLE_ROWS, d), pool_k.dtype),
+            pltpu.VMEM((2, hkv, _TABLE_ROWS, d), pool_v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((hkv, g_pad, 1), jnp.float32),
+            pltpu.VMEM((hkv, g_pad, 1), jnp.float32),
+            pltpu.VMEM((hkv, g_pad, d), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g_pad, d), q.dtype),
+        compiler_params=_grid_params("arbitrary", "arbitrary"),
+        interpret=interpret,
+        name="table_flash_decode",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.asarray(tables, jnp.int32),
+        jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,)),
+        qg,
+        pool_k,
+        pool_v,
+    )
     return out[:, :, :g, :].reshape(b, hq, d)
 
 

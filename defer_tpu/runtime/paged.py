@@ -26,7 +26,15 @@ Static-shape design (everything jits once):
         it reads O(B * rung * block_size) rows; every other tick kind
         reads the whole table, O(B * max_blocks * block_size) rows
         regardless of request depth, which is the baseline all paths
-        are measured against.
+        are measured against. Where the flash-decode kernel runs
+        (`_flash_decode_mode`), the pool is floating point and the
+        table a whole number of 256-row chunks, the step attends
+        THROUGH the table instead (`_attend`): the new row is written
+        into the pool first and `table_flash_decode` reads each slot's
+        live pages out of it, folding the same 256-row blocks in the
+        same order as `flash_decode` over the gathered span, so the
+        two agree to the bit; no span is made, and each slot reads
+        O(own live blocks) rows.
       - "blockwise": attend THROUGH the block table — scatter the new
         K/V row into the pool first, then fold pool blocks into an
         online-softmax carry (running max / denominator,
@@ -99,7 +107,11 @@ from defer_tpu.models.quant import (
 from defer_tpu.obs import spans
 from defer_tpu.obs.serving import ServerStats, ServingMetrics
 from defer_tpu.ops.gated_delta import CHUNK
-from defer_tpu.ops.pallas_attention import _MASK_VALUE
+from defer_tpu.ops.pallas_attention import (
+    _MASK_VALUE,
+    table_decode_pages,
+    table_flash_decode,
+)
 from defer_tpu.parallel.transformer_stack import LINEAR
 from defer_tpu.runtime.batching import (
     accept_lengths,
@@ -190,6 +202,8 @@ def _pool_write_rows(pool_l, dest, rowi, val, layer=None):
     one back, per layer where the pool is scanned and at the
     program's edge where it is carried (PERF.md PR 33). A window of
     trailing elements scatters in the layout the pool is stored in.
+    The row is cast to the pool's dtype first: a float32 row scattered
+    into a bf16 pool converts the whole pool to float32 and back.
 
     An int8 pool can't write a row in place — symmetric int8 keeps
     ONE scale per (block, head), so landing a row means re-deriving
@@ -207,7 +221,7 @@ def _pool_write_rows(pool_l, dest, rowi, val, layer=None):
     if not isinstance(pool_l, dict):
         heads = jnp.arange(pool_l.shape[-3])
         at = pre + (dest[:, None], heads[None, :], rowi[:, None])
-        return pool_l.at[at].set(val)
+        return pool_l.at[at].set(val.astype(pool_l.dtype))
     n = dest.shape[0]
     bs = pool_l["q"].shape[-2]
     at = pre + (dest,)
@@ -1862,6 +1876,10 @@ class PagedDecodeServer:
         self.constrained_tokens_n = 0
         self.constraint_dead_ends_n = 0
         self._step = None
+        # How the step's attention reads a table, by its width
+        # (`_attend`): decided once a rung as the step is built, and
+        # read by the K/V-row counters and the tick span.
+        self._attend_at: dict[int, str] = {}
         self._insert = None
         self._insert_state = None
         self._insert_dyn = None
@@ -2470,6 +2488,7 @@ class PagedDecodeServer:
             self._mesh_key,
         )
         jitted = cached_step(self.dec, step_key, builders[self.attention])
+        self._attend_at = {nb: self._attend(nb) for nb in self._rungs}
         self._step = _SpanSteps(
             jitted, self._build_span_programs(jitted, step_key)
         )
@@ -2675,7 +2694,13 @@ class PagedDecodeServer:
         stats int32 [L, 2], per layer the assignments that fell on
         held experts and the distinct held experts touched, over live
         rows (an idle slot sits at position 0, which no live one
-        does)."""
+        does).
+
+        An attention layer attends in one of two ways, by `_attend`
+        of the table's width: through the table (the new row written
+        into the pool first, then `table_flash_decode` reads each
+        slot's live pages of the layer out of the carried pool), or
+        over the span gathered from the pool, `_block`'s own math."""
         dec, bs = self.dec, self.bs
         tp = self._tp_axis()
         experts = bool(dec.cfg.num_experts)
@@ -2689,6 +2714,7 @@ class PagedDecodeServer:
             live = (pos > 0)[:, None]
             blk = tables[rows, pos // bs]  # [B]
             row = pos % bs
+            through_table = self._attend(tables.shape[1]) == "table"
 
             def body(carry, p, l, kind, layer):
                 x, pk, pv, *state = carry
@@ -2702,34 +2728,41 @@ class PagedDecodeServer:
                     x, stats = out if experts else (out, None)
                     carry = (x, pk, pv, *state)
                     return ((carry, stats) if experts else carry), None
-                # Gather this slot's pages into the contiguous view
-                # the flat block math expects: [B, Hkv, MB*bs, Dh].
-                # An int8 pool dequantizes AT the gather (scale folds
-                # into the block values), so _block sees fp blocks.
-                with jax.named_scope("kv_gather"):
-                    kc = _pool_gather(pk, tables, dec.kv_dtype, l)
-                    vc = _pool_gather(pv, tables, dec.kv_dtype, l)
-                    b_, mb, hkv, _, dh = kc.shape
-                    kc = kc.transpose(0, 2, 1, 3, 4).reshape(
-                        b_, hkv, mb * bs, dh
+                if through_table:
+                    out, pk, pv = self._table_layer(
+                        p, x, pk, pv, l, tables, pos, blk, row,
+                        adapter_ids, kind, live, layer,
                     )
-                    vc = vc.transpose(0, 2, 1, 3, 4).reshape(
-                        b_, hkv, mb * bs, dh
+                else:
+                    # Gather this slot's pages into the contiguous view
+                    # the flat block math expects: [B, Hkv, MB*bs, Dh].
+                    # An int8 pool dequantizes AT the gather (scale
+                    # folds into the block values), so _block sees fp
+                    # blocks.
+                    with jax.named_scope("kv_gather"):
+                        kc = _pool_gather(pk, tables, dec.kv_dtype, l)
+                        vc = _pool_gather(pv, tables, dec.kv_dtype, l)
+                        b_, mb, hkv, _, dh = kc.shape
+                        kc = kc.transpose(0, 2, 1, 3, 4).reshape(
+                            b_, hkv, mb * bs, dh
+                        )
+                        vc = vc.transpose(0, 2, 1, 3, 4).reshape(
+                            b_, hkv, mb * bs, dh
+                        )
+                    out, kc, vc = dec._block(
+                        p, x, kc, vc, pos, tp_axis=tp,
+                        adapter_ids=adapter_ids, kind=kind, live=live,
+                        layer=layer,
                     )
-                out, kc, vc = dec._block(
-                    p, x, kc, vc, pos, tp_axis=tp,
-                    adapter_ids=adapter_ids, kind=kind, live=live,
-                    layer=layer,
-                )
+                    # Scatter ONLY the new row back to its page.
+                    with jax.named_scope("kv_scatter"):
+                        pk = _pool_write_rows(
+                            pk, blk, row, kc[rows, :, pos, :], l
+                        )
+                        pv = _pool_write_rows(
+                            pv, blk, row, vc[rows, :, pos, :], l
+                        )
                 x, stats = out if experts else (out, None)
-                # Scatter ONLY the new row back to its page.
-                with jax.named_scope("kv_scatter"):
-                    pk = _pool_write_rows(
-                        pk, blk, row, kc[rows, :, pos, :], l
-                    )
-                    pv = _pool_write_rows(
-                        pv, blk, row, vc[rows, :, pos, :], l
-                    )
                 carry = (x, pk, pv, *state)
                 return ((carry, stats) if experts else carry), None
 
@@ -2747,6 +2780,58 @@ class PagedDecodeServer:
             return (out, pk, pv, tuple(state)) if recurrent else (out, pk, pv)
 
         return step
+
+    def _attend(self, cols: int) -> str:
+        """How the decode step's attention layers read a table of
+        `cols` columns: the server's `attention`, except that the
+        gathered step attends through the table ("table") where the
+        flash-decode kernel runs (`_flash_decode_mode`), the pool is
+        floating point and the table is a whole number of
+        `table_flash_decode`'s chunks. Asked where the step body is
+        traced and, for every rung, as its programs are built
+        (`_attend_at`), so the two agree."""
+        from defer_tpu.models.gpt import _flash_decode_mode
+
+        if (
+            self.attention == "gathered"
+            and self.kv_dtype == "fp"
+            and _flash_decode_mode() is not None
+            and table_decode_pages(self.bs, cols) is not None
+        ):
+            return "table"
+        return self.attention
+
+    def _table_layer(
+        self, p, x, pk, pv, l, tables, pos, blk, row, adapter_ids, kind,
+        live, layer,
+    ):
+        """One attention layer of the decode step through the block
+        table: `_block` with its attention read out of the pool. The
+        new K/V row is written at [l, blk, :, row] first, then
+        `table_flash_decode` attends each slot's live pages of layer
+        `l`; the projections, gate and everything after attention are
+        `_block`'s own code. Returns (out, pk, pv)."""
+        from defer_tpu.models.gpt import _flash_decode_mode
+
+        dec = self.dec
+        q, k_new, v_new, gate = dec._attn_qkv(
+            p, x, pos, adapter_ids, kind, with_gate=True
+        )
+        with jax.named_scope("kv_scatter"):
+            pk = _pool_write_rows(pk, blk, row, k_new[:, :, 0, :], l)
+            pv = _pool_write_rows(pv, blk, row, v_new[:, :, 0, :], l)
+        b, hq, _, dh = q.shape
+        with jax.named_scope("attn_core"):
+            attn = table_flash_decode(
+                q[:, :, 0, :], pk, pv, l, tables, pos,
+                window=dec.cfg.kind_of(kind)[0],
+                interpret=_flash_decode_mode() == "interpret",
+            )  # [B, Hq, Dh]
+            attn = attn.astype(x.dtype).reshape(b, 1, hq * dh)
+        out = dec._attn_out(
+            p, x, attn, self._tp_axis(), adapter_ids, live, layer, gate
+        )
+        return out, pk, pv
 
     def _build_step_blockwise(self):
         return self._jit_tick(self._step_body_blockwise(), n_rep=4)
@@ -5244,16 +5329,17 @@ class PagedDecodeServer:
         if self.decode_window > 1:
             self._tick_window()
             return {"kind": "window"}
-        return {"kind": "plain", "span_rows": self._tick_plain()}
+        return {"kind": "plain", **self._tick_plain()}
 
-    def _tick_plain(self) -> int:
+    def _tick_plain(self) -> dict:
         """One token for every live slot: the default tick, and the
         one whose phases the span log holds (plan, dispatch, sample,
-        sync, drain — `obs/spans.py`). Returns the rows of each slot's
-        table that the step was handed."""
+        sync, drain — `obs/spans.py`). Returns the `span_rows` of each
+        slot's table that the step was handed and how its attention
+        read them (`attend`: "table" or "gathered", `_attend`)."""
         live = [s is not None for s in self.slots]
         if not any(live):
-            return 0
+            return {"span_rows": 0}
         with spans.span("paged.tick.plan"):
             self._build()
             # Persistent [B,1] device feed (constructor note):
@@ -5318,15 +5404,17 @@ class PagedDecodeServer:
             # is pinned by; units in obs/serving.py). "gathered" reads
             # every slot to the rung above the batch's deepest live
             # slot, "blockwise" to its deepest live block; "pallas"
-            # clamps per slot, so each reads only its own live span.
+            # and "table" clamp per slot, so each reads only its own
+            # live span.
             baseline = self.B * self.MB * self.bs
-            if self.attention == "gathered":
+            attend = self._attend_at[nb]
+            if attend == "gathered":
                 rows_read = self.B * nb * self.bs
-            elif self.attention == "blockwise":
+            elif attend == "blockwise":
                 rows_read = (
                     self.B * (int(posm.max()) // self.bs + 1) * self.bs
                 )
-            else:  # pallas
+            else:  # pallas, table
                 win = self.dec.cfg.window
                 lo = (
                     np.maximum(posm - win + 1, 0) // self.bs
@@ -5432,7 +5520,7 @@ class PagedDecodeServer:
                 self._emit_token(
                     i, slot, host_nxt[i] if need_host else (nxt, i)
                 )
-        return nb * self.bs
+        return {"span_rows": nb * self.bs, "attend": attend}
 
     def _tick_spec(self) -> None:
         """One speculative round: TWO host dispatches advance every
@@ -6430,7 +6518,10 @@ class PagedDecodeServer:
         K = self.decode_window
         bs = self.bs
         baseline = K * self.B * self.MB * bs
-        if self.attention == "gathered":
+        # The window's step reads the whole table; a pipeline's stages
+        # run their own step, which never reads through it.
+        attend = self._attend_at.get(self.MB, self.attention)
+        if attend == "gathered":
             rows_read = baseline
         else:
             # Pure-python mirror over host ints (posm/emitted are
@@ -6443,11 +6534,11 @@ class PagedDecodeServer:
                     p + t if t < e else 0
                     for p, e in zip(pos_l, emitted)
                 ]
-                if self.attention == "blockwise":
+                if attend == "blockwise":
                     rows_read += (
                         self.B * (max(pe) // bs + 1) * bs
                     )
-                else:  # pallas
+                else:  # pallas, table
                     rows_read += bs * sum(
                         p // bs
                         - (max(p - win + 1, 0) // bs

@@ -1,7 +1,8 @@
-"""The gathered decode step updates the KV pool in place: compiled
-ahead of time for a described v5e it holds no copy of the pool, and
-the one-row write that makes that possible puts the same values in the
-same places as the form it replaced."""
+"""The decode step updates the KV pool in place: compiled ahead of time
+for a described v5e it holds no copy of the pool, whether it attends
+through the block table or over a gathered span, and the one-row
+write that makes that possible puts the same values in the same places
+as the form it replaced."""
 
 import re
 
@@ -73,16 +74,10 @@ def compiled_for_the_chip(monkeypatch):
 POOL_SIZED = ("copy", "dynamic-update-slice", "dynamic-slice", "custom-call")
 
 
-@pytest.mark.parametrize("make", [dense_gqa, kinds_and_experts])
-def test_the_step_compiled_for_a_v5e_holds_no_copy_of_the_pool(
-    make, one_chip, compiled_for_the_chip
-):
-    """No instruction of the optimised program makes an array of the
-    pool's shape or of one layer's slice of it but the scatters that
-    write the new rows (a `custom-call` of that shape is XLA's
-    `AllocateBuffer`: a second pool), the pool keeps the layout it is
-    stored in, and the program's temporaries are under one pool's
-    bytes."""
+def step_for_the_chip(make, one_chip):
+    """A server over `make()`'s stack with a bf16 pool of 512 blocks,
+    and `compiled(cols)`: its decode step compiled for a described v5e
+    on a table of `cols` columns."""
     dec = make()
     nb, bs, b = 512, 16, 8
     shapes = jax.eval_shape(dec.init, jax.random.key(0))
@@ -99,13 +94,25 @@ def test_the_step_compiled_for_a_v5e_holds_no_copy_of_the_pool(
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
-    program = srv._build_step().lower(
-        jax.tree.map(on_chip, srv.params), on_chip(srv.pool_k),
-        on_chip(srv.pool_v), i32(b, srv._rungs[0]), i32(b), i32(b, 1), i32(b),
-    ).compile()
+    def compiled(cols):
+        return srv._build_step().lower(
+            jax.tree.map(on_chip, srv.params), on_chip(srv.pool_k),
+            on_chip(srv.pool_v), i32(b, cols), i32(b), i32(b, 1), i32(b),
+        ).compile()
+
+    return srv, compiled
+
+
+def assert_holds_no_copy_of_the_pool(srv, program):
+    """No instruction of the optimised program makes an array of the
+    pool's shape or of one layer's slice of it but the scatters that
+    write the new rows (a `custom-call` of that shape is XLA's
+    `AllocateBuffer`: a second pool), the pool keeps the layout it is
+    stored in, and the program's temporaries are under one pool's
+    bytes."""
     text = program.as_text()
     assert "tpu_custom_call" in text  # the kernel's step, as on the chip
-    layers, _, hkv, _, dh = srv.pool_k.shape
+    layers, nb, hkv, bs, dh = srv.pool_k.shape
     shaped = re.compile(
         rf"= bf16\[(?:{layers}|1),{nb},{hkv},{bs},{dh}\]\{{([\d,]+)[^}}]*\}} "
         r"([\w\-]+)\("
@@ -116,6 +123,50 @@ def test_the_step_compiled_for_a_v5e_holds_no_copy_of_the_pool(
     assert {layout for layout, _ in found} == {"4,3,2,1,0"}, found
     one_pool = srv.pool_k.size * srv.pool_k.dtype.itemsize
     assert program.memory_analysis().temp_size_in_bytes < one_pool
+
+
+def gathered(self, cols):
+    """`PagedDecodeServer._attend` pinned to the gathered span."""
+    return "gathered"
+
+
+@pytest.mark.parametrize("make", [dense_gqa, kinds_and_experts])
+def test_the_step_compiled_for_a_v5e_holds_no_copy_of_the_pool(
+    make, one_chip, compiled_for_the_chip, monkeypatch
+):
+    """The step over a gathered span (`flash_decode` over the table's
+    pages made contiguous: the int8 pool, a rung of no whole 256-row
+    chunks) holds no copy of the pool (`assert_holds_no_copy_of_the_pool`)."""
+    monkeypatch.setattr(PagedDecodeServer, "_attend", gathered)
+    srv, compiled = step_for_the_chip(make, one_chip)
+    program = compiled(srv._rungs[0])
+    text = program.as_text()
+    assert "flash_decode" in text and "table_flash_decode" not in text
+    assert_holds_no_copy_of_the_pool(srv, program)
+
+
+@pytest.mark.parametrize("make", [dense_gqa, kinds_and_experts])
+def test_the_step_through_the_table_holds_no_copy_of_the_pool(
+    make, one_chip, compiled_for_the_chip, monkeypatch
+):
+    """On a table of whole 256-row chunks the step attends through the
+    table (`table_flash_decode` reads the carried pool) and holds no
+    copy of the pool either; on the whole table its temporaries are
+    under those of the same step over a gathered span."""
+    srv, compiled = step_for_the_chip(make, one_chip)
+    assert srv._attend(srv._rungs[0]) == "table"
+    program = compiled(srv._rungs[0])
+    assert "table_flash_decode" in program.as_text()
+    assert_holds_no_copy_of_the_pool(srv, program)
+
+    def temp(program):
+        return program.memory_analysis().temp_size_in_bytes
+
+    whole = srv._rungs[-1]
+    through = temp(compiled(whole))
+    with monkeypatch.context() as m:
+        m.setattr(PagedDecodeServer, "_attend", gathered)
+        assert temp(compiled(whole)) > through
 
 
 def recurrent_and_attention():
