@@ -292,6 +292,7 @@ def serve_disagg(
                 # Nothing seated: we're waiting on the wire, not the
                 # device — yield instead of spinning admit hot.
                 time.sleep(1e-3)
+        srv.settle()
         done = srv.done
     finally:
         if session is not None:

@@ -143,6 +143,9 @@ class ThreadReplica:
             except queue_mod.Empty:
                 return
             try:
+                # An op sees every slot's record whole: no step left
+                # in flight by the last tick.
+                self.srv.settle()
                 box["val"] = fn(self.srv)
             except BaseException as e:  # op errors go to the caller
                 box["exc"] = e

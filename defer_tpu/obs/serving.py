@@ -56,6 +56,22 @@ class ServingMetrics:
             "defer_decode_ticks_total",
             "Batched decode steps executed", labels,
         )
+        # The plain tick's launch ahead (runtime/paged.py
+        # `_tick_plain`): steps dispatched while the step before them
+        # was still unread, over defer_decode_ticks_total the share of
+        # steps the host's work of a tick ran under; and rows such a
+        # step computed for a request that ended (eos, stop sequence)
+        # in the step before, dropped unread.
+        self.tick_ahead = reg.counter(
+            "defer_tick_ahead_total",
+            "Decode steps launched while the previous step's tokens "
+            "were still unread", labels,
+        )
+        self.tick_ahead_discarded = reg.counter(
+            "defer_tick_ahead_rows_discarded_total",
+            "Rows a step launched ahead computed for a request that "
+            "had already ended, dropped unread", labels,
+        )
         self.tokens_generated = reg.counter(
             "defer_tokens_generated_total",
             "Tokens emitted by decode slots (incl. first token)", labels,

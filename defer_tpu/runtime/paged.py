@@ -1046,7 +1046,8 @@ class PagedDecodeServer:
     sequence a tick makes no transfer, and `out` holds `(device array,
     index)` in a token's place, which `_finish` fetches. `done[rid]`
     is the two joined on the host: a `[1, T0 + n]` array in the
-    prompt's dtype.
+    prompt's dtype. The plain tick leaves one step in flight whose
+    tokens no record holds yet (`_tick_plain`); `settle` emits them.
     """
 
     def __init__(
@@ -1818,6 +1819,10 @@ class PagedDecodeServer:
         # max_batch [1,1] arrays every tick (host dispatch overhead
         # that dominates at small models). Idle rows are dummies.
         self._feed = jnp.zeros((max_batch, 1), jnp.int32)
+        # The plain tick's step in flight, launched before the tick
+        # read the tokens of the step ahead of it (`_tick_plain`), or
+        # None: its slots' records still lack its tokens.
+        self._inflight: dict | None = None
         self._sampler = SlotSampler(max_batch)
         # deque, not list: admission consumes from the head every
         # _admit pass, and a deep open-loop backlog would turn
@@ -2247,6 +2252,7 @@ class PagedDecodeServer:
                     time.sleep(1e-3)
                 continue
             self._tick()
+        self.settle()
         return self.done
 
     @property
@@ -4935,6 +4941,9 @@ class PagedDecodeServer:
                 # Bounded lookahead: enough prompts are already
                 # sharing the budget — admission stays near-FIFO.
                 return
+            # A seat makes the next tick a mixed one, which reads
+            # every slot's record whole.
+            self.settle()
             (rid, prompt, steps, adapter_id, samp,
              stop_seqs, cid) = self.pending[0]
             if self.radix is not None:
@@ -5363,7 +5372,13 @@ class PagedDecodeServer:
     def _tick_variant(self) -> dict:
         """Run the tick this server's mode calls for; what to record
         of it: which `kind` it was and, for the plain tick, the
-        `span_rows` of each slot's table that its step was handed."""
+        `span_rows` of each slot's table that its step was handed and
+        whether the step went `ahead`."""
+        seats = self._seat_slots()
+        if not (self.pp > 1 or self.spec_k or seats or self.decode_window > 1):
+            return {"kind": "plain", **self._tick_plain()}
+        # The other ticks read every slot's record whole.
+        self.settle()
         if self.pp > 1:
             self._tick_pp()
             return {"kind": "pp"}
@@ -5373,36 +5388,97 @@ class PagedDecodeServer:
             else:
                 self._tick_spec()
             return {"kind": "spec"}
-        if self._seat_slots():
+        if seats:
             # Mixed mode engages only while a seat is mid-prefill;
             # pure-decode stretches fall through to the EXACT plain /
             # window programs (the prefill_budget=None bit-identity
             # contract, and the window scan's dispatch amortization).
             self._tick_mixed()
             return {"kind": "mixed"}
-        if self.decode_window > 1:
-            self._tick_window()
-            return {"kind": "window"}
-        return {"kind": "plain", **self._tick_plain()}
+        self._tick_window()
+        return {"kind": "window"}
+
+    def settle(self) -> None:
+        """Read and emit the step the plain tick left in flight, if it
+        left one. Whatever reads or changes the slots' records other
+        than `_admit` and the plain tick calls this first: afterwards
+        each record holds every token its steps made."""
+        step, self._inflight = self._inflight, None
+        if step is not None:
+            with spans.span("paged.settle"):
+                self._drain_plain(step)
 
     def _tick_plain(self) -> dict:
         """One token for every live slot: the default tick, and the
         one whose phases the span log holds (plan, dispatch, sample,
-        sync, drain — `obs/spans.py`). Returns the `span_rows` of each
-        slot's table that the step was handed and how its attention
-        read them (`attend`: "table" or "gathered", `_attend`)."""
-        live = [s is not None for s in self.slots]
-        if not any(live):
+        sync, drain — `obs/spans.py`).
+
+        Where every live slot is greedy and unconstrained the tick
+        keeps one step in flight. It plans and dispatches step N+1
+        from the device's feed, then waits for step N's tokens and
+        emits them, then queues N+1's argmax: the device starts N+1
+        as N ends, and the host's work of a tick runs under it. A slot
+        whose count ends at N is idle in N+1; one that ends at N by
+        eos or a stop sequence had a row in N+1 as well, which is
+        dropped (`defer_tick_ahead_rows_discarded_total`). A slot
+        seated while N was in flight joins at N+1. With nothing in
+        flight the call launches N first. A sampling or constrained
+        slot makes the tick settle and run its one step through the
+        five phases in order.
+
+        Returns the `span_rows` of each slot's table that the step
+        whose tokens it emitted was handed, how its attention read
+        them (`attend`: "table" or "gathered", `_attend`), and
+        `ahead`: 1 where it left a step in flight."""
+        if not any(s is not None for s in self.slots):
+            self.settle()
             return {"span_rows": 0}
+        ahead = not any(
+            s is not None and (s["sampling"] or s["cid"]) for s in self.slots
+        )
+        if not ahead:
+            self.settle()
+        prev, self._inflight = self._inflight, None
+        if prev is None:
+            prev = self._launch_plain(None)
+            self._sample_plain(prev)
+        step = self._launch_plain(prev) if ahead else None
+        self._drain_plain(prev)
+        if step is not None:
+            self._sample_plain(step)
+            self._inflight = step
+        return {
+            "span_rows": prev["span_rows"], "attend": prev["attend"],
+            "ahead": int(step is not None),
+        }
+
+    def _launch_plain(self, prev: dict | None) -> dict | None:
+        """Plan and dispatch one step for every slot that still
+        decodes after `prev`, the step in flight (None where none is):
+        a slot `prev` was launched for is one position further on, and
+        idle where its count ends in `prev`. Returns the step as the
+        sample and the drain take it, or None where no slot decodes."""
+        rids: list = [None] * self.B
+        held = np.zeros((self.B,), np.int32)
+        live = np.zeros((self.B,), bool)
+        for i, s in enumerate(self.slots):
+            if s is None:
+                continue
+            held[i] = prev is not None and prev["rids"][i] == s["rid"]
+            if s["remaining"] > held[i]:
+                rids[i] = s["rid"]
+                live[i] = True
+        if not live.any():
+            return None
         with spans.span("paged.tick.plan"):
             self._build()
             # Persistent [B,1] device feed (constructor note):
-            # admissions set their row, draws below overwrite the
-            # whole vector — no per-tick concat of max_batch [1,1]
-            # arrays.
+            # admissions set their row, draws overwrite the whole
+            # vector — no per-tick concat of max_batch [1,1] arrays.
             feed = self._feed
-            # Idle slots write into trash block 0 at position 0.
-            posm = np.where(live, self.pos, 0).astype(np.int32)
+            # Idle slots write into trash block 0 at position 0: a
+            # slot whose count ends in `prev` still holds its table.
+            posm = np.where(live, self.pos + held, 0).astype(np.int32)
             pos = jnp.asarray(posm)
             # COPY the mutable host state before handing it to the
             # device: jnp.asarray of a numpy array is zero-copy on
@@ -5414,8 +5490,19 @@ class PagedDecodeServer:
             # rows past a slot's position are masked, so the columns
             # dropped here change no logit.
             nb = pick_rung(self._rungs, self.bs, int(posm.max()))
-            tables = jnp.asarray(self.tables[:, :nb].copy())
+            tables = jnp.asarray(np.where(live[:, None], self.tables[:, :nb], 0))
             adapter = jnp.asarray(self.adapter.copy())
+            # Host transfer only when eos/streaming/stop matching needs
+            # the values — the plain path stays async (same guard as the
+            # flat server).
+            need_host = (
+                self.eos_id is not None
+                or self.on_token is not None
+                or any(
+                    r is not None and s["stop"] is not None
+                    for s, r in zip(self.slots, rids)
+                )
+            )
         with spans.span("paged.tick.dispatch"):
             # The recurrent layers' state pools, where the stack has
             # them, are the step's last operand and last result.
@@ -5436,10 +5523,21 @@ class PagedDecodeServer:
                 # An expert decoder's step hands its counters back
                 # beside the logits (`_step_body`).
                 logits, moe = logits
+            if prev is not None:
+                self.obs.tick_ahead.inc()
+        return {
+            "rids": rids, "live": live, "posm": posm, "nb": nb,
+            "span_rows": nb * self.bs, "attend": self._attend_at[nb],
+            "logits": logits, "moe": moe, "need_host": need_host,
+        }
+
+    def _sample_plain(self, step: dict) -> None:
+        """Queue `step`'s next tokens into the feed and count it."""
+        live, posm, nb = step["live"], step["posm"], step["nb"]
         with spans.span("paged.tick.sample"):
             self.ticks += 1
             self.dispatches += 1
-            n_live = sum(live)
+            n_live = int(live.sum())
             now = time.perf_counter()
             if self._last_tick_t is not None:
                 self.obs.itl.observe(now - self._last_tick_t, n_live)
@@ -5461,7 +5559,7 @@ class PagedDecodeServer:
             # and "table" clamp per slot, so each reads only its own
             # live span.
             baseline = self.B * self.MB * self.bs
-            attend = self._attend_at[nb]
+            attend = step["attend"]
             if attend == "gathered":
                 rows_read = self.B * nb * self.bs
             elif attend == "blockwise":
@@ -5490,13 +5588,13 @@ class PagedDecodeServer:
                         int(np.maximum(posm + 1 - w, 0).sum())
                         * (self.dec.cfg.num_layers // len(self.dec.cfg.layer_kinds))
                     )
-            ll = logits[:, -1, :]
+            ll = step.pop("logits")[:, -1, :]
             sm = self._sampler
             # Constrained rows (defer_tpu/constrain/): fold the DFA mask
             # into the batched logits BEFORE argmax/draw, advance states
             # after. Guarded by the host mirror so unconstrained serving
             # dispatches the exact pre-constraint op sequence.
-            constrained = any(sm.row_constrained)
+            constrained = step["constrained"] = any(sm.row_constrained)
             if constrained:
                 crow, cacc = crt.constrain_rows(
                     self._ctrans, self._cacc, sm.cid, sm.cstate
@@ -5517,42 +5615,48 @@ class PagedDecodeServer:
                 sm.cstate = crt.advance_state(
                     crow, sm.cstate, nxt, cvec & ~dead
                 )
-                mfrac = crt.masked_frac(cmask, cvec & jnp.asarray(live))
+                step["dead"] = dead
+                step["mfrac"] = crt.masked_frac(cmask, cvec & jnp.asarray(live))
+            step["nxt"] = nxt
             self._feed = nxt[:, None].astype(jnp.int32)
-            # Host transfer only when eos/streaming/stop matching needs
-            # the values — the plain path stays async (same guard as the
-            # flat server).
-            need_host = (
-                self.eos_id is not None
-                or self.on_token is not None
-                or any(
-                    s is not None and s["stop"] is not None
-                    for s in self.slots
-                )
-            )
+
+    def _drain_plain(self, step: dict) -> None:
+        """Wait for `step`'s tokens (one transfer, where a consumer
+        needs their values) and emit each to the request it was
+        launched for. A row whose request has ended since is dropped."""
+        nxt = step["nxt"]
+        constrained = step["constrained"]
         with spans.span("paged.tick.sync"):
             # analysis: ignore[host-sync-in-hot-loop] single batched
             # transfer per WINDOW (a window of one token here), and only
             # when an eos/stop/stream consumer needs host tokens — the
             # sync this serving loop is designed around
-            host_nxt = np.asarray(nxt).tolist() if need_host else None
-            if moe is not None:
+            host_nxt = np.asarray(nxt).tolist() if step["need_host"] else None
+            if step["moe"] is not None:
                 # analysis: ignore[host-sync-in-hot-loop] [L, 2] int32
-                # of the step that made the logits above: ready with
-                # the tokens, at the same sync point
-                self._account_moe(self.obs.moe_decode, np.asarray(moe))
+                # of the step that made the tokens above: ready with
+                # them, at the same sync point
+                self._account_moe(self.obs.moe_decode, np.asarray(step["moe"]))
             if constrained:
                 # analysis: ignore[host-sync-in-hot-loop] one batched
                 # per-tick transfer of the dead-end flags + mask
                 # fractions, and only while a constrained row is live
-                dead_host = np.asarray(dead)
+                dead_host = np.asarray(step["dead"])
                 # analysis: ignore[host-sync-in-hot-loop] ready with the
                 # vector above (same sync point)
-                mfrac_host = np.asarray(mfrac)
-        with spans.span("paged.tick.drain", tokens=n_live):
-            for i, slot in enumerate(self.slots):
-                if slot is None:
+                mfrac_host = np.asarray(step["mfrac"])
+        with spans.span("paged.tick.drain") as sp:
+            drained = 0
+            for i, rid in enumerate(step["rids"]):
+                if rid is None:
                     continue
+                slot = self.slots[i]
+                if slot is None or slot["rid"] != rid:
+                    # Ended by eos or a stop sequence in the step
+                    # before, after this one was launched.
+                    self.obs.tick_ahead_discarded.inc()
+                    continue
+                drained += 1
                 if constrained and slot["cid"]:
                     if bool(dead_host[i]):
                         # The forced eos never enters the output: the
@@ -5575,9 +5679,9 @@ class PagedDecodeServer:
                 slot["remaining"] -= 1
                 self.pos[i] += 1
                 self._emit_token(
-                    i, slot, host_nxt[i] if need_host else (nxt, i)
+                    i, slot, host_nxt[i] if host_nxt is not None else (nxt, i)
                 )
-        return {"span_rows": nb * self.bs, "attend": attend}
+            sp.counts["tokens"] = drained
 
     def _tick_spec(self) -> None:
         """One speculative round: TWO host dispatches advance every

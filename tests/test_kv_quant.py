@@ -132,27 +132,26 @@ def _forced_trace(dec, params, prompt, steps, forced=None, **srv_kw):
     srv = PagedDecodeServer(
         dec, params, num_blocks=16, block_size=4, max_batch=1, **srv_kw
     )
-    srv.submit(prompt, steps)
+    rid = srv.submit(prompt, steps)
     srv._admit()
     srv._build()
     orig = srv._step
 
     rec = []
 
-    def spy(*args):
-        logits, pk, pv = orig(*args)
+    def spy(params, pk, pv, tables, pos, feed, *rest):
+        # Step t feeds the trajectory's token t: the tick launches a
+        # step before it reads the one in flight, so the feed is
+        # replaced where the step takes it.
+        t = len(rec)
+        if forced is not None and 0 < t < len(forced):
+            feed = jnp.asarray([[forced[t]]], jnp.int32)
+        logits, pk, pv = orig(params, pk, pv, tables, pos, feed, *rest)
         rec.append(np.asarray(logits[:, -1, :]))
         return logits, pk, pv
 
     srv._step = spy
-    toks = [int(np.asarray(srv._feed)[0, 0])]
-    t = 0
-    while any(s is not None for s in srv.slots):
-        srv._tick()
-        toks.append(int(np.asarray(srv._feed)[0, 0]))
-        if forced is not None and t + 1 < len(forced):
-            srv._feed = jnp.asarray([[forced[t + 1]]], jnp.int32)
-        t += 1
+    toks = np.asarray(srv.run()[rid])[0, prompt.shape[1]:].tolist()
     return toks, rec
 
 

@@ -15,6 +15,9 @@ from defer_tpu.obs import spans
 from defer_tpu.runtime.paged import PagedDecodeServer
 
 TICK_PHASES = ["plan", "dispatch", "sample", "sync", "drain"]
+# A tick that launches its step ahead reads the step before it between
+# the step's dispatch and its sample.
+AHEAD_PHASES = ["plan", "dispatch", "sync", "drain", "sample"]
 SEAT_PHASES = ["plan", "prefill", "insert", "first_token"]
 
 
@@ -188,17 +191,36 @@ def served():
 
 
 def test_every_tick_has_its_five_phases_in_order(served):
-    records = served[-1]
+    """A tick that launches ahead has the five phases in the order
+    that hides the read of the step before, after a launch of that
+    step where none was in flight; one that does not runs its step
+    through them in order, or only reads the step in flight where no
+    slot decodes past it. Each token a drain emits is counted once."""
+    dec, params, reqs, rids, srv, streamed, records = served
     got = by_name(records)
     assert got["paged.tick"]
+    shapes = collections.Counter()
+    drained = 0
     for tick in got["paged.tick"]:
         assert tick.counts["kind"] == "plain" and 1 <= tick.counts["live"] <= 2
         kids = [r for r in records if r.parent == tick.id]
-        assert [k.name for k in kids] == [f"paged.tick.{p}" for p in TICK_PHASES]
+        names = [k.name.removeprefix("paged.tick.") for k in kids]
+        if tick.counts["ahead"]:
+            primed = names[:3] == ["plan", "dispatch", "sample"]
+            assert names == ["plan", "dispatch", "sample"] * primed + AHEAD_PHASES
+            shapes["primed" if primed else "ahead"] += 1
+        else:
+            assert names in (TICK_PHASES, ["sync", "drain"])
+            shapes[len(names)] += 1
         assert all(a.t1 <= b.t0 for a, b in zip(kids, kids[1:]))
         assert tick.t0 <= kids[0].t0 and kids[-1].t1 <= tick.t1
         assert sum(k.t1 - k.t0 for k in kids) <= tick.t1 - tick.t0
-        assert kids[-1].counts == {"tokens": tick.counts["live"]}
+        (drain,) = [k for k in kids if k.name == "paged.tick.drain"]
+        assert 1 <= drain.counts["tokens"] <= tick.counts["live"]
+        drained += drain.counts["tokens"]
+    assert shapes["primed"] and shapes["ahead"], shapes
+    # An admission emits a request's first token, the drains the rest.
+    assert drained + len(reqs) == sum(steps for _, steps in reqs)
 
 
 def test_every_request_has_one_seat_one_finish_and_one_request_span(served):
