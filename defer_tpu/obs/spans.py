@@ -6,14 +6,15 @@ and appends one `Record` to a ring of `LOG_MAX` records. `parent` is
 the span that was open on the same thread (servers run on threads
 under `fleet/replica.py`), and spans of one request carry its `rid`.
 
-Each span also enters `utils.profiling.annotate(name)`, so whenever a
-`jax.profiler` trace is being captured the same spans lie on the
-profiler's clock beside the device's operations; with no session that
-is an atomic load.
-
-Two exits: `snapshot(t_lo, t_hi)` for code (the benchmark's readers
-filter by their own `perf_counter` window), `to_chrome_trace()` for a
-person (Perfetto). `obs.reset()` clears the log.
+Each span also enters `utils.profiling.annotate(name, id=, parent=,
+rid=)`, so whenever a `jax.profiler` trace is being captured the same
+spans lie on the profiler's clock beside the device's operations, each
+trace event carrying its record's `id`, and its `parent` and `rid`
+where it has them: the event joins its record, whose counts are filled
+inside the block. With no session that is an atomic load. A person
+opens that trace (TensorBoard, Perfetto); code reads the log through
+`snapshot(t_lo, t_hi)` (the benchmark's readers filter by their own
+`perf_counter` window). `obs.reset()` clears the log.
 
 Program builds come through one `jax.monitoring` listener, registered
 when this module is first imported: each lowering, compilation and
@@ -26,7 +27,6 @@ from __future__ import annotations
 
 import collections
 import itertools
-import os
 import threading
 import time
 from typing import NamedTuple
@@ -55,7 +55,6 @@ class Record(NamedTuple):
     t1: float
     rid: int | None
     counts: dict
-    tid: int  # threading.get_ident(), a track for to_chrome_trace
 
 
 class Snapshot(NamedTuple):
@@ -87,8 +86,7 @@ def record(name: str, t0: float, t1: float, rid=None, **counts) -> None:
     parent is the span open on this thread."""
     stack = _stack()
     rec = Record(
-        next(_ids), stack[-1] if stack else None, name, t0, t1, rid,
-        counts, threading.get_ident(),
+        next(_ids), stack[-1] if stack else None, name, t0, t1, rid, counts
     )
     with _lock:  # against snapshot()'s copy and reset()
         _log.append(rec)
@@ -115,7 +113,12 @@ class span:
         self._parent = stack[-1] if stack else None
         self.id = next(_ids)
         stack.append(self.id)
-        self._ann = annotate(self.name)
+        meta = {"id": self.id}
+        if self._parent is not None:
+            meta["parent"] = self._parent
+        if self.rid is not None:
+            meta["rid"] = self.rid
+        self._ann = annotate(self.name, **meta)
         self._ann.__enter__()
         self._t0 = _clock()
         return self
@@ -127,7 +130,7 @@ class span:
         if self.keep:
             rec = Record(
                 self.id, self._parent, self.name, self._t0, t1, self.rid,
-                self.counts, threading.get_ident(),
+                self.counts,
             )
             with _lock:
                 _log.append(rec)
@@ -148,21 +151,6 @@ def snapshot(t_lo: float | None = None, t_hi: float | None = None) -> Snapshot:
     if t_hi is not None:
         records = [r for r in records if r.t1 <= t_hi]
     return Snapshot(records, complete)
-
-
-def to_chrome_trace() -> list[dict]:
-    """The log as a `traceEvents` list (complete events, microseconds):
-    `json.dump({"traceEvents": to_chrome_trace()}, f)` loads in
-    Perfetto, one track per thread."""
-    pid = os.getpid()
-    return [
-        {
-            "name": r.name, "ph": "X", "pid": pid, "tid": r.tid,
-            "ts": r.t0 * 1e6, "dur": (r.t1 - r.t0) * 1e6,
-            "args": {"id": r.id, "parent": r.parent, "rid": r.rid, **r.counts},
-        }
-        for r in snapshot().records
-    ]
 
 
 def reset() -> None:

@@ -139,6 +139,12 @@ def _pool_arr(pool):
 # ladder is held to what chat-length traffic on a long table needs.
 _RUNG_SIXTEENTHS = (4, 6, 16)
 
+# The decode step's function name, so its program is `jit_<name>` in a
+# profiler trace (an event of the device plane's `XLA Modules` line a
+# step): a trace reader finds one step's execution by it, as it finds
+# a kernel by the `name=` of its `pallas_call`.
+DECODE_STEP = "paged_decode_step"
+
 
 def span_rungs(mb: int) -> tuple[int, ...]:
     """The ladder of table spans, in columns of a `mb`-column block
@@ -2677,11 +2683,13 @@ class PagedDecodeServer:
         return logits[..., : self.dec.cfg.vocab_size]
 
     def _build_step(self):
+        step = self._step_body()
+        step.__name__ = DECODE_STEP
         if self.pool_state:
             # The state pools are one more donated operand, after the
             # four host-fed ones (no mesh serves such a stack).
-            return jax.jit(self._step_body(), donate_argnums=(1, 2, 7))
-        return self._jit_tick(self._step_body(), n_rep=4)
+            return jax.jit(step, donate_argnums=(1, 2, 7))
+        return self._jit_tick(step, n_rep=4)
 
     def _step_body(self):
         """The RAW (unjitted) gathered-attention step body — jitted

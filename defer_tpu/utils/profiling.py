@@ -51,10 +51,12 @@ def trace(trace_dir: str | None = None):
             log.warning("profiler stop failed: %s", e)
 
 
-def annotate(name: str):
-    """Named host-region annotation visible in captured traces."""
+def annotate(name: str, **meta):
+    """Named host-region annotation visible in captured traces; each
+    keyword comes back as a stat of the trace event (an int stays an
+    int), and the name stays as given."""
     try:
-        return jax.profiler.TraceAnnotation(name)
+        return jax.profiler.TraceAnnotation(name, **meta)
     except Exception:
         return contextlib.nullcontext()
 
